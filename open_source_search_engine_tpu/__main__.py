@@ -39,6 +39,9 @@ def cmd_serve(args) -> int:
     from .control.process import Process
     from .serve.server import SearchHTTPServer
     from .spider.loop import SpiderLoop
+    from .utils import compilecache
+
+    compilecache.configure()
 
     cluster = None
     if args.hosts:
@@ -134,6 +137,9 @@ def cmd_search(args) -> int:
     from .query import engine
 
     coll = CollectionDb(args.dir).get(args.coll, create=False)
+    if args.device:
+        from .utils import compilecache
+        compilecache.configure()
     search = engine.search_device if args.device else engine.search
     res = search(coll, args.query, topk=args.k)
     out = {

@@ -10,7 +10,8 @@ device program instead of ~450 s of host NumPy (BENCH_r04):
 1. **merge**: the runs' key columns are concatenated host-side (no
    host sort — enforced by the ``host-sort`` osselint rule), split
    into uint32 words, and sorted on-device by (key-sans-delbit asc,
-   recency desc) — a stable ``lexsort``, so ties resolve exactly like
+   recency desc) — a stable lexsort (``_lexsort``: one single-key pass
+   per key word), so ties resolve exactly like
    ``rdblite._dedup_newest``. First-of-group survives; surviving
    tombstones annihilate; survivors compact to the front with a
    stable flag sort.
@@ -100,6 +101,21 @@ def _compact(order, *cols):
     return tuple(c[order] for c in cols)
 
 
+def _lexsort(keys):
+    """``jnp.lexsort(keys)`` (last key primary, stable) as one stable
+    single-key sort per key, least significant first — the same
+    permutation by construction. The TPU compiler's time for ONE sort
+    grows about quadratically with its operand count: the base
+    program's 7-key merge sort alone compiled 428 s for a v5e at
+    N = 2^25, these seven 2-operand passes compile in 41 s (CHANGES.md,
+    PR 22), and a cold node pays that before its first answer."""
+    order = jnp.arange(keys[0].shape[0], dtype=jnp.int32)
+    for k in keys:
+        _, order = lax.sort((k[order], order), num_keys=1,
+                            is_stable=True)
+    return order
+
+
 def _count_true(m):
     return jnp.sum(m, dtype=jnp.int32)
 
@@ -182,7 +198,7 @@ def _derive(tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg, n):
 
     # --- store cap: scoring consumes ≤ P positions per pair ---
     keep = (occ < P) & valid
-    oc = jnp.argsort(~keep, stable=True)
+    oc = _lexsort(((~keep).astype(_U32),))
     (tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg,
      occ) = _compact(oc, tid_lo, tid_hi, docidx, hg, den, spam, wp,
                      sr, lg, occ)
@@ -221,7 +237,7 @@ def _derive(tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg, n):
                        spam.astype(jnp.int32))
     mhg = jnp.asarray(weights.MAPPED_HASHGROUP)[hg.astype(jnp.int32)]
     pid_key = jnp.where(valid, pair_id, jnp.int32(N))
-    o = jnp.lexsort((mhg, pid_key))
+    o = _lexsort((mhg, pid_key))
     ps_o, il_o, mh_o, pid_o, valid_o = _compact(
         o, ps, il, mhg, pid_key, valid)
     gch = (_neq_prev(pid_o) | _neq_prev(mh_o)) & valid_o
@@ -234,7 +250,7 @@ def _derive(tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg, n):
     # rank candidates within each pair, descending cval: stable sort by
     # (pair, non-candidate-last, ~bitcast(cval)) — monotone for f32 ≥ 0
     ckey = ~lax.bitcast_convert_type(cval, _U32)
-    o3 = jnp.lexsort((ckey, (~cand).astype(_U32), pid_o))
+    o3 = _lexsort((ckey, (~cand).astype(_U32), pid_o))
     seg = _neq_prev(pid_o[o3])
     rank = jnp.zeros(N, jnp.int32).at[o3].set(_seg_pos(seg, idx))
     contrib = jnp.where(cand & (rank < weights.MAX_TOP), cval,
@@ -283,13 +299,13 @@ def _base_program(n0, n1lo, n1hi, n2lo, n2hi, rec, n):
     # --- RdbMerge/Msg5: newest-wins dedup + tombstone annihilation ---
     n0c = n0 & ~_U32(1)
     negrec = _U32(0x7FFFFFFF) - rec
-    order = jnp.lexsort((negrec, n0c, n1lo, n1hi, n2lo, n2hi,
-                         (~valid).astype(_U32)))
+    order = _lexsort((negrec, n0c, n1lo, n1hi, n2lo, n2hi,
+                      (~valid).astype(_U32)))
     n0_s, n0c_s, l1, h1, l2, h2, valid_s = _compact(
         order, n0, n0c, n1lo, n1hi, n2lo, n2hi, valid)
     first = _neq_prev(n0c_s, l1, h1, l2, h2)
     keep = first & (n0_s & _U32(1)).astype(bool) & valid_s
-    oc = jnp.argsort(~keep, stable=True)
+    oc = _lexsort(((~keep).astype(_U32),))
     n0_s, l1, h1, l2, h2 = _compact(oc, n0_s, l1, h1, l2, h2)
     n_merged = _count_true(keep)
     valid = idx < n_merged
@@ -307,7 +323,7 @@ def _base_program(n0, n1lo, n1hi, n2lo, n2hi, rec, n):
     den = (n0_s >> 11) & _U32(0x1F)
 
     # --- docidx: rank of each distinct docid (np.unique collapse) ---
-    od = jnp.lexsort((d_lo, d_hi, (~valid).astype(_U32)))
+    od = _lexsort((d_lo, d_hi, (~valid).astype(_U32)))
     dl_s, dh_s, v_s = _compact(od, d_lo, d_hi, valid)
     newdoc = _neq_prev(dl_s, dh_s) & v_s
     docrank = jnp.cumsum(newdoc.astype(jnp.int32)) - 1
@@ -337,8 +353,8 @@ def _delta_program(tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg, m):
     the host path — then the shared derive stage."""
     N = tid_lo.shape[0]
     valid = jnp.arange(N, dtype=jnp.int32) < m
-    o = jnp.lexsort((wp, docidx, tid_lo, tid_hi,
-                     (~valid).astype(_U32)))
+    o = _lexsort((wp, docidx, tid_lo, tid_hi,
+                  (~valid).astype(_U32)))
     tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg = _compact(
         o, tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg)
     return _derive(tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg, m)

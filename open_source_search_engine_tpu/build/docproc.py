@@ -614,7 +614,7 @@ def index_document(coll: Collection, url: str, content: str, *,
     # boilerplate gate (Sections dup votes): sections this page shares
     # with enough sibling pages of the site demote at build time
     flds = extract_fields(content)
-    # directory taxonomy (Catdb): a filed site's docs carry catid/
+    # directory category tree (Catdb): a filed site's docs carry catid/
     # category fields — gbmin:catid:/gbfacet:category do the rest
     flds.update(coll.catdb.doc_fields(site))
     tdoc = _tokenize_doc(content, u.full, is_html, flds)

@@ -1,9 +1,9 @@
-"""Catdb — the DMOZ-style directory taxonomy (Catdb.h:27 / dmozparse).
+"""Catdb — the DMOZ-style directory category tree (Catdb.h:27 / dmozparse).
 
-The reference parses the DMOZ RDF dump into ``catdb``: a taxonomy of
+The reference parses the DMOZ RDF dump into ``catdb``: a tree of
 topics plus url→category assignments; queries can then restrict or
 facet by directory topic. DMOZ itself is dead, but the subsystem is
-the same with any taxonomy:
+the same with any category tree:
 
 * a **category tree** loaded from ``categories.txt`` — one
   ``catid<TAB>parent_catid<TAB>Topic/Path`` line per node (parent 0 =
@@ -53,10 +53,10 @@ class Catdb:
         if p.exists():
             self.load_tree(p.read_text(encoding="utf-8"))
 
-    # --- taxonomy ------------------------------------------------------
+    # --- category tree -------------------------------------------------
 
     def load_tree(self, text: str) -> int:
-        """Parse the taxonomy file (dmozparse structure role)."""
+        """Parse the category-tree file (dmozparse structure role)."""
         n = 0
         for line in text.splitlines():
             line = line.strip()

@@ -3,14 +3,20 @@
 The reference's host plane is C++ (SURVEY §2: "everything is C++"); ours
 keeps the byte-crunching primitives native too: n-way run merge with
 tombstone annihilation, key binary search, and sorted-batch dedup
-(``rdbcore.cpp``). Built on demand with g++ into ``librdbcore.so``;
-every caller has a vectorized-numpy fallback, so the framework works
-(slower) without a toolchain.
+(``rdbcore.cpp``). Built on demand with g++ into a shared object whose
+NAME carries a hash of the source and the flags it was built from
+(``librdbcore-<sha>.so``), so a library left over from another version
+of the source — the artefacts are untracked, and a copied tree loses
+its mtimes — is never loaded: a changed source misses and rebuilds.
+Every caller has a vectorized-numpy fallback, so the framework works
+(slower) without a toolchain; the degrade is logged and counted
+(``native.fallback``) and ``chip_smoke.py`` fails on it.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,11 +25,12 @@ from pathlib import Path
 import numpy as np
 
 from ..utils.log import get_logger
+from ..utils.stats import g_stats
 
 log = get_logger("native")
 
 #: OSSE_NATIVE_SAN=1 → build/load ASan+UBSan-instrumented natives
-#: instead of the optimized ones. Separate ``.san.so`` artifact names so
+#: instead of the optimized ones. Separate ``.san`` artifact names so
 #: the two modes never clobber each other's build cache. The sanitizer
 #: runtimes must be preloaded into the (uninstrumented) Python process —
 #: ``tools/native_san_check.py`` handles the LD_PRELOAD dance.
@@ -33,25 +40,40 @@ _SAN_FLAGS = ["-fsanitize=address,undefined", "-fno-omit-frame-pointer",
 
 _DIR = Path(__file__).parent
 _SRC = _DIR / "rdbcore.cpp"
-_SO = _DIR / ("librdbcore.san.so" if SANITIZE else "librdbcore.so")
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _gxx_cmd(opt: str, src: Path, out: Path) -> list[str]:
+def _load(src: Path, stem: str, opt: str) -> ctypes.CDLL | None:
+    """Load the library built from ``src`` as it reads NOW, building
+    it first when no artefact carries this source's hash; None (logged
+    and counted) when the toolchain or the loader fails."""
     flags = _SAN_FLAGS if SANITIZE else [opt]
-    return ["g++", *flags, "-shared", "-fPIC", str(src), "-o", str(out)]
-
-
-def _build() -> bool:
+    key = hashlib.sha256(
+        src.read_bytes() + " ".join(flags).encode()).hexdigest()[:16]
+    if SANITIZE:
+        stem += ".san"
+    so = _DIR / f"{stem}-{key}.so"
     try:
-        subprocess.run(_gxx_cmd("-O3", _SRC, _SO),
-                       check=True, capture_output=True, timeout=120)
-        return True
-    except Exception as e:  # noqa: BLE001 — fall back to numpy
-        log.warning("native build failed (numpy fallback in use): %s", e)
-        return False
+        if not so.exists():
+            # build beside the target and rename: several processes
+            # (pytest workers) may race here, and none may ever load a
+            # half-written file
+            tmp = _DIR / f".{so.stem}.{os.getpid()}.so"
+            subprocess.run(
+                ["g++", *flags, "-shared", "-fPIC", str(src), "-o",
+                 str(tmp)], check=True, capture_output=True, timeout=180)
+            os.replace(tmp, so)
+            for stale in _DIR.glob(f"{stem}-*.so"):
+                if stale != so:
+                    stale.unlink(missing_ok=True)
+        return ctypes.CDLL(str(so))
+    except (OSError, subprocess.SubprocessError) as e:
+        log.warning("native %s unavailable (fallback in use): %s",
+                    stem, e)
+        g_stats.count("native.fallback")
+        return None
 
 
 def get_lib():
@@ -61,13 +83,8 @@ def get_lib():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-            if not _build():
-                return None
-        try:
-            lib = ctypes.CDLL(str(_SO))
-        except OSError as e:
-            log.warning("native load failed: %s", e)
+        lib = _load(_SRC, "librdbcore", "-O3")
+        if lib is None:
             return None
         lib.osse_merge_runs.restype = ctypes.c_int64
         lib.osse_merge_runs.argtypes = [
@@ -123,7 +140,6 @@ def searchsorted(sorted_keys: np.ndarray, probe: np.ndarray,
 # --- doccore: native HTML tokenize + term hash + rank columns ----------
 
 _DOC_SRC = _DIR / "doccore.cpp"
-_DOC_SO = _DIR / ("libdoccore.san.so" if SANITIZE else "libdoccore.so")
 _doc_lib = None
 _doc_tried = False
 
@@ -166,17 +182,6 @@ class _OsseDoc(ctypes.Structure):
     ]
 
 
-def _build_doccore() -> bool:
-    try:
-        subprocess.run(_gxx_cmd("-O2", _DOC_SRC, _DOC_SO),
-                       check=True, capture_output=True, timeout=180)
-        return True
-    except Exception as e:  # noqa: BLE001 — fall back to Python
-        log.warning("doccore build failed (python tokenizer in use): %s",
-                    e)
-        return False
-
-
 def get_doccore():
     """The loaded libdoccore, building on first use; None = fallback."""
     global _doc_lib, _doc_tried
@@ -184,14 +189,8 @@ def get_doccore():
         if _doc_lib is not None or _doc_tried:
             return _doc_lib
         _doc_tried = True
-        if not _DOC_SO.exists() or \
-                _DOC_SO.stat().st_mtime < _DOC_SRC.stat().st_mtime:
-            if not _build_doccore():
-                return None
-        try:
-            lib = ctypes.CDLL(str(_DOC_SO))
-        except OSError as e:
-            log.warning("doccore load failed: %s", e)
+        lib = _load(_DOC_SRC, "libdoccore", "-O2")
+        if lib is None:
             return None
         lib.osse_tokenize.restype = ctypes.POINTER(_OsseDoc)
         lib.osse_tokenize.argtypes = [

@@ -883,7 +883,11 @@ class SearchHTTPServer:
                 # exactly when the plane is saturated — shed instead
                 raise
             except Exception as e:  # noqa: BLE001 — degrade, don't 500
-                log.warning("device search failed (%s); host fallback",
+                # counted: an answer that came from the host while
+                # serve_device is on hides the device (chip_smoke.py
+                # requires this counter to stay 0)
+                g_stats.count("serve.device_fallback")
+                log.warning("device search failed (%r); host fallback",
                             e)
                 with self._lock:
                     res = engine.search(self._coll(query), q, topk=n,

@@ -65,9 +65,23 @@ _TPU_PEAKS = (
     ("v2", 45e12, 700e9, "tpu-v2"),
 )
 
-#: order-of-magnitude host numbers for the CPU fallback — labeled
-#: assumed so nobody reads a CI-box verdict as a chip verdict
+#: order-of-magnitude host numbers for a CPU run — labeled assumed so
+#: nobody reads a CI-box verdict as a chip verdict
 _CPU_PEAKS = (2e11, 4e10, "cpu (assumed)")
+
+
+def peaks_row(device_kind: str) -> tuple:
+    """The ``_TPU_PEAKS`` row an accelerator's ``device_kind`` matches.
+    A kind that matches no row is an error, never a default: a
+    roofline against another chip's (or the host's) peaks is wrong in
+    silence."""
+    kind = str(device_kind).lower()
+    for row in _TPU_PEAKS:
+        if row[0] in kind:
+            return row
+    raise LookupError(
+        f"device_kind {device_kind!r} matches no row of "
+        "devwatch._TPU_PEAKS — add its published peaks")
 
 
 def _nbytes(a) -> int:
@@ -322,21 +336,16 @@ class DevWatch:
     # -- roofline attribution ----------------------------------------
 
     def _peaks_for(self) -> dict:
-        if self._peaks is not None:
-            return self._peaks
-        flops, bw, label = _CPU_PEAKS
-        assumed = True
-        try:
+        if self._peaks is None:
             import jax
-            kind = str(jax.devices()[0].device_kind).lower()
-            for sub, f, b, lab in _TPU_PEAKS:
-                if sub in kind:
-                    flops, bw, label, assumed = f, b, lab, False
-                    break
-        except Exception:
-            g_stats.count("devwatch.peaks_errors")
-        self._peaks = {"flops": flops, "bw": bw, "label": label,
-                       "assumed": assumed, "ridge": flops / bw}
+            d0 = jax.devices()[0]
+            if d0.platform == "cpu":
+                flops, bw, label = _CPU_PEAKS
+            else:
+                _, flops, bw, label = peaks_row(d0.device_kind)
+            self._peaks = {"flops": flops, "bw": bw, "label": label,
+                           "assumed": d0.platform == "cpu",
+                           "ridge": flops / bw}
         return self._peaks
 
     def note_cost(self, kernel: str, bucket, thunk,

@@ -11,17 +11,19 @@ latency.
 
 Capture channels (all restored exactly on :func:`disable`):
 
-* ``jax._src.pjit``'s ``TRACING CACHE MISS at <site> because: ...``
-  explanations (gated on the ``jax_explain_cache_misses`` config,
-  flipped on while enabled) — these carry the jit call site and the
-  miss category, distinguishing a cold first trace from a genuine
-  retrace.
+* ``jax._src.interpreters.partial_eval``'s ``TRACING CACHE MISS at
+  <file>:<line>:<col> (<caller>):`` explanations (gated on the
+  ``jax_explain_cache_misses`` config, flipped on while enabled) —
+  these carry the jit call site and, on the following lines, either
+  ``never seen function: <fn> ...`` (a cold first trace) or ``for
+  <fn> defined at ...`` plus what differed (a genuine retrace).
 * ``jax._src.interpreters.pxla``'s ``Compiling <fn> with global shapes
-  and types [...]`` records — emitted at DEBUG even when
+  and types (...)`` records — emitted at DEBUG even when
   ``jax_log_compiles`` is off, so a DEBUG-level handler sees every
   backend compile without changing global logging behavior.
-* ``jax._src.dispatch``'s ``Finished tracing + transforming`` records
-  — per-trace durations.
+* ``jax._src.dispatch``'s ``Finished tracing + transforming`` and
+  ``Finished XLA compilation of <fn> in <s> sec`` records — per-trace
+  and per-compile durations (``jit.trace_ms`` / ``jit.compile_ms``).
 * Wrappers around ``jax.device_put`` / ``jax.device_get`` — the
   explicit transfer guard. JAX's own ``transfer_guard("log")`` writes
   from C++ straight to stderr where Python cannot observe it, so the
@@ -53,8 +55,8 @@ from . import trace
 from .stats import g_stats
 
 #: loggers whose records carry the compile/retrace story
-_JAX_LOGGERS = ("jax._src.pjit", "jax._src.interpreters.pxla",
-                "jax._src.dispatch")
+_JAX_LOGGERS = ("jax._src.interpreters.partial_eval",
+                "jax._src.interpreters.pxla", "jax._src.dispatch")
 
 #: repo-relative module suffixes that OWN device↔host traffic — a
 #: transfer attributed elsewhere is a hot-path violation (mirrors
@@ -66,13 +68,21 @@ _PKG_ROOT = Path(__file__).resolve().parent.parent
 _SELF_FILE = str(Path(__file__).resolve())
 
 _MISS_RE = re.compile(
-    r"TRACING CACHE MISS at ([^\s]+):(\d+) \(([^)]*)\) because:")
-_COMPILE_RE = re.compile(
-    r"Compiling ([^\s]+) with global shapes and types \[(.*?)\]\.",
+    r"TRACING CACHE MISS at (\S+?):(\d+):\d+ \([^)]*\):\s*(.*)",
     re.DOTALL)
+#: the jitted function's name inside a miss explanation: a cold trace
+#: says "never seen function:\n  <fn> id=...", a retrace "for <fn>
+#: defined at ..."
+_MISS_FN_RE = re.compile(
+    r"(?:never seen function:\s*|for )(\S+) (?:id=|defined at)")
+_COMPILE_RE = re.compile(
+    r"Compiling ([^\s]+) with global shapes and types (.*?)\. "
+    r"Argument mapping", re.DOTALL)
 _TRACED_RE = re.compile(
     r"Finished tracing \+ transforming (\S+) for pjit in "
     r"([0-9.eE+-]+) sec")
+_COMPILED_RE = re.compile(
+    r"Finished XLA compilation of (\S+) in ([0-9.eE+-]+) sec")
 
 
 @dataclass
@@ -151,9 +161,9 @@ class JitWatch:
         self._lock = threading.Lock()
         self.enabled = False
         self.events: dict[tuple, Event] = {}
-        self.totals = {"compiles": 0, "first_traces": 0,
-                       "retraces": 0, "transfers": 0,
-                       "transfers_offboundary": 0}
+        self.totals = {"compiles": 0, "compile_s": 0.0,
+                       "first_traces": 0, "retraces": 0,
+                       "transfers": 0, "transfers_offboundary": 0}
         self._handler = _Handler(self)
         self._saved_loggers: dict[str, tuple[int, bool]] = {}
         self._saved_explain: bool | None = None
@@ -254,7 +264,7 @@ class JitWatch:
         with self._lock:
             self.events.clear()
             for k in self.totals:
-                self.totals[k] = 0
+                self.totals[k] = type(self.totals[k])()
 
     # -- event plumbing ----------------------------------------------
 
@@ -288,11 +298,12 @@ class JitWatch:
         if m:
             now = time.perf_counter()
             site = _norm_site(m.group(1), int(m.group(2)))
-            fn = m.group(3)
-            # keep the category line ("never seen input type
-            # signature…"), drop the MISS header
-            why = msg.split("because:", 1)[-1].strip()
-            if "never seen function" in msg:
+            # keep the explanation ("different input types: ..."),
+            # drop the MISS header
+            why = m.group(3).strip()
+            mf = _MISS_FN_RE.search(why)
+            fn = mf.group(1) if mf else "unknown"
+            if why.startswith("never seen function"):
                 self._bump("first_trace", fn, "", site, last=why)
                 with self._lock:
                     self.totals["first_traces"] += 1
@@ -310,6 +321,13 @@ class JitWatch:
         if m:
             g_stats.record_ms("jit.trace_ms",
                               1000.0 * float(m.group(2)))
+            return
+        m = _COMPILED_RE.search(msg)
+        if m:
+            secs = float(m.group(2))
+            with self._lock:
+                self.totals["compile_s"] += secs
+            g_stats.record_ms("jit.compile_ms", 1000.0 * secs)
 
     def _note_transfer(self, fn: str, direction: str, args) -> None:
         now = time.perf_counter()

@@ -213,6 +213,18 @@ class TestRoofline:
         assert ent["verdict"] == "unknown"
         assert g_stats.snapshot()["counters"]["devwatch.cost_errors"] == 1
 
+    @pytest.mark.parametrize("kind,label", [
+        ("TPU v5 lite", "tpu-v5e"), ("TPU v5e", "tpu-v5e"),
+        ("TPU v5p", "tpu-v5p"), ("TPU v4", "tpu-v4")])
+    def test_peaks_row_matches_device_kind(self, kind, label):
+        assert devwatch.peaks_row(kind)[3] == label
+
+    def test_unmatched_device_kind_is_an_error(self):
+        """An accelerator outside the table never gets another
+        chip's (or the host's assumed) peaks."""
+        with pytest.raises(LookupError, match="TPU v9000"):
+            devwatch.peaks_row("TPU v9000")
+
     def test_real_query_populates_a_bucket(self, tmp_path):
         devwatch.enable()
         coll = _mk_coll(tmp_path, "rf", docs=3)

@@ -56,7 +56,9 @@ def test_retrace_attributed_to_call_site(watch):
     assert ev, snap["events"]
     # the site is THIS file and the miss explanation names the cause
     assert "test_jitwatch.py" in ev[0]["site"]
-    assert "never seen" in ev[0]["last"]
+    assert ev[0]["fn"] == "_probe"
+    assert "different input types" in ev[0]["last"]
+    assert snap["totals"]["compile_s"] > 0.0
     ctr = g_stats.snapshot()["counters"]
     assert any(k.startswith("jit.retrace.") for k in ctr)
 
@@ -171,3 +173,22 @@ def test_admin_jit_page(tmp_path, watch):
                    for k in js["counters"])
     finally:
         s.stop()
+
+
+def test_cold_trace_is_not_a_retrace(watch):
+    """A never-seen function's first trace is a first_trace naming the
+    function — the smoke's "retraces after warm-up" must not count a
+    route's first compile."""
+    @jax.jit
+    def _cold(x):
+        return x - 1
+
+    x = jnp.ones((4,), jnp.float32)
+    jitwatch.reset()
+    _cold(x)
+    snap = jitwatch.snapshot()
+    assert snap["totals"]["first_traces"] == 1
+    assert snap["totals"]["retraces"] == 0
+    ev = [e for e in snap["events"] if e["kind"] == "first_trace"]
+    assert ev[0]["fn"] == "_cold"
+    assert "test_jitwatch.py" in ev[0]["site"]

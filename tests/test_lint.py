@@ -31,13 +31,15 @@ def _lint_file(path: Path):
 
 class TestTreeIsClean:
     def test_zero_unwaived_findings_under_budget(self):
-        """The whole package + tools + tests lint clean in < 5s —
-        osselint is cheap enough to gate every PR."""
-        t0 = time.monotonic()
+        """The whole package + tools + tests lint clean in < 5s of the
+        linter's own CPU time (not wall time: the suite's other xdist
+        workers compile TPU kernels next to this test) — osselint is
+        cheap enough to gate every PR."""
+        t0 = time.process_time()
         files = osselint.iter_py_files(osselint.default_paths(ROOT),
                                        ROOT)
         findings = osselint.lint_files(files, ROOT)
-        elapsed = time.monotonic() - t0
+        elapsed = time.process_time() - t0
         assert not findings, "\n".join(
             f"{f.path}:{f.line}: {f.rule}: {f.msg}" for f in findings)
         assert len(files) > 100, "scan missed most of the tree?"

@@ -1,8 +1,8 @@
 """Sanitizer parity driver — run the native C++ cores under ASan+UBSan.
 
-Builds ``librdbcore.san.so`` / ``libdoccore.san.so`` (OSSE_NATIVE_SAN=1
-artifacts, ``-fsanitize=address,undefined``) and drives the same parity
-checks the tier-1 native tests run — merge/searchsorted vs. the numpy
+Builds ``librdbcore.san-<sha>.so`` / ``libdoccore.san-<sha>.so``
+(OSSE_NATIVE_SAN=1 artifacts, ``-fsanitize=address,undefined``) and
+drives the same parity checks the tier-1 native tests run — merge/searchsorted vs. the numpy
 reference, tokenize/hash vs. the Python tokenizer — so any heap
 overflow, use-after-free, or UB in ``rdbcore.cpp``/``doccore.cpp``
 aborts loudly instead of corrupting an index silently.
