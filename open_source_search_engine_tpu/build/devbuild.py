@@ -179,94 +179,98 @@ def _derive(tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg, n):
     idx = jnp.arange(N, dtype=jnp.int32)
     valid = idx < n
 
-    # --- pre-cap boundaries: term change + (term, doc) pair change ---
-    tch0 = _neq_prev(tid_lo, tid_hi) & valid
-    np0 = (_neq_prev(tid_lo, tid_hi) | _neq_prev(docidx)) & valid
-    occ = _seg_pos(np0, idx)
+    with jax.named_scope("build.term_directory"):
+        # --- pre-cap boundaries: term change + (term, doc) pair change ---
+        tch0 = _neq_prev(tid_lo, tid_hi) & valid
+        np0 = (_neq_prev(tid_lo, tid_hi) | _neq_prev(docidx)) & valid
+        occ = _seg_pos(np0, idx)
 
-    # df BEFORE the store cap (the Msg36 termfreq precompute): distinct
-    # (term, doc) pairs per term — integer scatter-add, deterministic
-    trank0 = jnp.cumsum(tch0.astype(jnp.int32)) - 1
-    n_terms = _count_true(tch0)
-    df = jnp.zeros(N, jnp.int32).at[
-        jnp.where(valid, trank0, N)].add(np0.astype(jnp.int32),
-                                         mode="drop")
-    d_tid_lo = jnp.zeros(N, _U32).at[
-        jnp.where(tch0, trank0, N)].set(tid_lo, mode="drop")
-    d_tid_hi = jnp.zeros(N, _U32).at[
-        jnp.where(tch0, trank0, N)].set(tid_hi, mode="drop")
+        # df BEFORE the store cap (the Msg36 termfreq precompute): distinct
+        # (term, doc) pairs per term — integer scatter-add, deterministic
+        trank0 = jnp.cumsum(tch0.astype(jnp.int32)) - 1
+        n_terms = _count_true(tch0)
+        df = jnp.zeros(N, jnp.int32).at[
+            jnp.where(valid, trank0, N)].add(np0.astype(jnp.int32),
+                                             mode="drop")
+        d_tid_lo = jnp.zeros(N, _U32).at[
+            jnp.where(tch0, trank0, N)].set(tid_lo, mode="drop")
+        d_tid_hi = jnp.zeros(N, _U32).at[
+            jnp.where(tch0, trank0, N)].set(tid_hi, mode="drop")
 
-    # --- store cap: scoring consumes ≤ P positions per pair ---
-    keep = (occ < P) & valid
-    oc = _lexsort(((~keep).astype(_U32),))
-    (tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg,
-     occ) = _compact(oc, tid_lo, tid_hi, docidx, hg, den, spam, wp,
-                     sr, lg, occ)
-    nk = _count_true(keep)
-    valid = idx < nk
+    with jax.named_scope("build.store_cap"):
+        # --- store cap: scoring consumes ≤ P positions per pair ---
+        keep = (occ < P) & valid
+        oc = _lexsort(((~keep).astype(_U32),))
+        (tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg,
+         occ) = _compact(oc, tid_lo, tid_hi, docidx, hg, den, spam, wp,
+                         sr, lg, occ)
+        nk = _count_true(keep)
+        valid = idx < nk
 
-    payload = jnp.where(
-        valid,
-        wp | (hg << 18) | (den << 22) | (spam << 27), _U32(0))
-    docc = jnp.where(
-        valid, (docidx.astype(_U32) << 4) | occ.astype(_U32), _U32(0))
+        payload = jnp.where(
+            valid,
+            wp | (hg << 18) | (den << 22) | (spam << 27), _U32(0))
+        docc = jnp.where(
+            valid, (docidx.astype(_U32) << 4) | occ.astype(_U32), _U32(0))
 
-    # --- doc-level runs: one entry per (term, doc) pair ---
-    newpair = (_neq_prev(tid_lo, tid_hi) | _neq_prev(docidx)) & valid
-    pair_id = jnp.cumsum(newpair.astype(jnp.int32)) - 1
-    n_pairs = _count_true(newpair)
-    pair_tgt = jnp.where(newpair, pair_id, N)
-    runstart = jnp.zeros(N, jnp.int32).at[pair_tgt].set(idx, mode="drop")
-    doc_col = jnp.zeros(N, jnp.int32).at[pair_tgt].set(
-        docidx, mode="drop")
-    count = jnp.zeros(N, jnp.int32).at[
-        jnp.where(valid, pair_id, N)].add(1, mode="drop")
-    cnt_col = jnp.minimum(count, P).astype(jnp.uint8)
+    with jax.named_scope("build.pair_runs"):
+        # --- doc-level runs: one entry per (term, doc) pair ---
+        newpair = (_neq_prev(tid_lo, tid_hi) | _neq_prev(docidx)) & valid
+        pair_id = jnp.cumsum(newpair.astype(jnp.int32)) - 1
+        n_pairs = _count_true(newpair)
+        pair_tgt = jnp.where(newpair, pair_id, N)
+        runstart = jnp.zeros(N, jnp.int32).at[pair_tgt].set(idx, mode="drop")
+        doc_col = jnp.zeros(N, jnp.int32).at[pair_tgt].set(
+            docidx, mode="drop")
+        count = jnp.zeros(N, jnp.int32).at[
+            jnp.where(valid, pair_id, N)].add(1, mode="drop")
+        cnt_col = jnp.minimum(count, P).astype(jnp.uint8)
 
-    tch = _neq_prev(tid_lo, tid_hi) & valid
-    trank = jnp.cumsum(tch.astype(jnp.int32)) - 1
-    term_tgt = jnp.where(tch, trank, N)
-    # pair index at a term start == searchsorted(runstart, tstart)
-    dir_dstart = jnp.zeros(N, jnp.int32).at[term_tgt].set(
-        pair_id, mode="drop")
-    dir_pstart = jnp.zeros(N, jnp.int32).at[term_tgt].set(
-        idx, mode="drop")
+        tch = _neq_prev(tid_lo, tid_hi) & valid
+        trank = jnp.cumsum(tch.astype(jnp.int32)) - 1
+        term_tgt = jnp.where(tch, trank, N)
+        # pair index at a term start == searchsorted(runstart, tstart)
+        dir_dstart = jnp.zeros(N, jnp.int32).at[term_tgt].set(
+            pair_id, mode="drop")
+        dir_pstart = jnp.zeros(N, jnp.int32).at[term_tgt].set(
+            idx, mode="drop")
 
-    # --- exact impacts (the _impacts_np candidate-rank-sum, on-chip) --
-    ps, il = _posscore(hg.astype(jnp.int32), den.astype(jnp.int32),
-                       spam.astype(jnp.int32))
-    mhg = jnp.asarray(weights.MAPPED_HASHGROUP)[hg.astype(jnp.int32)]
-    pid_key = jnp.where(valid, pair_id, jnp.int32(N))
-    o = _lexsort((mhg, pid_key))
-    ps_o, il_o, mh_o, pid_o, valid_o = _compact(
-        o, ps, il, mhg, pid_key, valid)
-    gch = (_neq_prev(pid_o) | _neq_prev(mh_o)) & valid_o
-    gid = jnp.cumsum(gch.astype(jnp.int32)) - 1
-    gmax = jnp.zeros(N, jnp.float32).at[
-        jnp.where(valid_o, gid, N)].max(ps_o, mode="drop")
-    cand = (il_o | gch) & valid_o
-    cval = jnp.where(il_o, ps_o, gmax[jnp.where(valid_o, gid, 0)])
-    pch = _neq_prev(pid_o) & valid_o
-    # rank candidates within each pair, descending cval: stable sort by
-    # (pair, non-candidate-last, ~bitcast(cval)) — monotone for f32 ≥ 0
-    ckey = ~lax.bitcast_convert_type(cval, _U32)
-    o3 = _lexsort((ckey, (~cand).astype(_U32), pid_o))
-    seg = _neq_prev(pid_o[o3])
-    rank = jnp.zeros(N, jnp.int32).at[o3].set(_seg_pos(seg, idx))
-    contrib = jnp.where(cand & (rank < weights.MAX_TOP), cval,
-                        jnp.float32(0.0))
-    # pair sums folded LEFT-TO-RIGHT like np.add.reduceat: position-q
-    # rows scatter to unique pair slots, then P sequential adds
-    q = _seg_pos(pch, idx)
-    acc = jnp.zeros(N, jnp.float32)
-    for j in range(P):
-        sel = (q == j) & valid_o
-        acc = acc + jnp.zeros(N, jnp.float32).at[
-            jnp.where(sel, pid_o, N)].set(contrib, mode="drop")
-    pvalid = idx < n_pairs
-    imp32 = jnp.where(pvalid, jnp.maximum(acc, jnp.float32(1e-30)),
-                      jnp.float32(0.0))
-    imp16 = jnp.where(pvalid, _demote(imp32), jnp.float16(0.0))
+    with jax.named_scope("build.impacts"):
+        # --- exact impacts (the _impacts_np candidate-rank-sum, on-chip) --
+        ps, il = _posscore(hg.astype(jnp.int32), den.astype(jnp.int32),
+                           spam.astype(jnp.int32))
+        mhg = jnp.asarray(weights.MAPPED_HASHGROUP)[hg.astype(jnp.int32)]
+        pid_key = jnp.where(valid, pair_id, jnp.int32(N))
+        o = _lexsort((mhg, pid_key))
+        ps_o, il_o, mh_o, pid_o, valid_o = _compact(
+            o, ps, il, mhg, pid_key, valid)
+        gch = (_neq_prev(pid_o) | _neq_prev(mh_o)) & valid_o
+        gid = jnp.cumsum(gch.astype(jnp.int32)) - 1
+        gmax = jnp.zeros(N, jnp.float32).at[
+            jnp.where(valid_o, gid, N)].max(ps_o, mode="drop")
+        cand = (il_o | gch) & valid_o
+        cval = jnp.where(il_o, ps_o, gmax[jnp.where(valid_o, gid, 0)])
+        pch = _neq_prev(pid_o) & valid_o
+        # rank candidates within each pair, descending cval: stable sort by
+        # (pair, non-candidate-last, ~bitcast(cval)) — monotone for f32 ≥ 0
+        ckey = ~lax.bitcast_convert_type(cval, _U32)
+        o3 = _lexsort((ckey, (~cand).astype(_U32), pid_o))
+        seg = _neq_prev(pid_o[o3])
+        rank = jnp.zeros(N, jnp.int32).at[o3].set(_seg_pos(seg, idx))
+        contrib = jnp.where(cand & (rank < weights.MAX_TOP), cval,
+                            jnp.float32(0.0))
+        # pair sums folded LEFT-TO-RIGHT like np.add.reduceat: position-q
+        # rows scatter to unique pair slots, then P sequential adds
+        q = _seg_pos(pch, idx)
+        acc = jnp.zeros(N, jnp.float32)
+        for j in range(P):
+            sel = (q == j) & valid_o
+            acc = acc + jnp.zeros(N, jnp.float32).at[
+                jnp.where(sel, pid_o, N)].set(contrib, mode="drop")
+        pvalid = idx < n_pairs
+        imp32 = jnp.where(pvalid, jnp.maximum(acc, jnp.float32(1e-30)),
+                          jnp.float32(0.0))
+        imp16 = jnp.where(pvalid, _demote(imp32), jnp.float16(0.0))
 
     return dict(
         payload=payload, docc=docc, pocc=jnp.where(
@@ -296,43 +300,46 @@ def _base_program(n0, n1lo, n1hi, n2lo, n2hi, rec, n):
     idx = jnp.arange(N, dtype=jnp.int32)
     valid = idx < n
 
-    # --- RdbMerge/Msg5: newest-wins dedup + tombstone annihilation ---
-    n0c = n0 & ~_U32(1)
-    negrec = _U32(0x7FFFFFFF) - rec
-    order = _lexsort((negrec, n0c, n1lo, n1hi, n2lo, n2hi,
-                      (~valid).astype(_U32)))
-    n0_s, n0c_s, l1, h1, l2, h2, valid_s = _compact(
-        order, n0, n0c, n1lo, n1hi, n2lo, n2hi, valid)
-    first = _neq_prev(n0c_s, l1, h1, l2, h2)
-    keep = first & (n0_s & _U32(1)).astype(bool) & valid_s
-    oc = _lexsort(((~keep).astype(_U32),))
-    n0_s, l1, h1, l2, h2 = _compact(oc, n0_s, l1, h1, l2, h2)
-    n_merged = _count_true(keep)
-    valid = idx < n_merged
+    with jax.named_scope("build.merge_runs"):
+        # --- RdbMerge/Msg5: newest-wins dedup + tombstone annihilation ---
+        n0c = n0 & ~_U32(1)
+        negrec = _U32(0x7FFFFFFF) - rec
+        order = _lexsort((negrec, n0c, n1lo, n1hi, n2lo, n2hi,
+                          (~valid).astype(_U32)))
+        n0_s, n0c_s, l1, h1, l2, h2, valid_s = _compact(
+            order, n0, n0c, n1lo, n1hi, n2lo, n2hi, valid)
+        first = _neq_prev(n0c_s, l1, h1, l2, h2)
+        keep = first & (n0_s & _U32(1)).astype(bool) & valid_s
+        oc = _lexsort(((~keep).astype(_U32),))
+        n0_s, l1, h1, l2, h2 = _compact(oc, n0_s, l1, h1, l2, h2)
+        n_merged = _count_true(keep)
+        valid = idx < n_merged
 
-    # --- posdb.unpack, bit-split (no uint64 on device) ---
-    tid_lo = (h2 << 16) | (l2 >> 16)
-    tid_hi = h2 >> 16
-    d_lo = ((l2 & _U32(0x3FF)) << 22) | (h1 >> 10)   # docid bits 0..31
-    d_hi = (l2 >> 10) & _U32(0x3F)                   # docid bits 32..37
-    sr = (h1 >> 5) & _U32(0xF)
-    lg = (h1 & _U32(0x1F)) | (((n0_s >> 3) & _U32(1)) << 5)
-    wp = l1 >> 14
-    hg = (l1 >> 10) & _U32(0xF)
-    spam = (l1 >> 6) & _U32(0xF)
-    den = (n0_s >> 11) & _U32(0x1F)
+    with jax.named_scope("build.unpack_keys"):
+        # --- posdb.unpack, bit-split (no uint64 on device) ---
+        tid_lo = (h2 << 16) | (l2 >> 16)
+        tid_hi = h2 >> 16
+        d_lo = ((l2 & _U32(0x3FF)) << 22) | (h1 >> 10)   # docid bits 0..31
+        d_hi = (l2 >> 10) & _U32(0x3F)                   # docid bits 32..37
+        sr = (h1 >> 5) & _U32(0xF)
+        lg = (h1 & _U32(0x1F)) | (((n0_s >> 3) & _U32(1)) << 5)
+        wp = l1 >> 14
+        hg = (l1 >> 10) & _U32(0xF)
+        spam = (l1 >> 6) & _U32(0xF)
+        den = (n0_s >> 11) & _U32(0x1F)
 
-    # --- docidx: rank of each distinct docid (np.unique collapse) ---
-    od = _lexsort((d_lo, d_hi, (~valid).astype(_U32)))
-    dl_s, dh_s, v_s = _compact(od, d_lo, d_hi, valid)
-    newdoc = _neq_prev(dl_s, dh_s) & v_s
-    docrank = jnp.cumsum(newdoc.astype(jnp.int32)) - 1
-    n_docs = _count_true(newdoc)
-    docidx = jnp.zeros(N, jnp.int32).at[od].set(docrank)
-    docidx = jnp.where(valid, docidx, 0)
-    doc_tgt = jnp.where(newdoc, docrank, N)
-    bd_lo = jnp.zeros(N, _U32).at[doc_tgt].set(dl_s, mode="drop")
-    bd_hi = jnp.zeros(N, _U32).at[doc_tgt].set(dh_s, mode="drop")
+    with jax.named_scope("build.docidx"):
+        # --- docidx: rank of each distinct docid (np.unique collapse) ---
+        od = _lexsort((d_lo, d_hi, (~valid).astype(_U32)))
+        dl_s, dh_s, v_s = _compact(od, d_lo, d_hi, valid)
+        newdoc = _neq_prev(dl_s, dh_s) & v_s
+        docrank = jnp.cumsum(newdoc.astype(jnp.int32)) - 1
+        n_docs = _count_true(newdoc)
+        docidx = jnp.zeros(N, jnp.int32).at[od].set(docrank)
+        docidx = jnp.where(valid, docidx, 0)
+        doc_tgt = jnp.where(newdoc, docrank, N)
+        bd_lo = jnp.zeros(N, _U32).at[doc_tgt].set(dl_s, mode="drop")
+        bd_hi = jnp.zeros(N, _U32).at[doc_tgt].set(dh_s, mode="drop")
 
     out = _derive(tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg,
                   n_merged)
@@ -353,10 +360,11 @@ def _delta_program(tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg, m):
     the host path — then the shared derive stage."""
     N = tid_lo.shape[0]
     valid = jnp.arange(N, dtype=jnp.int32) < m
-    o = _lexsort((wp, docidx, tid_lo, tid_hi,
-                  (~valid).astype(_U32)))
-    tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg = _compact(
-        o, tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg)
+    with jax.named_scope("build.sort_memtable"):
+        o = _lexsort((wp, docidx, tid_lo, tid_hi,
+                      (~valid).astype(_U32)))
+        tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg = _compact(
+            o, tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg)
     return _derive(tid_lo, tid_hi, docidx, hg, den, spam, wp, sr, lg, m)
 
 
@@ -391,19 +399,21 @@ def _cube_rows(payload, docc, starts, cum, D: int, n_positions: int,
     (docidx<<4 | occ), so the host ships only the per-slot (start,
     cumlen) descriptors — no posting-sized upload on either build
     path."""
-    R = starts.shape[0]
-    lane = jnp.arange(n_lanes, dtype=jnp.int32)
-    row = jnp.clip(jnp.searchsorted(cum, lane, side="right") - 1,
-                   0, R - 1).astype(jnp.int32)
-    src = jnp.clip(starts[row] + lane - cum[row], 0,
-                   payload.shape[0] - 1)
-    dv = docc[src]
-    occ = (dv & _U32(0xF)).astype(jnp.int32)
-    dxi = (dv >> 4).astype(jnp.int32)
-    dst = jnp.where(lane < cum[-1],
-                    (row * n_positions + occ) * D + dxi, total)
-    return jnp.zeros((total,), _U32).at[dst].set(payload[src],
-                                                 mode="drop")
+    with jax.named_scope("build.cube_row_targets"):
+        R = starts.shape[0]
+        lane = jnp.arange(n_lanes, dtype=jnp.int32)
+        row = jnp.clip(jnp.searchsorted(cum, lane, side="right") - 1,
+                       0, R - 1).astype(jnp.int32)
+        src = jnp.clip(starts[row] + lane - cum[row], 0,
+                       payload.shape[0] - 1)
+        dv = docc[src]
+        occ = (dv & _U32(0xF)).astype(jnp.int32)
+        dxi = (dv >> 4).astype(jnp.int32)
+        dst = jnp.where(lane < cum[-1],
+                        (row * n_positions + occ) * D + dxi, total)
+    with jax.named_scope("build.cube_row_scatter"):
+        return jnp.zeros((total,), _U32).at[dst].set(payload[src],
+                                                     mode="drop")
 
 
 # ---------------------------------------------------------------------------

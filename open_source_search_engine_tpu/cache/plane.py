@@ -402,8 +402,10 @@ class GenCache:
             }
 
     def __del__(self):  # noqa: D105 — drop the membudget gauge with us
+        # lock-free: the collector may run this on a thread that holds
+        # the budget's lock (it did, inside ``set_gauge``: a deadlock)
         try:
-            g_membudget.set_gauge(MEM_LABEL, self.name, 0)
+            g_membudget.forget_gauge(MEM_LABEL, self.name)
         except Exception:  # osselint: ignore[silent-except] — teardown
             pass
 

@@ -380,23 +380,25 @@ def _build_dense_rows(d_doc, d_imp, d_rs, d_cnt, starts, cum,
     flattened scatter over the doc-pair columns. Lane → row via
     searchsorted on the cumulative-length table; everything stays on
     device — the host ships only (starts, cum), a few KB."""
-    R = starts.shape[0]
-    lane = jnp.arange(n_lanes, dtype=jnp.int32)
-    row = jnp.clip(jnp.searchsorted(cum, lane, side="right") - 1,
-                   0, R - 1).astype(jnp.int32)
-    src = jnp.clip(starts[row] + lane - cum[row], 0,
-                   d_doc.shape[0] - 1)
-    valid = lane < cum[-1]
-    doc = d_doc[src].astype(jnp.int32)
-    # dst fits int32: V·D ≤ DENSE_BUDGET/7 < 2^31
-    dst = jnp.where(valid, row * D + doc, V * D)
-    imp = jnp.zeros((V * D,), d_imp.dtype).at[dst].set(
-        d_imp[src], mode="drop")
-    rs = jnp.zeros((V * D,), jnp.int32).at[dst].set(
-        d_rs[src], mode="drop")
-    cnt = jnp.zeros((V * D,), jnp.uint8).at[dst].set(
-        d_cnt[src], mode="drop")
-    return imp.reshape(V, D), rs, cnt
+    with jax.named_scope("build.dense_row_targets"):
+        R = starts.shape[0]
+        lane = jnp.arange(n_lanes, dtype=jnp.int32)
+        row = jnp.clip(jnp.searchsorted(cum, lane, side="right") - 1,
+                       0, R - 1).astype(jnp.int32)
+        src = jnp.clip(starts[row] + lane - cum[row], 0,
+                       d_doc.shape[0] - 1)
+        valid = lane < cum[-1]
+        doc = d_doc[src].astype(jnp.int32)
+        # dst fits int32: V·D ≤ DENSE_BUDGET/7 < 2^31
+        dst = jnp.where(valid, row * D + doc, V * D)
+    with jax.named_scope("build.dense_row_scatter"):
+        imp = jnp.zeros((V * D,), d_imp.dtype).at[dst].set(
+            d_imp[src], mode="drop")
+        rs = jnp.zeros((V * D,), jnp.int32).at[dst].set(
+            d_rs[src], mode="drop")
+        cnt = jnp.zeros((V * D,), jnp.uint8).at[dst].set(
+            d_cnt[src], mode="drop")
+        return imp.reshape(V, D), rs, cnt
 
 
 class _DeltaOverflow(Exception):
@@ -520,6 +522,9 @@ class DeviceIndex:
         #: two-phase (f1), direct-cube (fd) and generic full-cube (f2)
         #: kernels (escalation reruns not counted)
         self.route_counts = {"f1": 0, "fd": 0, "f2": 0}
+        #: dispatches by (program name, shape bucket), counted where
+        #: every wave program goes through (``_costed``)
+        self.dispatches: dict[tuple, int] = {}
         #: resident-plan cache (the termlist-cache role, RdbCache): the
         #: per-query host planning pass — directory binary searches, df
         #: lookups, slot planning, row layout — repeats byte-identically
@@ -2059,7 +2064,12 @@ class DeviceIndex:
         (kernel, shape-bucket) on first sight: devwatch pulls
         flops/bytes from ``lower().compile().cost_analysis()`` once
         per bucket (a dict hit afterwards), so every warmed shape has
-        a bandwidth/compute verdict next to the modeled wave bytes."""
+        a bandwidth/compute verdict next to the modeled wave bytes.
+        Every dispatch is counted by (program, shape bucket) in
+        ``self.dispatches``: which program each wave rode is the
+        index's own record (``/admin/device``), devwatch on or off."""
+        key = (name, tuple(int(x) for x in bucket))
+        self.dispatches[key] = self.dispatches.get(key, 0) + 1
         if devwatch.enabled():
             devwatch.note_cost(
                 name, bucket,
@@ -2346,12 +2356,14 @@ def _two_phase(d_payload, d_doc, d_imp, d_rs, d_cnt,
     # reassociation as before. The exponent shift is undone exactly
     # (power of two) on the f32 result.
     B, Ts, _ = d_sel.shape
-    ubb_mm = jax.lax.dot_general(
-        d_sel.reshape(B * Ts, V).astype(d_dense_imp.dtype), d_dense_imp,
-        (((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32).reshape(B, Ts, D) \
-        * jnp.float32(IMPACT_SCALE)
+    with jax.named_scope("f1.phase1_dense_bounds"):
+        ubb_mm = jax.lax.dot_general(
+            d_sel.reshape(B * Ts, V).astype(d_dense_imp.dtype),
+            d_dense_imp,
+            (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32).reshape(B, Ts, D) \
+            * jnp.float32(IMPACT_SCALE)
 
     def one(ubb, d_slot, d_group, d_base, d_quota, d_syn,
             s_start, s_len, s_group, s_base, s_quota, s_syn, s_isbase,
@@ -2362,176 +2374,179 @@ def _two_phase(d_payload, d_doc, d_imp, d_rs, d_cnt,
         t_ax = jnp.arange(T)
         live = ~d_dead                                        # [D]
 
-        # ---- phase 1: group upper bounds over the full doc axis
-        # (dense-row part arrives precomputed from the batch matmul) ----
-        dgate = (d_slot >= 0)
-        # sparse rows: one fused contiguous gather + bounded scatter-add
-        # into [T, D]. Base-row lanes of dead docs zero at GATHER time
-        # (a [Rs, Lsp] gather of the dead vector) so base and delta
-        # share one scatter target — half the [2, T, D] footprint the
-        # former base/delta target split paid per lane
-        lane = jnp.arange(lsp, dtype=jnp.int32)
-        sidx = s_start[:, None] + lane[None, :]               # [Rs, Lsp]
-        smask = lane[None, :] < s_len[:, None]
-        sidxc = jnp.clip(sidx, 0, M - 1)
-        sdoc = d_doc[sidxc]
-        # gather moves the packed f16 bytes; the cast to f32 (and the
-        # exact IMPACT_SCALE shift back) happens in registers so the
-        # scatter-add target stays full precision
-        simp = d_imp[sidxc].astype(jnp.float32) * jnp.float32(
-            IMPACT_SCALE)
-        srs = d_rs[sidxc]
-        scnt = d_cnt[sidxc]
-        sdead = d_dead[jnp.clip(sdoc, 0, D - 1)]              # [Rs, Lsp]
-        skeep = smask & ~(s_isbase[:, None] & sdead)
-        tgt = jnp.where(skeep, s_group[:, None] * D + sdoc, T * D)
-        ubs = jnp.zeros((T * D,), jnp.float32).at[tgt.ravel()].add(
-            jnp.where(skeep, simp, 0.0).ravel(), mode="drop"
-        ).reshape(T, D)
-        ub = ubb * live[None, :] + ubs                        # [T, D]
-        rstgt = jnp.where(
-            smask, jnp.arange(Rs, dtype=jnp.int32)[:, None] * D + sdoc,
-            Rs * D)
-        rsacc = jnp.zeros((Rs * D,), jnp.int32).at[rstgt.ravel()].set(
-            jnp.where(smask, srs, 0).ravel(), mode="drop")
-        cntacc = jnp.zeros((Rs * D,), jnp.uint8).at[rstgt.ravel()].set(
-            jnp.where(smask, scnt, jnp.uint8(0)).ravel(), mode="drop")
+        with jax.named_scope("f1.phase1_bounds"):
+            # ---- phase 1: group upper bounds over the full doc axis
+            # (dense-row part arrives precomputed from the batch matmul) ----
+            dgate = (d_slot >= 0)
+            # sparse rows: one fused contiguous gather + bounded scatter-add
+            # into [T, D]. Base-row lanes of dead docs zero at GATHER time
+            # (a [Rs, Lsp] gather of the dead vector) so base and delta
+            # share one scatter target — half the [2, T, D] footprint the
+            # former base/delta target split paid per lane
+            lane = jnp.arange(lsp, dtype=jnp.int32)
+            sidx = s_start[:, None] + lane[None, :]               # [Rs, Lsp]
+            smask = lane[None, :] < s_len[:, None]
+            sidxc = jnp.clip(sidx, 0, M - 1)
+            sdoc = d_doc[sidxc]
+            # gather moves the packed f16 bytes; the cast to f32 (and the
+            # exact IMPACT_SCALE shift back) happens in registers so the
+            # scatter-add target stays full precision
+            simp = d_imp[sidxc].astype(jnp.float32) * jnp.float32(
+                IMPACT_SCALE)
+            srs = d_rs[sidxc]
+            scnt = d_cnt[sidxc]
+            sdead = d_dead[jnp.clip(sdoc, 0, D - 1)]              # [Rs, Lsp]
+            skeep = smask & ~(s_isbase[:, None] & sdead)
+            tgt = jnp.where(skeep, s_group[:, None] * D + sdoc, T * D)
+            ubs = jnp.zeros((T * D,), jnp.float32).at[tgt.ravel()].add(
+                jnp.where(skeep, simp, 0.0).ravel(), mode="drop"
+            ).reshape(T, D)
+            ub = ubb * live[None, :] + ubs                        # [T, D]
+            rstgt = jnp.where(
+                smask, jnp.arange(Rs, dtype=jnp.int32)[:, None] * D + sdoc,
+                Rs * D)
+            rsacc = jnp.zeros((Rs * D,), jnp.int32).at[rstgt.ravel()].set(
+                jnp.where(smask, srs, 0).ravel(), mode="drop")
+            cntacc = jnp.zeros((Rs * D,), jnp.uint8).at[rstgt.ravel()].set(
+                jnp.where(smask, scnt, jnp.uint8(0)).ravel(), mode="drop")
 
-        # intersection + admissible min bound
-        present = ub > 0.0                                    # [T, D]
-        sc = counts
-        ubw = ub * (freqw * freqw)[:, None]
-        req_ok = jnp.all(jnp.where(required[:, None], present, True),
-                         axis=0)
-        neg_ok = ~jnp.any(jnp.where(negative[:, None], present, False),
-                          axis=0)
-        # the truth-table gate is a [D]-wide gather from a 1024-entry
-        # table — ~140 ms/wave at B=64 (scalar gather) — and non-
-        # boolean queries carry the all-true table, so the lookup is
-        # compiled out unless the wave really holds boolean queries
-        tok = presence_table_ok(present, table) if use_table else True
-        alive = (req_ok & neg_ok & tok
-                 & (jnp.arange(D) < n_docs_total))
-        if use_filter:
-            # numeric range gate (gbmin:/gbmax: — a host-ANDed boolean
-            # column over however many fields the query constrained)
-            alive = alive & d_filter
-        m1 = present & sc[:, None]
-        ubw_m = jnp.where(m1, ubw, big)
-        min_single_ub = jnp.min(ubw_m, axis=0)
-        from .scorer import MAX_PAIR_SPAN
-        if T <= MAX_PAIR_SPAN + 1:
-            # every pair is within the span, so the pair-bound min has
-            # a closed form: min over pairs of √(a_i·a_j) = √(min1·min2)
-            # over the two smallest present scored bounds — O(T·D)
-            # instead of the unrolled pair loop (~79 ms/wave at B=32)
-            npres = jnp.sum(m1, axis=0)                       # [D]
-            am = jnp.argmin(ubw_m, axis=0)                    # [D]
-            min2 = jnp.min(
-                jnp.where(t_ax[:, None] == am[None, :], big, ubw_m),
-                axis=0)
-            min_pair_ub = jnp.sqrt(min_single_ub * min2)
-            any_pair = npres >= 2
-        else:
-            min_pair_ub = jnp.full((D,), big)
-            any_pair = jnp.zeros((D,), bool)
-            for i in range(T):
-                for j in range(i + 1, min(i + 1 + MAX_PAIR_SPAN, T)):
-                    ok = present[i] & present[j] & sc[i] & sc[j]
-                    pu = jnp.sqrt(ubw[i] * ubw[j])
-                    min_pair_ub = jnp.where(
-                        ok, jnp.minimum(min_pair_ub, pu), min_pair_ub)
-                    any_pair = any_pair | ok
-        ubmin = jnp.minimum(jnp.where(any_pair, min_pair_ub, big),
-                            min_single_ub)
-        # per-doc filter-only fallback (mirrors scorer.min_scores)
-        ubmin = jnp.where(jnp.any(m1, axis=0), ubmin, 1.0)
-        mult = final_multipliers(d_siterank, d_doclang, qlang)
-        if use_sort:
-            # gbsortby: rank purely by the positive sort column — the
-            # per-doc "bound" IS the exact sort key, so selection is
-            # exact and the escalation check passes by construction
-            ubfinal = jnp.where(alive, d_sort, 0.0)
-        else:
-            ubfinal = jnp.where(alive, ubmin * mult * 1.00001, 0.0)
-        nm = jnp.sum(alive)
+            # intersection + admissible min bound
+            present = ub > 0.0                                    # [T, D]
+            sc = counts
+            ubw = ub * (freqw * freqw)[:, None]
+            req_ok = jnp.all(jnp.where(required[:, None], present, True),
+                             axis=0)
+            neg_ok = ~jnp.any(jnp.where(negative[:, None], present, False),
+                              axis=0)
+            # the truth-table gate is a [D]-wide gather from a 1024-entry
+            # table — ~140 ms/wave at B=64 (scalar gather) — and non-
+            # boolean queries carry the all-true table, so the lookup is
+            # compiled out unless the wave really holds boolean queries
+            tok = presence_table_ok(present, table) if use_table else True
+            alive = (req_ok & neg_ok & tok
+                     & (jnp.arange(D) < n_docs_total))
+            if use_filter:
+                # numeric range gate (gbmin:/gbmax: — a host-ANDed boolean
+                # column over however many fields the query constrained)
+                alive = alive & d_filter
+            m1 = present & sc[:, None]
+            ubw_m = jnp.where(m1, ubw, big)
+            min_single_ub = jnp.min(ubw_m, axis=0)
+            from .scorer import MAX_PAIR_SPAN
+            if T <= MAX_PAIR_SPAN + 1:
+                # every pair is within the span, so the pair-bound min has
+                # a closed form: min over pairs of √(a_i·a_j) = √(min1·min2)
+                # over the two smallest present scored bounds — O(T·D)
+                # instead of the unrolled pair loop (~79 ms/wave at B=32)
+                npres = jnp.sum(m1, axis=0)                       # [D]
+                am = jnp.argmin(ubw_m, axis=0)                    # [D]
+                min2 = jnp.min(
+                    jnp.where(t_ax[:, None] == am[None, :], big, ubw_m),
+                    axis=0)
+                min_pair_ub = jnp.sqrt(min_single_ub * min2)
+                any_pair = npres >= 2
+            else:
+                min_pair_ub = jnp.full((D,), big)
+                any_pair = jnp.zeros((D,), bool)
+                for i in range(T):
+                    for j in range(i + 1, min(i + 1 + MAX_PAIR_SPAN, T)):
+                        ok = present[i] & present[j] & sc[i] & sc[j]
+                        pu = jnp.sqrt(ubw[i] * ubw[j])
+                        min_pair_ub = jnp.where(
+                            ok, jnp.minimum(min_pair_ub, pu), min_pair_ub)
+                        any_pair = any_pair | ok
+            ubmin = jnp.minimum(jnp.where(any_pair, min_pair_ub, big),
+                                min_single_ub)
+            # per-doc filter-only fallback (mirrors scorer.min_scores)
+            ubmin = jnp.where(jnp.any(m1, axis=0), ubmin, 1.0)
+            mult = final_multipliers(d_siterank, d_doclang, qlang)
+            if use_sort:
+                # gbsortby: rank purely by the positive sort column — the
+                # per-doc "bound" IS the exact sort key, so selection is
+                # exact and the escalation check passes by construction
+                ubfinal = jnp.where(alive, d_sort, 0.0)
+            else:
+                ubfinal = jnp.where(alive, ubmin * mult * 1.00001, 0.0)
+            nm = jnp.sum(alive)
 
-        # candidate selection via top-8-per-block max-reduces:
-        # approx_max_k/top_k lower to sort-like programs costing
-        # hundreds of ms on a [B, 131072] axis (measured ~190 ms fixed
-        # per wave); _block_topn is ~2 ms and its missed_max feeds the
-        # SAME lossless escalation check
-        cval, cand, ub_missed = _block_topn(ubfinal, kappa)
+        with jax.named_scope("f1.select"):
+            # candidate selection via top-8-per-block max-reduces:
+            # approx_max_k/top_k lower to sort-like programs costing
+            # hundreds of ms on a [B, 131072] axis (measured ~190 ms fixed
+            # per wave); _block_topn is ~2 ms and its missed_max feeds the
+            # SAME lossless escalation check
+            cval, cand, ub_missed = _block_topn(ubfinal, kappa)
 
-        # phase 2 scores only the top-k2 BY BOUND: the (k2+1)-th-best
-        # bound folds into the missed-max, so an unscored candidate
-        # that could have ranked triggers the same lossless escalation.
-        # Phase-2 gather cost is ∝ rows·P·κ·B (the dominant wave cost
-        # at ~13-56 Melem/s scalar gather), so κ=2048 rungs score 128
-        # candidates, not 2048 — the selection rung and the scoring
-        # width decouple
-        kap2 = kappa
-        if k2 < kappa:
-            vals, idxs = jax.lax.top_k(cval, k2 + 1)
-            cand = cand[idxs[:k2]]
-            cval = vals[:k2]
-            ub_missed = jnp.maximum(ub_missed, vals[k2])
-            kap2 = k2
+            # phase 2 scores only the top-k2 BY BOUND: the (k2+1)-th-best
+            # bound folds into the missed-max, so an unscored candidate
+            # that could have ranked triggers the same lossless escalation.
+            # Phase-2 gather cost is ∝ rows·P·κ·B (the dominant wave cost
+            # at ~13-56 Melem/s scalar gather), so κ=2048 rungs score 128
+            # candidates, not 2048 — the selection rung and the scoring
+            # width decouple
+            kap2 = kappa
+            if k2 < kappa:
+                vals, idxs = jax.lax.top_k(cval, k2 + 1)
+                cand = cand[idxs[:k2]]
+                cval = vals[:k2]
+                ub_missed = jnp.maximum(ub_missed, vals[k2])
+                kap2 = k2
 
-        # ---- phase 2: exact scoring of the κ candidates ----
-        dead_c = d_dead[cand]                                 # [κ]
-        p_ax = jnp.arange(P, dtype=jnp.int32)[:, None]        # [P, 1]
-        cube = jnp.zeros((T, P, kap2), jnp.uint32)
-        pv = jnp.zeros((T, P, kap2), bool)
+        with jax.named_scope("f1.phase2_score"):
+            # ---- phase 2: exact scoring of the κ candidates ----
+            dead_c = d_dead[cand]                                 # [κ]
+            p_ax = jnp.arange(P, dtype=jnp.int32)[:, None]        # [P, 1]
+            cube = jnp.zeros((T, P, kap2), jnp.uint32)
+            pv = jnp.zeros((T, P, kap2), bool)
 
-        def add_row(cube, pv, rs, cnt_c, group, base, quota, syn,
-                    is_base):
-            cnt = cnt_c.astype(jnp.int32)                     # [κ]
-            cnt = jnp.where(is_base & dead_c, 0, cnt)
-            q = p_ax - base                                   # [P, κ]
-            sel = (q >= 0) & (q < jnp.minimum(cnt, quota)[None, :])
-            src = rs[None, :] + q
-            val = (d_payload[jnp.clip(src, 0, N - 1)]
-                   | (syn.astype(jnp.uint32) << jnp.uint32(31)))
-            gmask = (group == t_ax)[:, None, None]            # [T, 1, 1]
-            cube = cube + jnp.where(sel, val, jnp.uint32(0))[None] \
-                * gmask.astype(jnp.uint32)
-            pv = pv | (sel[None] & gmask)
-            return cube, pv
+            def add_row(cube, pv, rs, cnt_c, group, base, quota, syn,
+                        is_base):
+                cnt = cnt_c.astype(jnp.int32)                     # [κ]
+                cnt = jnp.where(is_base & dead_c, 0, cnt)
+                q = p_ax - base                                   # [P, κ]
+                sel = (q >= 0) & (q < jnp.minimum(cnt, quota)[None, :])
+                src = rs[None, :] + q
+                val = (d_payload[jnp.clip(src, 0, N - 1)]
+                       | (syn.astype(jnp.uint32) << jnp.uint32(31)))
+                gmask = (group == t_ax)[:, None, None]            # [T, 1, 1]
+                cube = cube + jnp.where(sel, val, jnp.uint32(0))[None] \
+                    * gmask.astype(jnp.uint32)
+                pv = pv | (sel[None] & gmask)
+                return cube, pv
 
-        dslotc = jnp.clip(d_slot, 0, V - 1)[:, None] * D + cand[None, :]
-        dense_rs_c = d_dense_rs[dslotc]
-        dense_cnt_c = d_dense_cnt[dslotc]
-        for r in range(Rd):
-            rs_c = jnp.where(dgate[r], dense_rs_c[r], 0)
-            cnt_c = jnp.where(dgate[r], dense_cnt_c[r], jnp.uint8(0))
-            cube, pv = add_row(cube, pv, rs_c, cnt_c, d_group[r],
-                               d_base[r], d_quota[r], d_syn[r], True)
-        for r in range(Rs):
-            cube, pv = add_row(cube, pv, rsacc[r * D + cand],
-                               cntacc[r * D + cand], s_group[r],
-                               s_base[r], s_quota[r], s_syn[r],
-                               s_isbase[r])
+            dslotc = jnp.clip(d_slot, 0, V - 1)[:, None] * D + cand[None, :]
+            dense_rs_c = d_dense_rs[dslotc]
+            dense_cnt_c = d_dense_cnt[dslotc]
+            for r in range(Rd):
+                rs_c = jnp.where(dgate[r], dense_rs_c[r], 0)
+                cnt_c = jnp.where(dgate[r], dense_cnt_c[r], jnp.uint8(0))
+                cube, pv = add_row(cube, pv, rs_c, cnt_c, d_group[r],
+                                   d_base[r], d_quota[r], d_syn[r], True)
+            for r in range(Rs):
+                cube, pv = add_row(cube, pv, rsacc[r * D + cand],
+                                   cntacc[r * D + cand], s_group[r],
+                                   s_base[r], s_quota[r], s_syn[r],
+                                   s_isbase[r])
 
-        min_sc, present2 = min_scores(cube, pv, freqw, sc)
-        req_ok2 = jnp.all(jnp.where(required[:, None], present2, True),
-                          axis=0)
-        neg_ok2 = ~jnp.any(jnp.where(negative[:, None], present2, False),
-                           axis=0)
-        tok2 = presence_table_ok(present2, table) if use_table \
-            else True
-        match2 = (req_ok2 & neg_ok2 & tok2
-                  & (cval > 0.0) & (min_sc < big))
-        if use_sort:
-            final = jnp.where(match2, d_sort[cand], 0.0)
-        else:
-            final = jnp.where(
-                match2,
-                min_sc * final_multipliers(d_siterank[cand],
-                                           d_doclang[cand], qlang),
-                0.0)
-        ts, tl = jax.lax.top_k(final, k2)
-        ti = cand[tl]
+            min_sc, present2 = min_scores(cube, pv, freqw, sc)
+            req_ok2 = jnp.all(jnp.where(required[:, None], present2, True),
+                              axis=0)
+            neg_ok2 = ~jnp.any(jnp.where(negative[:, None], present2, False),
+                               axis=0)
+            tok2 = presence_table_ok(present2, table) if use_table \
+                else True
+            match2 = (req_ok2 & neg_ok2 & tok2
+                      & (cval > 0.0) & (min_sc < big))
+            if use_sort:
+                final = jnp.where(match2, d_sort[cand], 0.0)
+            else:
+                final = jnp.where(
+                    match2,
+                    min_sc * final_multipliers(d_siterank[cand],
+                                               d_doclang[cand], qlang),
+                    0.0)
+            ts, tl = jax.lax.top_k(final, k2)
+            ti = cand[tl]
         return jnp.concatenate([
             jnp.atleast_1d(nm.astype(jnp.uint32)),
             jax.lax.bitcast_convert_type(jnp.atleast_1d(ub_missed),
@@ -2583,85 +2598,89 @@ def _full_cube(d_payload, d_docc, d_cube, d_dense_cnt,
         live = ~d_dead
         p_ax = jnp.arange(P, dtype=jnp.int32)[:, None]        # [P, 1]
 
-        cube = jnp.zeros((T, P, D), jnp.uint32)
-        pv = jnp.zeros((T, P, D), bool)
-        # materialized cube rows: slice + count-mask (cube rows are
-        # always base postings, so the dead vector masks them)
-        V = d_dense_cnt.shape[0] // D
-        for r in range(Rc):
-            gate = c_slot[r] >= 0
-            row = jax.lax.dynamic_slice(
-                d_cube, (jnp.clip(c_slot[r], 0, VcPD // (P * D) - 1)
-                         * P * D,), (P * D,)).reshape(P, D)
-            cnt = jax.lax.dynamic_slice(
-                d_dense_cnt, (jnp.clip(c_dslot[r], 0, V - 1) * D,),
-                (D,)).astype(jnp.int32)
-            # shift the row to the sublist's slot range [base,
-            # base+quota): out[p] = row[p - base]. Done as a contiguous
-            # dynamic_slice on a zero-padded [2P, D] image — a traced-
-            # index take here lowers to a ~P·D scalar gather per row
-            # per lane, measured as THE dominant F2 cost (~270 ms/wave)
-            q = p_ax[:, 0] - c_base[r]                    # [P]
-            padded = jnp.concatenate(
-                [jnp.zeros((P, D), row.dtype), row], axis=0)
-            row = jax.lax.dynamic_slice(
-                padded,
-                (jnp.int32(P) - jnp.clip(c_base[r], 0, P)
-                 .astype(jnp.int32), jnp.int32(0)), (P, D))
-            pvr = ((q[:, None] >= 0)
-                   & (q[:, None]
-                      < jnp.minimum(cnt, c_quota[r])[None, :])
-                   & live[None, :] & gate)
-            val = row | (c_syn[r].astype(jnp.uint32) << jnp.uint32(31))
-            gmask = (c_group[r] == t_ax)[:, None, None]
-            cube = cube + jnp.where(pvr, val, jnp.uint32(0))[None] \
-                * gmask.astype(jnp.uint32)
-            pv = pv | (pvr[None] & gmask)
-        # posting-granular scatter rows (bigrams, deltas, small terms)
-        lane = jnp.arange(lpost, dtype=jnp.int32)
-        idx = p_start[:, None] + lane[None, :]                # [Rp, Lp]
-        m = lane[None, :] < p_len[:, None]
-        idxc = jnp.clip(idx, 0, N - 1)
-        docc = d_docc[idxc]
-        doc = (docc >> jnp.uint32(_OCC_BITS)).astype(jnp.int32)
-        occ = (docc & jnp.uint32(_OCC_MASK)).astype(jnp.int32)
-        pay = (d_payload[idxc]
-               | (p_syn[:, None].astype(jnp.uint32) << jnp.uint32(31)))
-        dead_l = d_dead[jnp.clip(doc, 0, D - 1)]
-        ok = (m & (occ < p_quota[:, None])
-              & ~(dead_l & p_isbase[:, None]))
-        slot = p_base[:, None] + occ
-        tgt = jnp.where(ok, (p_group[:, None] * P + slot) * D + doc,
-                        T * P * D)
-        cube = cube.reshape(-1).at[tgt.ravel()].add(
-            jnp.where(ok, pay, jnp.uint32(0)).ravel(), mode="drop"
-        ).reshape(T, P, D)
-        pv = pv.reshape(-1).at[tgt.ravel()].set(
-            ok.ravel(), mode="drop").reshape(T, P, D)
+        with jax.named_scope("f2.cube_rows"):
+            cube = jnp.zeros((T, P, D), jnp.uint32)
+            pv = jnp.zeros((T, P, D), bool)
+            # materialized cube rows: slice + count-mask (cube rows are
+            # always base postings, so the dead vector masks them)
+            V = d_dense_cnt.shape[0] // D
+            for r in range(Rc):
+                gate = c_slot[r] >= 0
+                row = jax.lax.dynamic_slice(
+                    d_cube, (jnp.clip(c_slot[r], 0, VcPD // (P * D) - 1)
+                             * P * D,), (P * D,)).reshape(P, D)
+                cnt = jax.lax.dynamic_slice(
+                    d_dense_cnt, (jnp.clip(c_dslot[r], 0, V - 1) * D,),
+                    (D,)).astype(jnp.int32)
+                # shift the row to the sublist's slot range [base,
+                # base+quota): out[p] = row[p - base]. Done as a contiguous
+                # dynamic_slice on a zero-padded [2P, D] image — a traced-
+                # index take here lowers to a ~P·D scalar gather per row
+                # per lane, measured as THE dominant F2 cost (~270 ms/wave)
+                q = p_ax[:, 0] - c_base[r]                    # [P]
+                padded = jnp.concatenate(
+                    [jnp.zeros((P, D), row.dtype), row], axis=0)
+                row = jax.lax.dynamic_slice(
+                    padded,
+                    (jnp.int32(P) - jnp.clip(c_base[r], 0, P)
+                     .astype(jnp.int32), jnp.int32(0)), (P, D))
+                pvr = ((q[:, None] >= 0)
+                       & (q[:, None]
+                          < jnp.minimum(cnt, c_quota[r])[None, :])
+                       & live[None, :] & gate)
+                val = row | (c_syn[r].astype(jnp.uint32) << jnp.uint32(31))
+                gmask = (c_group[r] == t_ax)[:, None, None]
+                cube = cube + jnp.where(pvr, val, jnp.uint32(0))[None] \
+                    * gmask.astype(jnp.uint32)
+                pv = pv | (pvr[None] & gmask)
+        with jax.named_scope("f2.tail_scatter"):
+            # posting-granular scatter rows (bigrams, deltas, small terms)
+            lane = jnp.arange(lpost, dtype=jnp.int32)
+            idx = p_start[:, None] + lane[None, :]                # [Rp, Lp]
+            m = lane[None, :] < p_len[:, None]
+            idxc = jnp.clip(idx, 0, N - 1)
+            docc = d_docc[idxc]
+            doc = (docc >> jnp.uint32(_OCC_BITS)).astype(jnp.int32)
+            occ = (docc & jnp.uint32(_OCC_MASK)).astype(jnp.int32)
+            pay = (d_payload[idxc]
+                   | (p_syn[:, None].astype(jnp.uint32) << jnp.uint32(31)))
+            dead_l = d_dead[jnp.clip(doc, 0, D - 1)]
+            ok = (m & (occ < p_quota[:, None])
+                  & ~(dead_l & p_isbase[:, None]))
+            slot = p_base[:, None] + occ
+            tgt = jnp.where(ok, (p_group[:, None] * P + slot) * D + doc,
+                            T * P * D)
+            cube = cube.reshape(-1).at[tgt.ravel()].add(
+                jnp.where(ok, pay, jnp.uint32(0)).ravel(), mode="drop"
+            ).reshape(T, P, D)
+            pv = pv.reshape(-1).at[tgt.ravel()].set(
+                ok.ravel(), mode="drop").reshape(T, P, D)
 
-        min_sc, present = min_scores(cube, pv, freqw, counts)
-        req_ok = jnp.all(jnp.where(required[:, None], present, True),
-                         axis=0)
-        neg_ok = ~jnp.any(jnp.where(negative[:, None], present, False),
-                          axis=0)
-        tok = presence_table_ok(present, table) if use_table else True
-        match = (req_ok & neg_ok & tok
-                 & (jnp.arange(D) < n_docs_total) & (min_sc < big))
-        if use_filter:
-            match = match & d_filter
-        if use_sort:
-            final = jnp.where(match, d_sort, 0.0)
-        else:
-            final = jnp.where(
-                match, min_sc * final_multipliers(d_siterank, d_doclang,
-                                                  qlang), 0.0)
-        nm = jnp.sum(match)
-        # block-winners then a cheap exact top-k over the winners;
-        # escalation reruns with 4x the blocks, terminal at n_sel == D
-        # where every doc is selected and missed is exactly 0
-        w_vals, w_idx, missed = _block_topn(final, min(n_sel, D))
-        ts, tl = jax.lax.top_k(w_vals, min(k2, min(n_sel, D)))
-        ti = w_idx[tl]
+        with jax.named_scope("f2.score_match"):
+            min_sc, present = min_scores(cube, pv, freqw, counts)
+            req_ok = jnp.all(jnp.where(required[:, None], present, True),
+                             axis=0)
+            neg_ok = ~jnp.any(jnp.where(negative[:, None], present, False),
+                              axis=0)
+            tok = presence_table_ok(present, table) if use_table else True
+            match = (req_ok & neg_ok & tok
+                     & (jnp.arange(D) < n_docs_total) & (min_sc < big))
+            if use_filter:
+                match = match & d_filter
+            if use_sort:
+                final = jnp.where(match, d_sort, 0.0)
+            else:
+                final = jnp.where(
+                    match, min_sc * final_multipliers(d_siterank, d_doclang,
+                                                      qlang), 0.0)
+            nm = jnp.sum(match)
+        with jax.named_scope("f2.topk"):
+            # block-winners then a cheap exact top-k over the winners;
+            # escalation reruns with 4x the blocks, terminal at n_sel == D
+            # where every doc is selected and missed is exactly 0
+            w_vals, w_idx, missed = _block_topn(final, min(n_sel, D))
+            ts, tl = jax.lax.top_k(w_vals, min(k2, min(n_sel, D)))
+            ti = w_idx[tl]
         return jnp.concatenate([
             jnp.atleast_1d(nm.astype(jnp.uint32)),
             jax.lax.bitcast_convert_type(jnp.atleast_1d(missed),
@@ -2732,57 +2751,61 @@ def _direct_cube(d_cube, d_payload, d_docc, d_siterank,
         T = required.shape[0]
         live = ~d_dead
         sc = counts
-        rows = quarter_rows[
-            jnp.clip(g_quarter, 0, Vc * 4 - 1)].reshape(T, 4, P4, D)
-        synbit = (g_qsyn.astype(jnp.uint32)
-                  << jnp.uint32(31))[:, :, None, None]
-        rows = jnp.where(rows != 0, rows | synbit, rows)
-        rows = rows.reshape(T, P, D)
-        pvr = (rows != 0) & live[None, None, :]               # [T, P, D]
-        # dead docs' base values must not pollute scatter-adds below
-        cube = jnp.where(pvr, rows, jnp.uint32(0))
-        # posting-granular scatter tail (bigrams, deltas, small terms —
-        # same semantics as _full_cube's scatter block)
-        lane = jnp.arange(lpost, dtype=jnp.int32)
-        idx = p_start[:, None] + lane[None, :]                # [Rp, Lp]
-        m = lane[None, :] < p_len[:, None]
-        idxc = jnp.clip(idx, 0, N - 1)
-        docc = d_docc[idxc]
-        doc = (docc >> jnp.uint32(_OCC_BITS)).astype(jnp.int32)
-        occ = (docc & jnp.uint32(_OCC_MASK)).astype(jnp.int32)
-        pay = (d_payload[idxc]
-               | (p_syn[:, None].astype(jnp.uint32) << jnp.uint32(31)))
-        dead_l = d_dead[jnp.clip(doc, 0, D - 1)]
-        ok = (m & (occ < p_quota[:, None])
-              & ~(dead_l & p_isbase[:, None]))
-        slot = p_base[:, None] + occ
-        tgt = jnp.where(ok, (p_group[:, None] * P + slot) * D + doc,
-                        T * P * D)
-        cube = cube.reshape(-1).at[tgt.ravel()].add(
-            jnp.where(ok, pay, jnp.uint32(0)).ravel(), mode="drop"
-        ).reshape(T, P, D)
-        pvr = pvr.reshape(-1).at[tgt.ravel()].set(
-            ok.ravel(), mode="drop").reshape(T, P, D)
-        min_sc, present = min_scores(cube, pvr, freqw, sc)
-        req_ok = jnp.all(jnp.where(required[:, None], present, True),
-                         axis=0)
-        neg_ok = ~jnp.any(jnp.where(negative[:, None], present, False),
-                          axis=0)
-        tok = presence_table_ok(present, table) if use_table else True
-        match = (req_ok & neg_ok & tok
-                 & (jnp.arange(D) < n_docs_total) & (min_sc < big))
-        if use_filter:
-            match = match & d_filter
-        if use_sort:
-            final = jnp.where(match, d_sort, 0.0)
-        else:
-            final = jnp.where(
-                match, min_sc * final_multipliers(d_siterank, d_doclang,
-                                                  qlang), 0.0)
-        nm = jnp.sum(match)
-        w_vals, w_idx, missed = _block_topn(final, min(n_sel, D))
-        ts, tl = jax.lax.top_k(w_vals, min(k2, n_sel, D))
-        ti = w_idx[tl]
+        with jax.named_scope("fd.cube_rows"):
+            rows = quarter_rows[
+                jnp.clip(g_quarter, 0, Vc * 4 - 1)].reshape(T, 4, P4, D)
+            synbit = (g_qsyn.astype(jnp.uint32)
+                      << jnp.uint32(31))[:, :, None, None]
+            rows = jnp.where(rows != 0, rows | synbit, rows)
+            rows = rows.reshape(T, P, D)
+            pvr = (rows != 0) & live[None, None, :]               # [T, P, D]
+            # dead docs' base values must not pollute scatter-adds below
+            cube = jnp.where(pvr, rows, jnp.uint32(0))
+        with jax.named_scope("fd.tail_scatter"):
+            # posting-granular scatter tail (bigrams, deltas, small terms —
+            # same semantics as _full_cube's scatter block)
+            lane = jnp.arange(lpost, dtype=jnp.int32)
+            idx = p_start[:, None] + lane[None, :]                # [Rp, Lp]
+            m = lane[None, :] < p_len[:, None]
+            idxc = jnp.clip(idx, 0, N - 1)
+            docc = d_docc[idxc]
+            doc = (docc >> jnp.uint32(_OCC_BITS)).astype(jnp.int32)
+            occ = (docc & jnp.uint32(_OCC_MASK)).astype(jnp.int32)
+            pay = (d_payload[idxc]
+                   | (p_syn[:, None].astype(jnp.uint32) << jnp.uint32(31)))
+            dead_l = d_dead[jnp.clip(doc, 0, D - 1)]
+            ok = (m & (occ < p_quota[:, None])
+                  & ~(dead_l & p_isbase[:, None]))
+            slot = p_base[:, None] + occ
+            tgt = jnp.where(ok, (p_group[:, None] * P + slot) * D + doc,
+                            T * P * D)
+            cube = cube.reshape(-1).at[tgt.ravel()].add(
+                jnp.where(ok, pay, jnp.uint32(0)).ravel(), mode="drop"
+            ).reshape(T, P, D)
+            pvr = pvr.reshape(-1).at[tgt.ravel()].set(
+                ok.ravel(), mode="drop").reshape(T, P, D)
+        with jax.named_scope("fd.score_match"):
+            min_sc, present = min_scores(cube, pvr, freqw, sc)
+            req_ok = jnp.all(jnp.where(required[:, None], present, True),
+                             axis=0)
+            neg_ok = ~jnp.any(jnp.where(negative[:, None], present, False),
+                              axis=0)
+            tok = presence_table_ok(present, table) if use_table else True
+            match = (req_ok & neg_ok & tok
+                     & (jnp.arange(D) < n_docs_total) & (min_sc < big))
+            if use_filter:
+                match = match & d_filter
+            if use_sort:
+                final = jnp.where(match, d_sort, 0.0)
+            else:
+                final = jnp.where(
+                    match, min_sc * final_multipliers(d_siterank, d_doclang,
+                                                      qlang), 0.0)
+            nm = jnp.sum(match)
+        with jax.named_scope("fd.topk"):
+            w_vals, w_idx, missed = _block_topn(final, min(n_sel, D))
+            ts, tl = jax.lax.top_k(w_vals, min(k2, n_sel, D))
+            ti = w_idx[tl]
         return jnp.concatenate([
             jnp.atleast_1d(nm.astype(jnp.uint32)),
             jax.lax.bitcast_convert_type(jnp.atleast_1d(missed),
@@ -2825,69 +2848,73 @@ def _direct_cube_fused(d_cube, d_payload, d_docc, d_siterank,
     # only applies the dead mask to the resident quarters ----
     def tail_of(p_start, p_len, p_quota, p_group, p_base, p_syn,
                 p_isbase):
-        lane = jnp.arange(lpost, dtype=jnp.int32)
-        idx = p_start[:, None] + lane[None, :]
-        m = lane[None, :] < p_len[:, None]
-        idxc = jnp.clip(idx, 0, N - 1)
-        docc = d_docc[idxc]
-        doc = (docc >> jnp.uint32(_OCC_BITS)).astype(jnp.int32)
-        occ = (docc & jnp.uint32(_OCC_MASK)).astype(jnp.int32)
-        pay = (d_payload[idxc]
-               | (p_syn[:, None].astype(jnp.uint32) << jnp.uint32(31)))
-        dead_l = d_dead[jnp.clip(doc, 0, D - 1)]
-        ok = (m & (occ < p_quota[:, None])
-              & ~(dead_l & p_isbase[:, None]))
-        slot = p_base[:, None] + occ
-        tgt = jnp.where(ok, (p_group[:, None] * P + slot) * D + doc,
-                        T * P * D)
-        return jnp.zeros((T * P * D,), jnp.uint32).at[tgt.ravel()].add(
-            jnp.where(ok, pay, jnp.uint32(0)).ravel(), mode="drop"
-        ).reshape(T, P, D)
+        with jax.named_scope("fd.tail_scatter"):
+            lane = jnp.arange(lpost, dtype=jnp.int32)
+            idx = p_start[:, None] + lane[None, :]
+            m = lane[None, :] < p_len[:, None]
+            idxc = jnp.clip(idx, 0, N - 1)
+            docc = d_docc[idxc]
+            doc = (docc >> jnp.uint32(_OCC_BITS)).astype(jnp.int32)
+            occ = (docc & jnp.uint32(_OCC_MASK)).astype(jnp.int32)
+            pay = (d_payload[idxc]
+                   | (p_syn[:, None].astype(jnp.uint32) << jnp.uint32(31)))
+            dead_l = d_dead[jnp.clip(doc, 0, D - 1)]
+            ok = (m & (occ < p_quota[:, None])
+                  & ~(dead_l & p_isbase[:, None]))
+            slot = p_base[:, None] + occ
+            tgt = jnp.where(ok, (p_group[:, None] * P + slot) * D + doc,
+                            T * P * D)
+            return jnp.zeros((T * P * D,), jnp.uint32).at[tgt.ravel()].add(
+                jnp.where(ok, pay, jnp.uint32(0)).ravel(), mode="drop"
+            ).reshape(T, P, D)
 
     from .pallas_scores import fd_scores_fused_notail
     interp = jax.default_backend() == "cpu"
-    if lpost == 0:
-        # pure quarter-row wave: no tail cube at all
-        ms, presbits = fd_scores_fused_notail(
-            g_quarter.reshape(B, T * 4),
-            g_qsyn.reshape(B, T * 4).astype(jnp.int32),
-            d_cube, d_dead.astype(jnp.int32).reshape(1, D),
-            freqw, counts.astype(jnp.float32), T=T, P=P,
-            interpret=interp)
-    else:
-        tails = jax.vmap(tail_of)(p_start, p_len, p_quota, p_group,
-                                  p_base, p_syn, p_isbase)
-        ms, presbits = fd_scores_fused(
-            g_quarter.reshape(B, T * 4),
-            g_qsyn.reshape(B, T * 4).astype(jnp.int32),
-            d_cube, tails, d_dead.astype(jnp.int32).reshape(1, D),
-            freqw, counts.astype(jnp.float32), T=T, P=P,
-            interpret=interp)
+    with jax.named_scope("fd.fused_score"):
+        if lpost == 0:
+            # pure quarter-row wave: no tail cube at all
+            ms, presbits = fd_scores_fused_notail(
+                g_quarter.reshape(B, T * 4),
+                g_qsyn.reshape(B, T * 4).astype(jnp.int32),
+                d_cube, d_dead.astype(jnp.int32).reshape(1, D),
+                freqw, counts.astype(jnp.float32), T=T, P=P,
+                interpret=interp)
+        else:
+            tails = jax.vmap(tail_of)(p_start, p_len, p_quota, p_group,
+                                      p_base, p_syn, p_isbase)
+            ms, presbits = fd_scores_fused(
+                g_quarter.reshape(B, T * 4),
+                g_qsyn.reshape(B, T * 4).astype(jnp.int32),
+                d_cube, tails, d_dead.astype(jnp.int32).reshape(1, D),
+                freqw, counts.astype(jnp.float32), T=T, P=P,
+                interpret=interp)
 
     # ---- XLA tail: match gates + selection (cheap [T, D]/[D] work) --
     def finish(ms, bits, freqw, required, negative, counts, table,
                qlang):
-        t_ax = jnp.arange(T, dtype=jnp.int32)
-        present = ((bits[None, :] >> t_ax[:, None]) & 1) > 0  # [T, D]
-        req_ok = jnp.all(jnp.where(required[:, None], present, True),
-                         axis=0)
-        neg_ok = ~jnp.any(jnp.where(negative[:, None], present,
-                                    False), axis=0)
-        tok = presence_table_ok(present, table) if use_table else True
-        match = (req_ok & neg_ok & tok
-                 & (jnp.arange(D) < n_docs_total) & (ms < big))
-        if use_filter:
-            match = match & d_filter
-        if use_sort:
-            final = jnp.where(match, d_sort, 0.0)
-        else:
-            final = jnp.where(
-                match, ms * final_multipliers(d_siterank, d_doclang,
-                                              qlang), 0.0)
-        nm = jnp.sum(match)
-        w_vals, w_idx, missed = _block_topn(final, min(n_sel, D))
-        ts, tl = jax.lax.top_k(w_vals, min(k2, n_sel, D))
-        ti = w_idx[tl]
+        with jax.named_scope("fd.match"):
+            t_ax = jnp.arange(T, dtype=jnp.int32)
+            present = ((bits[None, :] >> t_ax[:, None]) & 1) > 0  # [T, D]
+            req_ok = jnp.all(jnp.where(required[:, None], present, True),
+                             axis=0)
+            neg_ok = ~jnp.any(jnp.where(negative[:, None], present,
+                                        False), axis=0)
+            tok = presence_table_ok(present, table) if use_table else True
+            match = (req_ok & neg_ok & tok
+                     & (jnp.arange(D) < n_docs_total) & (ms < big))
+            if use_filter:
+                match = match & d_filter
+            if use_sort:
+                final = jnp.where(match, d_sort, 0.0)
+            else:
+                final = jnp.where(
+                    match, ms * final_multipliers(d_siterank, d_doclang,
+                                                  qlang), 0.0)
+            nm = jnp.sum(match)
+        with jax.named_scope("fd.topk"):
+            w_vals, w_idx, missed = _block_topn(final, min(n_sel, D))
+            ts, tl = jax.lax.top_k(w_vals, min(k2, n_sel, D))
+            ti = w_idx[tl]
         return jnp.concatenate([
             jnp.atleast_1d(nm.astype(jnp.uint32)),
             jax.lax.bitcast_convert_type(jnp.atleast_1d(missed),

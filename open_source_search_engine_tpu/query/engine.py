@@ -513,10 +513,15 @@ def search_device_batch(coll: Collection, queries, *, topk: int = 10,
         return rec
 
     out = []
-    t_res = time.perf_counter()
     lock_ctx = results_lock if results_lock is not None \
         else contextlib.nullcontext()
-    with lock_ctx:
+    # the container keeps its start (lock wait included) and is a span
+    # on the profiler's clock; its two parts, cut at one clock reading,
+    # are the request's stages
+    batch_span = trace.timed_span("query.results_batch",
+                                  queries=len(plans))
+    with batch_span, lock_ctx:
+        t_held = time.perf_counter()
         for plan, (docids, scores, n_matched) in zip(plans, raw):
             results, clustered = build_results(
                 get_doc,
@@ -535,7 +540,10 @@ def search_device_batch(coll: Collection, queries, *, topk: int = 10,
                 suggestion=_suggest(coll, plan)
                 if n_matched == 0 else None,
                 facets=compute_facets(plan, docids, get_doc)))
-    trace.record("query.results_batch", t_res, queries=len(out))
+    if results_lock is not None:
+        trace.record("query.lock_wait", batch_span.t0, t_held)
+    trace.record("query.results_work", t_held, batch_span.t1,
+                 queries=len(out))
     return out
 
 

@@ -335,7 +335,10 @@ def _fd_call(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
     assert D % TILE_D == 0
     P4 = P // 4
     Vc4 = d_cube.shape[0] // (P4 * D)
-    rows3 = d_cube.reshape(Vc4, P4, D)
+    # the flat cube -> quarter rows relayout: a copy of the whole
+    # resident cube on the device, named so the trace can say so
+    with jax.named_scope("fd.cube_relayout"):
+        rows3 = d_cube.reshape(Vc4, P4, D)
     # (B, 1, T) so every block dim equals an array dim (Mosaic requires
     # sublane block dims to match the array or divide 8)
     fw = freqw.astype(jnp.float32).reshape(B, 1, T)
@@ -374,6 +377,10 @@ def _fd_call(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
             pltpu.SemaphoreType.DMA((T * 4,)),
         ],
     )
+    # no scope of its own: the kernel's op takes the name of the
+    # innermost scope, and stays ``_fd_scores_fused`` /
+    # ``fd_scores_fused_notail`` (the jit it is called under), which is
+    # what the ledger's ``breakdown.device_ops`` has held since PR 27
     ms, pres = pl.pallas_call(
         functools.partial(_fd_kernel, T=T, P=P, has_tail=has_tail),
         grid_spec=grid_spec,

@@ -17,6 +17,17 @@ always-running consumer owns the device, callers only enqueue:
 * Depth is bounded at :data:`DEPTH` so a burst cannot pipeline
   unbounded device memory.
 
+The loop thread is always in one of three states, each a span of the
+trace plane (``utils/trace.py``; on the device trace's clock too while
+a profiler session runs): ``resident.idle`` (nothing queued, nothing
+in flight), ``resident.issue_wave``, ``resident.collect_wave``. A
+ticket's own timeline is four stages of its request's ledger:
+``resident.queue_wait`` (submit -> taken), ``resident.issue_wave``,
+``resident.inflight_wait`` (issue done -> its collect begins) and
+``resident.collect_wave``; every boundary is one clock reading shared
+by the stages on both sides, and the devwatch wave record is built
+from the same readings.
+
 Freshness protocol (the generation rule the tests pin down): the loop
 re-resolves its DeviceIndex via ``di_fn`` ONLY while nothing is in
 flight. If ``gen_fn()`` (the Rdb version) moves while waves are in
@@ -42,12 +53,14 @@ from the surviving twin, and no ticket is ever lost to a failover.
 
 from __future__ import annotations
 
+import time
 from collections import deque
 from typing import Callable
 
 from ..utils import deadline as deadline_mod
 from ..utils import devwatch
 from ..utils import threads as _threads
+from ..utils import trace
 from ..utils.chaos import g_chaos
 from ..utils.lockcheck import make_condition, make_event
 from ..utils.log import get_logger
@@ -82,13 +95,18 @@ class Ticket:
     snapshot that scored)."""
 
     __slots__ = ("plans", "topk", "lang", "deadline", "di",
-                 "generation", "_ev", "_res", "_err")
+                 "generation", "ledgers", "t_submit", "_ev", "_res",
+                 "_err")
 
     def __init__(self, plans, topk: int, lang: int, deadline=None):
         self.plans = plans
         self.topk = topk
         self.lang = lang
         self.deadline = deadline
+        #: the stage ledgers of the requests waiting on this ticket
+        #: (the submitter's), and when it was submitted
+        self.ledgers = trace.current_ledgers()
+        self.t_submit = time.perf_counter()
         self.di = None
         self.generation: int | None = None
         self._ev = make_event("resident.ticket")
@@ -116,15 +134,23 @@ class Ticket:
 class _Wave:
     """An issued-but-uncollected wave and the tickets riding it.
     ``obs`` is the devwatch flight-recorder record opened at issue
-    (None when the telemetry plane is off)."""
+    (None when the telemetry plane is off); ``t_begin`` / ``t_issued``
+    are the issue span's two clock readings."""
 
-    __slots__ = ("pending", "tickets", "di", "obs")
+    __slots__ = ("pending", "tickets", "di", "obs", "t_begin",
+                 "t_issued")
 
     def __init__(self, pending, tickets, di, obs=None):
         self.pending = pending
         self.tickets = tickets
         self.di = di
         self.obs = obs
+        self.t_begin = self.t_issued = 0.0
+
+
+def _ledgers_of(tickets) -> tuple:
+    """Every rider's stage ledger: a wave's stages are written to all."""
+    return tuple(led for t in tickets for led in t.ledgers)
 
 
 class ResidentLoop:
@@ -195,9 +221,13 @@ class ResidentLoop:
         try:
             while True:
                 with self._cv:
-                    while self._alive and not self._queue \
+                    if self._alive and not self._queue \
                             and not self._inflight:
-                        self._cv.wait()
+                        # starved: nothing to issue, nothing to collect
+                        with trace.timed_span("resident.idle"):
+                            while self._alive and not self._queue \
+                                    and not self._inflight:
+                                self._cv.wait()
                     if not self._alive:
                         self._abort_locked(
                             RuntimeError("resident loop stopped"))
@@ -268,6 +298,22 @@ class ResidentLoop:
         batch = self._take_batch()
         if not batch:
             return
+        overlapped = bool(self._inflight)
+        span = trace.timed_span("resident.issue_wave")
+        with trace.bind_ledgers(_ledgers_of(batch)), span:
+            # a ticket waited from its submit to this reading, where
+            # the wave's issue begins
+            for t in batch:
+                trace.record("resident.queue_wait", t.t_submit, span.t0,
+                             ledgers=t.ledgers)
+            wave = self._issue_wave(batch, overlapped, span.t0)
+        if wave is not None:
+            wave.t_begin, wave.t_issued = span.t0, span.t1
+
+    def _issue_wave(self, batch: list[Ticket], overlapped: bool,
+                    t_begin: float):
+        """Dispatch one wave for the live tickets of ``batch``; the
+        ``_Wave`` now in flight, or None where nothing was issued."""
         live = []
         for t in batch:
             # the coordinator's budget may have run out while the
@@ -279,7 +325,7 @@ class ResidentLoop:
                 live.append(t)
         batch = live
         if not batch:
-            return
+            return None
         if g_chaos.enabled:
             g_chaos.resident_fault("issue")
         obs = devwatch.wave_begin("resident", coll=self.name,
@@ -295,27 +341,47 @@ class ResidentLoop:
             for t in batch:
                 t.di = di
                 t.generation = di._built_version
-            self._inflight.append(_Wave(pending, batch, di, obs))
+            wave = _Wave(pending, batch, di, obs)
+            self._inflight.append(wave)
             self.waves_issued += 1
             g_stats.count("resident.issue")
+            if overlapped:
+                g_stats.count("resident.issue_overlapped")
+            return wave
         except BaseException as exc:  # noqa: BLE001
-            devwatch.wave_end(obs, error=type(exc).__name__)
+            t_fail = time.perf_counter()
+            devwatch.wave_end(obs, (t_begin, t_fail, t_fail, t_fail),
+                              error=type(exc).__name__)
             for t in batch:
                 t._fail(exc)
+            return None
 
     def _collect_one(self) -> None:
         wave = self._inflight.popleft()
+        span = trace.timed_span("resident.collect_wave")
+        results = err = None
         try:
-            if g_chaos.enabled:
-                g_chaos.resident_fault("collect")
-            devwatch.wave_collect(wave.obs)
-            results = wave.di.collect_batch(wave.pending)
-            off = 0
-            for t in wave.tickets:
-                t._resolve(results[off:off + len(t.plans)])
-                off += len(t.plans)
-            devwatch.wave_end(wave.obs)
+            with trace.bind_ledgers(_ledgers_of(wave.tickets)), span:
+                # the wave waited from its issue's end to this
+                # reading, where its collect begins
+                trace.record("resident.inflight_wait", wave.t_issued,
+                             span.t0)
+                if g_chaos.enabled:
+                    g_chaos.resident_fault("collect")
+                devwatch.wave_collect(wave.obs)
+                results = wave.di.collect_batch(wave.pending)
         except BaseException as exc:  # noqa: BLE001
-            devwatch.wave_end(wave.obs, error=type(exc).__name__)
+            err = exc
+        # the stages are in the riders' ledgers BEFORE a ticket
+        # resolves: a woken request may close its timeline at once
+        devwatch.wave_end(
+            wave.obs, (wave.t_begin, wave.t_issued, span.t0, span.t1),
+            error=type(err).__name__ if err else None)
+        if err is not None:
             for t in wave.tickets:
-                t._fail(exc)
+                t._fail(err)
+            return
+        off = 0
+        for t in wave.tickets:
+            t._resolve(results[off:off + len(t.plans)])
+            off += len(t.plans)

@@ -74,7 +74,8 @@ class QueryBatcher:
         #: run_batch((coll_name, topk, offset), [queries]) → [results]
         self._run_batch = run_batch
         self._cv = threading.Condition()
-        #: (key, query, holder, parent span | None)
+        #: (key, query, holder, parent span | None, deadline, tier,
+        #: tenant, the rider's stage ledgers, when it was enqueued)
         self._queue: list[tuple] = []
         self._inflight = 0  # device waves currently dispatched
         self._alive = True
@@ -121,7 +122,9 @@ class QueryBatcher:
             self._queue.append((key, q, holder,
                                 trace_mod.current_span(), dl,
                                 priority_mod.current_tier(),
-                                priority_mod.current_tenant()))
+                                priority_mod.current_tenant(),
+                                trace_mod.current_ledgers(),
+                                time.perf_counter()))
             self._gauge_locked()
             self._cv.notify_all()
             while "res" not in holder and "err" not in holder:
@@ -134,6 +137,8 @@ class QueryBatcher:
                 self._cv.wait(timeout=left)
         if "err" in holder:
             raise holder["err"]
+        # result set on the pool thread -> this rider's thread runs
+        trace_mod.record("batcher.wake", holder["t_set"])
         return holder["res"]
 
     def _loop(self) -> None:
@@ -168,8 +173,14 @@ class QueryBatcher:
                     self._queue.remove(e)
                 self._gauge_locked()
                 self._inflight += 1
+            # each rider waited from its own enqueue to this moment;
+            # from here the batch waits as one (batcher.pool_wait)
+            t_formed = time.perf_counter()
+            for e in batch:
+                trace_mod.record("batcher.queue_wait", e[8], t_formed,
+                                 parent=e[3], ledgers=e[7])
             try:
-                self._pool.submit(self._run_one, key, batch)
+                self._pool.submit(self._run_one, key, batch, t_formed)
             except RuntimeError as exc:  # pool shut down by stop()
                 with self._cv:
                     self._inflight -= 1
@@ -178,15 +189,16 @@ class QueryBatcher:
                     self._cv.notify_all()
                 return
 
-    def _run_one(self, key, batch) -> None:
+    def _run_one(self, key, batch, t_formed: float) -> None:
         try:
-            self._run_one_inner(key, batch)
+            self._run_one_inner(key, batch, t_formed)
         finally:
             with self._cv:
                 self._inflight -= 1
                 self._cv.notify_all()  # wake the fill-or-flush window
 
-    def _run_one_inner(self, key, batch) -> None:
+    def _run_one_inner(self, key, batch, t_formed: float) -> None:
+        t_run = time.perf_counter()
         try:
             # worker thread = empty contextvars context; re-attach the
             # first traced waiter's span so the coalesced dispatch
@@ -212,17 +224,24 @@ class QueryBatcher:
             tenants = [e[6] for e in batch
                        if len(e) > 6 and e[6] is not None]
             tenant = tenants[0] if tenants else None
-            t0 = time.perf_counter()
+            # every rider waited through the whole of each batch
+            # stage: the pool and loop threads write them to all
             with trace_mod.attach(parents[0] if parents else None), \
+                    trace_mod.bind_ledgers(
+                        [led for e in batch for led in e[7]]), \
                     deadline_mod.bind(dl), \
                     priority_mod.bind_tier(tier), \
                     priority_mod.bind_tenant(tenant):
+                trace_mod.record("batcher.pool_wait", t_formed, t_run,
+                                 batch=len(batch))
                 res = self._run_batch(key, [e[1] for e in batch])
             for p in parents[1:]:
-                p.record("query.device_batch", t0, coalesced=True,
+                p.record("query.device_batch", t_run, coalesced=True,
                          batch=len(batch))
+            t_set = time.perf_counter()
             with self._cv:
                 for e, r in zip(batch, res):
+                    e[2]["t_set"] = t_set
                     e[2]["res"] = r
                 self._cv.notify_all()
         except Exception as exc:  # noqa: BLE001 — waiters must wake
@@ -449,8 +468,11 @@ class SearchHTTPServer:
         # The lock still covers the collection lookup and — via
         # results_lock — the host post-processing, which reads the
         # single-writer Rdb/titledb structures.
+        t_lock = time.perf_counter()
         with self._lock:
+            t_held = time.perf_counter()
             coll = self.colldb.get(cname)
+        trace_mod.record("query.lock_wait", t_lock, t_held)
         return engine.search_device_batch(
             coll, queries, topk=topk, offset=offset,
             resident=True, results_lock=self._lock)
@@ -903,9 +925,11 @@ class SearchHTTPServer:
                 degraded_out["degraded"] = True
             self.stats["degraded"] = self.stats.get("degraded", 0) + 1
             trace_mod.tag(results="degraded")
-        payload, ctype = render_results(
-            res, fmt,
-            trace_id=tr.trace_id if (debug and tr is not None) else None)
+        with trace_mod.timed_span("serve.render"):
+            payload, ctype = render_results(
+                res, fmt,
+                trace_id=tr.trace_id if (debug and tr is not None)
+                else None)
         return 200, payload, ctype
 
     def _page_get(self, query: dict) -> tuple[int, str, str]:
@@ -1611,6 +1635,16 @@ class SearchHTTPServer:
         snap = devwatch.snapshot()
         body = {k: snap[k] for k in ("enabled", "totals", "waves",
                                      "rooflines", "peaks")}
+        # each resident index's own count of dispatches by (program,
+        # shape bucket): kept in DeviceIndex._costed, devwatch or not
+        body["dispatches"] = []
+        for name, coll in sorted(dict(self.colldb.colls).items()):
+            di = getattr(coll, "_device_index", None)
+            for (prog, bucket), n in sorted(
+                    dict(di.dispatches if di is not None else {}).items()):
+                body["dispatches"].append(
+                    {"coll": name, "program": prog,
+                     "bucket": list(bucket), "dispatches": n})
         if query.get("format") == "json":
             return 200, json.dumps(body), "application/json"
         waves = list(snap["waves"])[-64:]
@@ -1643,6 +1677,11 @@ class SearchHTTPServer:
             f"<td>{e['dispatches']}</td></tr>"
             for e in snap["rooflines"]) \
             or "<tr><td colspan=9>none</td></tr>"
+        disp = "".join(
+            f"<tr><td>{d['coll']}</td><td>{d['program']}</td>"
+            f"<td>{d['bucket']}</td><td>{d['dispatches']}</td></tr>"
+            for d in body["dispatches"]) \
+            or "<tr><td colspan=4>none</td></tr>"
         pk = snap["peaks"]
         return 200, (
             "<html><head><title>gb device</title></head><body>"
@@ -1664,6 +1703,9 @@ class SearchHTTPServer:
             "<th>flops</th><th>bytes</th><th>intensity</th>"
             "<th>ridge</th><th>verdict</th><th>modeled bytes</th>"
             f"<th>dispatches</th></tr>{roof}</table>"
+            "<h2>dispatches per (program, shape bucket)</h2>"
+            "<table border=1><tr><th>coll</th><th>program</th>"
+            f"<th>bucket</th><th>dispatches</th></tr>{disp}</table>"
             "</body></html>"), "text/html"
 
     #: waterfall bar palette — one color per host, assigned by hash so
@@ -1940,6 +1982,11 @@ class SearchHTTPServer:
             def log_message(self, fmt, *args):  # route to our logger
                 log.debug("%s " + fmt, self.client_address[0], *args)
 
+            def parse_request(self):
+                # the request line is read: serve.request starts here
+                self._t_request = time.perf_counter()
+                return super().parse_request()
+
             def _serve(self, method: str):
                 parsed = urllib.parse.urlsplit(self.path)
                 query = dict(urllib.parse.parse_qsl(parsed.query))
@@ -1955,10 +2002,14 @@ class SearchHTTPServer:
                     self.headers.get(priority_mod.PRIORITY_HEADER))
                 tenant = priority_mod.tenant_from_header(
                     self.headers.get(priority_mod.TENANT_HEADER))
-                status, payload, ctype = outer.handle(
-                    method, parsed.path, query, body,
-                    client_ip=self.client_address[0], niceness=nice,
-                    tier=tier, tenant=tenant)
+                # the request's stage ledger rides this thread's
+                # context; the trace that /search opens takes it up
+                ledger = trace_mod.StageLedger()
+                with trace_mod.bind_ledgers((ledger,)):
+                    status, payload, ctype = outer.handle(
+                        method, parsed.path, query, body,
+                        client_ip=self.client_address[0],
+                        niceness=nice, tier=tier, tenant=tenant)
                 data = payload.encode("utf-8")
                 self.send_response(status)
                 self.send_header("Content-Type", ctype + "; charset=utf-8")
@@ -1970,6 +2021,9 @@ class SearchHTTPServer:
                     self.send_header(hname, hval)
                 self.end_headers()
                 self.wfile.write(data)
+                if parsed.path == "/search":
+                    # last byte written: the timeline closes
+                    trace_mod.finish_request(ledger, self._t_request)
 
             def do_GET(self):
                 self._serve("GET")

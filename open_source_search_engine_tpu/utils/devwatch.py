@@ -18,7 +18,10 @@ spans) and fleet metrics (host counters) both stop short of:
 * **Wave flight recorder** — a bounded ring of per-wave records from
   the resident loop (single-chip DeviceIndex waves and MeshServeIndex
   shard_map waves ride the same hooks): issue→dispatch→collect timing
-  split, per-round device time and fetched bytes, escalation reissues,
+  split (built from the clock readings of the loop's own spans,
+  ``resident.issue_wave`` / ``.collect_wave``, handed to
+  :meth:`DevWatch.wave_end` as ``marks``: this module reads no clock
+  for a wave), per-round device time and fetched bytes, escalation reissues,
   and the modeled ``wave_bytes_per_query`` next to what the round
   actually moved. Each wave also drops a device-tagged span into the
   trace plane, so a sampled trace shows the wave *inside* the request.
@@ -40,7 +43,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from collections import deque
 
 from . import trace
@@ -271,13 +273,11 @@ class DevWatch:
             self.wave_seq += 1
             seq = self.wave_seq
         return {"seq": seq, "source": source, "tags": dict(tags),
-                "t0": time.perf_counter(), "t_issue": None,
-                "t_collect": None, "rounds": []}
+                "rounds": []}
 
     def wave_issued(self, obs: dict | None, **tags) -> None:
         if obs is None:
             return
-        obs["t_issue"] = time.perf_counter()
         obs["tags"].update(tags)
 
     def wave_collect(self, obs: dict | None) -> None:
@@ -286,7 +286,6 @@ class DevWatch:
         this wave until :meth:`wave_end`."""
         if obs is None:
             return
-        obs["t_collect"] = time.perf_counter()
         self._tl.active = obs
 
     def note_round(self, **detail) -> None:
@@ -305,17 +304,17 @@ class DevWatch:
         with self._lock:
             self.totals["rounds"] += 1
 
-    def wave_end(self, obs: dict | None, error: str | None = None,
-                 **tags) -> None:
+    def wave_end(self, obs: dict | None, marks: tuple,
+                 error: str | None = None, **tags) -> None:
+        """Close the record. ``marks`` are the caller's four clock
+        readings (issue began, issue done, collect began, collect
+        done): the same ones its ``resident.*`` spans were made from."""
         if obs is None:
             return
         if getattr(self._tl, "active", None) is obs:
             self._tl.active = None
-        t_end = time.perf_counter()
         obs["tags"].update(tags)
-        t0 = obs["t0"]
-        ti = obs["t_issue"] if obs["t_issue"] is not None else t0
-        tc = obs["t_collect"] if obs["t_collect"] is not None else ti
+        t0, ti, tc, t_end = marks
         rec = {"seq": obs["seq"], "source": obs["source"],
                "issue_s": ti - t0, "wait_s": max(0.0, tc - ti),
                "collect_s": max(0.0, t_end - tc),
@@ -486,8 +485,9 @@ def note_round(**detail) -> None:
     g_devwatch.note_round(**detail)
 
 
-def wave_end(obs, error: str | None = None, **tags) -> None:
-    g_devwatch.wave_end(obs, error=error, **tags)
+def wave_end(obs, marks: tuple, error: str | None = None,
+             **tags) -> None:
+    g_devwatch.wave_end(obs, marks, error=error, **tags)
 
 
 def note_cost(kernel: str, bucket, thunk, modeled_bytes=None) -> None:

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import threading
 import weakref
+from collections import deque
 from typing import Callable
 
 from .log import get_logger
@@ -77,6 +78,12 @@ class MemBudget:
         #: zeroes gauges re-enters set_gauge; the guard stops the
         #: recursion, not the relief)
         self._relieving: set[str] = set()
+        #: (label, key) gauges whose owner was garbage-collected: a
+        #: finalizer may run on a thread that HOLDS ``_lock`` (the
+        #: collector strikes at any allocation, also inside
+        #: ``_used_locked``), so it only appends here and the next
+        #: locked reader drops them
+        self._dead: deque = deque()
 
     # --- limit -----------------------------------------------------------
 
@@ -105,7 +112,19 @@ class MemBudget:
 
     # --- accounting ------------------------------------------------------
 
+    def forget_gauge(self, label: str, key: object) -> None:
+        """Drop an owner's gauge from its finalizer (``__del__``):
+        takes no lock, so it cannot deadlock a thread that the
+        collector interrupted while it held the budget's."""
+        self._dead.append((label, key))
+
+    def _reap_locked(self) -> None:
+        while self._dead:
+            label, key = self._dead.popleft()
+            self._gauges.get(label, {}).pop(key, None)
+
     def _used_locked(self) -> int:
+        self._reap_locked()
         return (sum(self._reserved.values())
                 + sum(sum(g.values()) for g in self._gauges.values()))
 
@@ -113,8 +132,7 @@ class MemBudget:
         with self._lock:
             if label is None:
                 return self._used_locked()
-            return (self._reserved.get(label, 0)
-                    + sum(self._gauges.get(label, {}).values()))
+            return self._label_used_locked(label)
 
     def free(self) -> int:
         with self._lock:
@@ -153,6 +171,7 @@ class MemBudget:
                     self._relieving.discard(label)
 
     def _label_used_locked(self, label: str) -> int:
+        self._reap_locked()
         return (self._reserved.get(label, 0)
                 + sum(self._gauges.get(label, {}).values()))
 
@@ -296,6 +315,7 @@ class MemBudget:
 
     def snapshot(self) -> dict:
         with self._lock:
+            self._reap_locked()
             labels: dict[str, dict] = {}
             for lb in sorted(set(self._reserved)
                              | set(self._gauges)
@@ -327,6 +347,7 @@ class MemBudget:
             self.high_water = 0
             self._pressure = []
             self._relieving.clear()
+            self._dead.clear()
 
 
 #: process-wide singleton (reference ``g_mem``)

@@ -20,8 +20,22 @@ This module is the Dapper-style fix (Sigelman et al., 2010):
 * **Slow-query log** — any trace slower than ``slow_query_ms`` is kept
   regardless of the sampling coin flip and appended as one JSON line to
   ``slowlog.jsonl`` (next to ``statsdb.jsonl``).  An unsampled slow
-  trace keeps only its root-span skeleton — enough to know it happened
-  and how long it took.
+  trace keeps its root-span skeleton and its **stage ledger**
+  (``"stages"``): where the time went, hand-off by hand-off, as far
+  as the trace's own end (``serve.unaccounted`` is recorded after the
+  last byte is written, when the line is already out).  Appends
+  are bounded (:data:`SLOWLOG_PER_S` a second, the rest counted in
+  ``trace.slow_dropped``): a saturated server, where every request is
+  slow, must not pay a file line a request.
+* **Stage ledger** — always on, sampled or not: every request carries
+  a flat list of (stage, ms) for the disjoint stages of
+  :data:`REQUEST_STAGES`.  It is bound by a contextvar on the
+  handler's thread, handed across threads explicitly like the parent
+  span (queue entry, ``Ticket``), and a coalesced batch's stages fan
+  out to every rider.  ``record`` / ``timed_span`` are the only doors:
+  they feed ``g_stats`` (always), the ledger (stages), the span tree
+  (sampled) and, while a ``jax.profiler`` session runs, a
+  ``TraceAnnotation`` of the same name on the device trace's clock.
 * **Cross-host propagation** — the transport stamps outgoing RPCs with
   an ``X-OSSE-Trace: <trace_id>:<parent_span_id>`` header; node
   handlers ``adopt()`` it, run their handler under a local root span,
@@ -44,6 +58,7 @@ from __future__ import annotations
 import contextvars
 import itertools
 import json
+import sys
 import threading
 import time
 import uuid
@@ -62,6 +77,31 @@ RING_KEEP = 128
 DEFAULT_SAMPLE_N = 64
 #: default slow-query threshold (ms); slower traces always kept
 DEFAULT_SLOW_MS = 1000.0
+#: slow traces exported (ring + slowlog.jsonl) in any one second; the
+#: rest are counted in ``trace.slow_dropped``
+SLOWLOG_PER_S = 4
+
+#: the stages of a served request in the order it meets them. They are
+#: disjoint and together cover ``serve.request``; only these names
+#: enter a stage ledger. Containers (``serve.request``,
+#: ``serve.search``, ``query.device_batch``, ``query.results_batch``)
+#: and what a stage holds (``devindex.plan`` / ``.issue`` / ``.device``)
+#: stay spans and ``g_stats`` timings, and are never summed.
+REQUEST_STAGES = (
+    "admission.queue_delay",    # admit entered -> admitted
+    "batcher.queue_wait",       # enqueued -> its batch formed
+    "batcher.pool_wait",        # batch formed -> a pool thread runs it
+    "query.lock_wait",          # waiting for the server's core lock
+    "resident.queue_wait",      # submit -> taken by the loop
+    "resident.issue_wave",      # plan, route, async dispatch
+    "resident.inflight_wait",   # issue done -> its collect begins
+    "resident.collect_wave",    # blocking fetch, escalation rounds
+    "query.results_work",       # results built under the core lock
+    "batcher.wake",             # result set -> the rider's thread runs
+    "serve.render",             # render_results
+    "serve.unaccounted",        # serve.request less all of the above
+)
+_STAGE_SET = frozenset(REQUEST_STAGES)
 
 _ids = itertools.count(1)
 
@@ -72,6 +112,35 @@ _ctx: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
 #: and debug=1 echoes work without paying for span bookkeeping
 _tid_ctx: contextvars.ContextVar[str | None] = contextvars.ContextVar(
     "osse_trace_id", default=None)
+#: the stage ledgers of the requests this thread works for: one on a
+#: handler's thread, every rider's on a thread that runs a coalesced
+#: batch, none elsewhere
+_led_ctx: contextvars.ContextVar[tuple] = contextvars.ContextVar(
+    "osse_stage_ledgers", default=())
+
+
+class StageLedger:
+    """One request's (stage, ms) rows, in the order they were written.
+    Several threads append in turn (handler, batcher, pool, resident
+    loop); ``list.append`` is all the synchronisation that takes."""
+
+    __slots__ = ("rows",)
+
+    def __init__(self):
+        self.rows: list[tuple[str, float]] = []
+
+    def add(self, name: str, ms: float) -> None:
+        self.rows.append((name, ms))
+
+    def stages(self) -> dict[str, float]:
+        """stage -> ms, a stage met twice summed, first-met order."""
+        out: dict[str, float] = {}
+        for name, ms in list(self.rows):
+            out[name] = out.get(name, 0.0) + ms
+        return out
+
+    def total_ms(self) -> float:
+        return sum(ms for _, ms in list(self.rows))
 
 
 class Span:
@@ -209,6 +278,60 @@ class attach:
             _tid_ctx.reset(self._tok2)
 
 
+def current_ledgers() -> tuple:
+    """The stage ledgers bound on this thread — what a hand-off to
+    another thread carries along, beside the parent span."""
+    return _led_ctx.get()
+
+
+class bind_ledgers:
+    """Bind the ledgers of the requests a thread works for: the
+    handler binds its request's one, a pool or loop thread every
+    rider's of the batch or wave it runs."""
+
+    __slots__ = ("leds", "_tok")
+
+    def __init__(self, leds):
+        self.leds = tuple(leds)
+
+    def __enter__(self) -> tuple:
+        self._tok = _led_ctx.set(self.leds)
+        return self.leds
+
+    def __exit__(self, *exc) -> None:
+        _led_ctx.reset(self._tok)
+
+
+def _to_ledgers(name: str, ms: float, ledgers=None) -> None:
+    if name in _STAGE_SET:
+        for led in (_led_ctx.get() if ledgers is None else ledgers):
+            led.add(name, ms)
+
+
+#: ``jax.profiler.TraceAnnotation`` once jax is loaded (False where it
+#: cannot be had); never imported from here: a process that has not
+#: loaded jax has no profiler session to write into
+_annotation = None
+
+
+def _trace_annotation():
+    """The annotation class while a profiler session runs, else None:
+    with no session a span pays one ``is_enabled()`` call (0.07 us) and
+    builds nothing."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        try:
+            _annotation = jax.profiler.TraceAnnotation
+            _annotation.is_enabled()
+        except Exception:  # noqa: BLE001 — a span must never raise
+            _annotation = False
+    return _annotation if _annotation and _annotation.is_enabled() \
+        else None
+
+
 class span:
     """``with span("query.pack", npass=i):`` — child of the current
     span, no-op (yields None) outside a sampled trace."""
@@ -238,43 +361,79 @@ class span:
 class timed_span:
     """A span that ALSO feeds ``g_stats.record_ms(name)`` — the query
     path uses this everywhere a ``g_stats.timed`` used to live, so the
-    aggregate plane and the trace plane cannot drift apart."""
+    aggregate plane and the trace plane cannot drift apart. A stage
+    (:data:`REQUEST_STAGES`) also lands in the bound stage ledgers,
+    and while a ``jax.profiler`` session runs the interval is written
+    onto its clock as a ``TraceAnnotation`` of the same name. ``t0`` /
+    ``t1`` are the clock readings it made, for a neighbour that starts
+    or ends on the same boundary (one clock a boundary)."""
 
-    __slots__ = ("name", "_cm", "_t0")
+    __slots__ = ("name", "_cm", "t0", "t1", "_ann")
 
     def __init__(self, name: str, **tags):
         self.name = name
         self._cm = span(name, **tags)
+        self.t1 = None
 
     def __enter__(self) -> Span | None:
-        self._t0 = time.perf_counter()
+        ann = _trace_annotation()
+        self._ann = ann(self.name) if ann is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self._cm.__enter__()
 
     def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
         self._cm.__exit__(*exc)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         # exemplar: when this interval ran under a SAMPLED trace, pin
         # its trace id to the histogram bucket it lands in — the
         # /admin/perf p99 cell links to the concrete /admin/traces
         # waterfall (Dapper's aggregate→trace bridge)
         sp = self._cm.sp
+        ms = (self.t1 - self.t0) * 1000.0
+        _to_ledgers(self.name, ms)
         g_stats.record_ms(
-            self.name, (time.perf_counter() - self._t0) * 1000.0,
+            self.name, ms,
             exemplar=sp.trace_id if sp is not None else None)
 
 
-def record(name: str, t0: float, t1: float | None = None, **tags) -> None:
+def record(name: str, t0: float, t1: float | None = None, *,
+           parent: Span | None = None, ledgers=None, **tags) -> None:
     """Attach an already-measured ``perf_counter`` interval to the
     current span AND to ``g_stats`` — like ``timed_span`` but for
     intervals the caller timed itself (device-time attribution after a
     block-until-ready). Feeding both planes here is what keeps ad-hoc
     ``perf_counter`` deltas off the query path (the ``adhoc-timing``
-    lint rule)."""
+    lint rule). ``parent`` / ``ledgers`` name a request other than the
+    thread's own: a wait measured by the thread that ended it (the
+    batcher for a rider, the resident loop for a ticket)."""
     end = time.perf_counter() if t1 is None else t1
-    p = _ctx.get()
+    p = parent if parent is not None else _ctx.get()
     if p is not None:
         p.record(name, t0, end, **tags)
-    g_stats.record_ms(name, (end - t0) * 1000.0,
+    ms = (end - t0) * 1000.0
+    _to_ledgers(name, ms, ledgers)
+    g_stats.record_ms(name, ms,
                       exemplar=p.trace_id if p is not None else None)
+
+
+def finish_request(ledger: StageLedger, t0: float,
+                   t1: float | None = None) -> None:
+    """Close a served request's timeline: the container
+    ``serve.request`` (request line read -> last byte written) and the
+    stage ``serve.unaccounted``, what its ledger's stages leave of it.
+    The request's trace closed (and, if slow, was exported) before the
+    answer was written, so no exported line holds this stage: it is
+    in ``g_stats`` and in the ledger the handler still holds."""
+    end = time.perf_counter() if t1 is None else t1
+    ms = (end - t0) * 1000.0
+    rest = max(ms - ledger.total_ms(), 0.0)
+    ledger.add("serve.unaccounted", rest)
+    g_stats.record_ms("serve.request", ms)
+    g_stats.record_ms("serve.unaccounted", rest)
 
 
 def tag(**kw) -> None:
@@ -302,14 +461,15 @@ def parse_header(value: str) -> tuple[str, str] | None:
 class _LiveTrace:
     """Handle yielded by :meth:`Tracer.start` while the trace runs."""
 
-    __slots__ = ("trace_id", "name", "sampled", "root")
+    __slots__ = ("trace_id", "name", "sampled", "root", "ledger")
 
     def __init__(self, trace_id: str, name: str, sampled: bool,
-                 root: Span):
+                 root: Span, ledger: StageLedger | None = None):
         self.trace_id = trace_id
         self.name = name
         self.sampled = sampled
         self.root = root
+        self.ledger = ledger if ledger is not None else StageLedger()
 
     def export(self) -> dict:
         end = (time.perf_counter() if self.root._t1 is None
@@ -320,6 +480,8 @@ class _LiveTrace:
             "sampled": self.sampled,
             "ts": time.time(),
             "dur_ms": round((end - self.root._t0) * 1000.0, 3),
+            "stages": {k: round(v, 3)
+                       for k, v in self.ledger.stages().items()},
             "root": self.root.to_dict(self.root._t0, end),
         }
 
@@ -359,7 +521,13 @@ class _StartCM:
             sampled = tr.sample_n == 1 or n % tr.sample_n == 0
         tid = self.trace_id or uuid.uuid4().hex[:16]
         root = Span(tid, self.name, host=tr.host, tags=self.tags)
-        self.trace = _LiveTrace(tid, self.name, bool(sampled), root)
+        # the request's ledger: the one its handler bound, else (a
+        # caller that is its own front door) one of the trace's own
+        leds = _led_ctx.get()
+        self.trace = _LiveTrace(tid, self.name, bool(sampled), root,
+                                leds[0] if len(leds) == 1 else None)
+        self._tok3 = None if len(leds) == 1 else \
+            _led_ctx.set((self.trace.ledger,))
         self._tok = _ctx.set(root if sampled else None)
         self._tok2 = _tid_ctx.set(tid)
         g_stats.count("trace.started")
@@ -373,6 +541,8 @@ class _StartCM:
             return
         _ctx.reset(self._tok)
         _tid_ctx.reset(self._tok2)
+        if self._tok3 is not None:
+            _led_ctx.reset(self._tok3)
         t.root.finish()
         self.tracer._finish(t)
 
@@ -392,6 +562,10 @@ class Tracer:
         self.ring: deque[dict] = deque(maxlen=RING_KEEP)
         self._lock = threading.Lock()
         self._n = 0
+        #: the second the slow-export bound is counting in, and its count
+        self._clock = time.monotonic
+        self._slow_t0 = 0.0
+        self._slow_n = 0
 
     def configure(self, sample_n: int | None = None,
                   slow_ms: float | None = None,
@@ -433,14 +607,30 @@ class Tracer:
     def _finish(self, t: _LiveTrace) -> None:
         dur_ms = (t.root._t1 - t.root._t0) * 1000.0
         slow = self.slow_ms > 0 and dur_ms >= self.slow_ms
-        if not (t.sampled or slow):
+        keep_slow = slow and self._slow_admit()
+        if slow:
+            g_stats.count("trace.slow")
+            if not keep_slow:
+                g_stats.count("trace.slow_dropped")
+        if not (t.sampled or keep_slow):
             return
         exported = t.export()
         exported["slow"] = slow
         self.ring.append(exported)
-        if slow:
-            g_stats.count("trace.slow")
+        if keep_slow:
             self._slowlog_append(exported)
+
+    def _slow_admit(self) -> bool:
+        """At most :data:`SLOWLOG_PER_S` slow exports in any one
+        second: where every request is slow the log keeps a sample and
+        a count, and the handler's thread pays neither the export nor
+        the file."""
+        now = self._clock()
+        with self._lock:
+            if now - self._slow_t0 >= 1.0:
+                self._slow_t0, self._slow_n = now, 0
+            self._slow_n += 1
+            return self._slow_n <= SLOWLOG_PER_S
 
     def _slowlog_append(self, exported: dict) -> None:
         path = self.slowlog_path

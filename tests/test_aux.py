@@ -54,9 +54,19 @@ def test_sampling_profiler_catches_hot_function():
     prof.start()
     hot_spin(time.perf_counter() + 0.4)
     prof.stop()
-    rep = prof.report()
+    # by hits, not by rank: a worker that has run other files holds
+    # dozens of parked threads, each sampled on every tick, whose frames
+    # tie with the busy thread's and can crowd it out of the top 30
+    rep = prof.report(top=100_000)
     assert rep["samples"] > 20
-    assert any(r["func"] == "hot_spin" for r in rep["top_self"])
-    assert any(r["func"] == "hot_spin" for r in rep["top_cumulative"])
+
+    def hits(rows, func):
+        return sum(r["hits"] for r in rows if r["func"] == func)
+    # this test's own frame is on the main thread's stack at every tick
+    ticks = hits(rep["top_cumulative"],
+                 "test_sampling_profiler_catches_hot_function")
+    assert ticks > 20
+    assert hits(rep["top_self"], "hot_spin") >= 0.5 * ticks
+    assert hits(rep["top_cumulative"], "hot_spin") >= 0.5 * ticks
     prof.reset()
     assert prof.report()["samples"] == 0

@@ -71,6 +71,27 @@ class TestGenCache:
         c.put("k", "new", gen=2)
         assert c.lookup("k", gen=2) == (True, "new")
 
+    def test_a_cache_collected_under_the_budgets_lock_is_no_deadlock(self):
+        """The collector runs finalizers wherever an allocation lands,
+        also on a thread that holds the budget's lock (it did, inside
+        ``set_gauge``, and the suite hung there): the finalizer takes
+        no lock, and the next locked reader drops the gauge."""
+        c = GenCache("t.finalized", ttl_s=60)
+        c.put("k", "v" * 4096)
+        assert g_membudget.used("cache") > 0
+        before = g_membudget.used("cache")
+        done = threading.Event()
+
+        def collect_under_the_lock(cache):
+            with g_membudget._lock:
+                cache.__del__()     # what the collector would run here
+            done.set()
+
+        threading.Thread(target=collect_under_the_lock, args=(c,),
+                         daemon=True).start()
+        assert done.wait(10.0), "the finalizer waited for its own lock"
+        assert g_membudget.used("cache") < before
+
     def test_gen_fn_supplies_default_generation(self):
         gen = [1]
         c = GenCache("t.genfn", ttl_s=60, gen_fn=lambda: gen[0])

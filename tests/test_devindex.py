@@ -85,6 +85,27 @@ class TestResidentParity:
                 [r.score for r in b.results],
                 [r.score for r in s.results], rtol=1e-6)
 
+    def test_dispatches_are_counted_by_program_and_bucket(self, coll):
+        """``_costed`` is the one door every wave program goes
+        through: the index's own count by (program name, shape bucket)
+        is the sum of what it dispatched, and the signature the
+        benchmark's deployment file checks stays as it was."""
+        import inspect
+        di = get_device_index(coll)
+        assert list(inspect.signature(di._costed.__func__).parameters
+                    )[:5] == ["self", "name", "bucket", "modeled_bytes",
+                              "fn"]
+        before = dict(di.dispatches)
+        search_device(coll, "apple", topk=10)
+        search_device(coll, "apple", topk=10)
+        new = {k: n - before.get(k, 0) for k, n in di.dispatches.items()
+               if n != before.get(k, 0)}
+        assert list(new.values()) == [2], new
+        ((name, bucket),) = new
+        assert name == "devindex._two_phase"
+        assert len(bucket) == 6 and all(isinstance(x, int)
+                                        for x in bucket)
+
     def test_refresh_tracks_writes(self, coll):
         di = get_device_index(coll)
         v0 = di._built_version

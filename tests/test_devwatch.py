@@ -30,6 +30,9 @@ from open_source_search_engine_tpu.utils.membudget import g_membudget
 from open_source_search_engine_tpu.utils.stats import g_stats
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: a wave's four clock readings, as the resident loop hands them over:
+#: issue began, issue done, collect began, collect done
+MARKS = (10.0, 10.25, 10.75, 12.0)
 
 DOC = ("<html><head><title>{t}</title></head><body>"
        "<p>walrus {t} herd gathers on the {t} shore. "
@@ -141,7 +144,7 @@ class TestFlightRecorder:
     def test_ring_is_bounded(self):
         devwatch.enable()
         for _ in range(devwatch.RING + 40):
-            devwatch.wave_end(devwatch.wave_begin("test"))
+            devwatch.wave_end(devwatch.wave_begin("test"), MARKS)
         snap = devwatch.snapshot()
         assert len(snap["waves"]) == devwatch.RING
         assert snap["totals"]["waves"] == devwatch.RING + 40
@@ -165,10 +168,24 @@ class TestFlightRecorder:
         assert r["device_s"] >= 0.0 and r["bytes_out"] > 0
         assert "escalations" in r
 
+    def test_the_split_is_the_callers_marks(self):
+        """devwatch reads no clock for a wave: the record's split is
+        built from the four readings the resident loop's own spans
+        made (one clock a boundary), handed over at ``wave_end``."""
+        devwatch.enable()
+        obs = devwatch.wave_begin("test")
+        devwatch.wave_issued(obs, plans=2)
+        devwatch.wave_collect(obs)
+        devwatch.wave_end(obs, MARKS)
+        w = devwatch.snapshot()["waves"][-1]
+        assert (w["issue_s"], w["wait_s"], w["collect_s"],
+                w["total_s"]) == (0.25, 0.5, 1.25, 2.0)
+        assert w["plans"] == 2
+
     def test_error_wave_is_recorded(self):
         devwatch.enable()
         obs = devwatch.wave_begin("test", coll="x")
-        devwatch.wave_end(obs, error="BoomError")
+        devwatch.wave_end(obs, MARKS, error="BoomError")
         snap = devwatch.snapshot()
         assert snap["waves"][-1]["error"] == "BoomError"
         assert snap["totals"]["wave_errors"] == 1
@@ -258,7 +275,7 @@ class TestNoop:
             "obs = devwatch.wave_begin('t')\n"
             "assert obs is None\n"
             "devwatch.wave_issued(obs); devwatch.wave_collect(obs)\n"
-            "devwatch.wave_end(obs)\n"
+            "devwatch.wave_end(obs, (0.0,) * 4)\n"
             "s = devwatch.snapshot()\n"
             "assert s['enabled'] is False and s['ledger'] == {}\n"
             "assert s['waves'] == [] and s['rooflines'] == []\n"
@@ -277,7 +294,7 @@ class TestNoop:
         t0 = time.perf_counter()
         for _ in range(20000):
             devwatch.note_round(coll="c", device_s=0.0)
-            devwatch.wave_end(devwatch.wave_begin("t"))
+            devwatch.wave_end(devwatch.wave_begin("t"), MARKS)
         assert time.perf_counter() - t0 < 1.0
 
 
@@ -324,6 +341,11 @@ class TestAdminPages:
         assert js["totals"]["waves"] >= 1
         assert js["waves"] and js["rooflines"]
         assert "ridge" in js["peaks"] or "label" in js["peaks"]
+        # the index's own dispatch counts, by (program, shape bucket)
+        assert "dispatches per (program, shape bucket)" in html
+        (d,) = [d for d in js["dispatches"] if d["coll"] == "main"]
+        assert d["program"] == "devindex._two_phase"
+        assert d["dispatches"] >= 1 and len(d["bucket"]) == 6
 
     def test_perf_page_carries_hbm_row(self, srv):
         js = json.loads(_get(srv, "/admin/perf?format=json").read())

@@ -207,3 +207,78 @@ class TestLifecycle:
             [_compile_cached("apple", 0)], topk=64, lang=0
         ).wait(timeout=60)
         assert n >= 1
+
+
+class _FakeIndex:
+    """The loop's duck type, nothing else: ``issue_batch`` hands the
+    plans back, ``collect_batch`` blocks on the first wave only (so a
+    test can queue behind it) and echoes every plan as a result."""
+
+    _built_version = 0
+
+    def __init__(self):
+        self.hold = threading.Event()
+        self.collecting = threading.Event()
+        self.collected = 0
+
+    def issue_batch(self, plans, topk=64, lang=0):
+        return list(plans)
+
+    def collect_batch(self, pending):
+        if self.collected == 0:
+            self.collecting.set()
+            self.hold.wait(60)
+        self.collected += 1
+        return [(p, None, 0) for p in pending]
+
+
+class TestTimeline:
+    def test_issue_overlapped_counts_issues_with_a_wave_in_flight(self):
+        """One ticket a wave (``max_batch`` 1). Wave 1 is issued alone
+        and its collect is held; three tickets queue behind it. Once
+        released the loop issues wave 2 with nothing in flight, then
+        waves 3 and 4 each beside the wave before: 4 issues, 2 of them
+        overlapped — exactly."""
+        from open_source_search_engine_tpu.utils.stats import g_stats
+
+        def counters():
+            c = g_stats.snapshot()["counters"]
+            return (c.get("resident.issue", 0),
+                    c.get("resident.issue_overlapped", 0))
+
+        di = _FakeIndex()
+        i0, o0 = counters()
+        loop = ResidentLoop(lambda: di, lambda: 0, max_batch=1,
+                            name="overlap")
+        try:
+            first = loop.submit(["w1"], topk=8)
+            assert di.collecting.wait(60)
+            queued = [loop.submit([f"w{k}"], topk=8) for k in (2, 3, 4)]
+            di.hold.set()
+            for t in [first] + queued:
+                assert t.wait(timeout=60)[0][0] == t.plans[0]
+        finally:
+            di.hold.set()
+            loop.stop()
+        i1, o1 = counters()
+        assert (i1 - i0, o1 - o0) == (4, 2)
+
+    def test_a_ticket_carries_its_submitters_ledgers(self):
+        """The four stages of a ticket's timeline land, in order, in
+        the ledger bound on the submitting thread — written by the
+        loop's thread, before the ticket resolves."""
+        from open_source_search_engine_tpu.utils import trace
+        di = _FakeIndex()
+        di.hold.set()
+        loop = ResidentLoop(lambda: di, lambda: 0, name="ledger")
+        led = trace.StageLedger()
+        try:
+            with trace.bind_ledgers((led,)):
+                t = loop.submit(["p"], topk=8)
+            t.wait(timeout=60)
+            assert [n for n, _ in led.rows] == [
+                "resident.queue_wait", "resident.issue_wave",
+                "resident.inflight_wait", "resident.collect_wave"]
+            assert all(ms >= 0.0 for _, ms in led.rows)
+        finally:
+            loop.stop()

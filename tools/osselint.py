@@ -144,7 +144,11 @@ class Ctx:
         self.rel = rel.replace("\\", "/")
         self.tree = ast.parse(src)
         self.parents: dict[ast.AST, ast.AST] = {}
-        for node in ast.walk(self.tree):
+        #: every node, in ``ast.walk`` order, walked once: each of
+        #: the whole-tree rules iterates this list (the walk itself
+        #: was most of the linter's CPU time)
+        self.nodes: list[ast.AST] = list(ast.walk(self.tree))
+        for node in self.nodes:
             for child in ast.iter_child_nodes(node):
                 self.parents[child] = node
         self.waivers: dict[int, set[str]] = {}
@@ -231,7 +235,7 @@ def _scope_pkg_tools(rel: str) -> bool:
 
 def rule_ttlcache_offplane(ctx: Ctx) -> list[Finding]:
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if isinstance(node, ast.Call):
             name = dotted(node.func)
             if name and name.split(".")[-1] == "TtlCache":
@@ -250,7 +254,7 @@ def _ttl_scope(rel: str) -> bool:
 
 def rule_urllib_in_parallel(ctx: Ctx) -> list[Finding]:
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         bad = None
         if isinstance(node, ast.Import):
             if any(a.name.split(".")[0] == "urllib" for a in node.names):
@@ -277,7 +281,7 @@ def _urllib_scope(rel: str) -> bool:
 
 def rule_bare_stats_timed(ctx: Ctx) -> list[Finding]:
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if isinstance(node, ast.Call) \
                 and dotted(node.func) == "g_stats.timed":
             out.append(Finding(
@@ -305,7 +309,7 @@ _STATS_NAME_FUNCS = {
 
 def rule_stats_cardinality(ctx: Ctx) -> list[Finding]:
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not (isinstance(node, ast.Call)
                 and dotted(node.func) in _STATS_NAME_FUNCS
                 and node.args):
@@ -341,7 +345,7 @@ def _stats_name_scope(rel: str) -> bool:
 
 def rule_id_key(ctx: Ctx) -> list[Finding]:
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not (isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Name)
                 and node.func.id == "id"):
@@ -372,7 +376,7 @@ def rule_id_key(ctx: Ctx) -> list[Finding]:
 
 def rule_blocking_under_lock(ctx: Ctx) -> list[Finding]:
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, (ast.With, ast.AsyncWith)):
             continue
         if not any(_is_lockish(item.context_expr)
@@ -403,7 +407,7 @@ def rule_silent_except(ctx: Ctx) -> list[Finding]:
             return any(broad(e) for e in t.elts)
         return False
 
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, ast.ExceptHandler):
             continue
         if node.type is None:
@@ -422,7 +426,7 @@ def rule_silent_except(ctx: Ctx) -> list[Finding]:
 
 def rule_mutable_default(ctx: Ctx) -> list[Finding]:
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
             continue
         defaults = list(node.args.defaults) + [
@@ -442,7 +446,7 @@ def rule_mutable_default(ctx: Ctx) -> list[Finding]:
 
 def rule_thread_spawn(ctx: Ctx) -> list[Finding]:
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if isinstance(node, ast.Call):
             name = dotted(node.func)
             if name and (name == "Thread"
@@ -467,7 +471,7 @@ _PROC_CALLS = {"os.kill", "os.killpg", "os.fork", "os.forkpty"}
 
 def rule_proc_spawn(ctx: Ctx) -> list[Finding]:
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, ast.Call):
             continue
         name = dotted(node.func)
@@ -511,7 +515,7 @@ def rule_residency_bypass(ctx: Ctx) -> list[Finding]:
     (``build_device_index`` / ``spawn_resident_loop`` /
     ``get_resident_loop``), which serve/tenancy.py owns."""
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, ast.Call):
             continue
         name = dotted(node.func)
@@ -581,7 +585,7 @@ def rule_locked_global(ctx: Ctx) -> list[Finding]:
                 f"module-level mutable `{name}` mutated outside a "
                 "`with lock:` — request threads share it"))
 
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if isinstance(node, (ast.Assign, ast.AugAssign, ast.Delete)):
             targets = node.targets if isinstance(node, (ast.Assign,
                                                         ast.Delete)) \
@@ -611,7 +615,7 @@ def rule_device_sync(ctx: Ctx) -> list[Finding]:
     # belong in devindex.py's issue path
     resident = ctx.rel == f"{PKG}/query/resident.py"
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, ast.Call):
             continue
         name = dotted(node.func)
@@ -662,7 +666,7 @@ def rule_host_sort(ctx: Ctx) -> list[Finding]:
     CPU work the plane exists to remove. Host ordering belongs to the
     oracle pipeline in ``query/devindex.py``."""
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, ast.Call):
             continue
         name = dotted(node.func)
@@ -698,7 +702,7 @@ def rule_mesh_collective(ctx: Ctx) -> list[Finding]:
     and devindex code must stay mesh-agnostic so the flat single-chip
     path runs it unchanged."""
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, ast.Call):
             continue
         name = dotted(node.func)
@@ -789,9 +793,9 @@ def _jit_registry(ctx: Ctx) -> dict[str, _JitSite]:
     if reg is not None:
         return reg
     reg = {}
-    defs = {n.name: n for n in ast.walk(ctx.tree)
+    defs = {n.name: n for n in ctx.nodes
             if isinstance(n, ast.FunctionDef)}
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if isinstance(node, ast.FunctionDef):
             for deco in node.decorator_list:
                 if _is_jax_jit(deco):
@@ -897,7 +901,7 @@ def rule_jit_unstable_static(ctx: Ctx) -> list[Finding]:
     out: list[Finding] = []
     if not reg:
         return out
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not (isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Name)
                 and node.func.id in reg):
@@ -946,7 +950,7 @@ def rule_jit_in_body(ctx: Ctx) -> list[Finding]:
     """jax.jit wrapped inside a function body — a fresh wrapper (and
     empty compile cache) per call, so nothing is ever warm."""
     out: list[Finding] = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not (isinstance(node, ast.Call) and _jit_wrap_call(node)):
             continue
         encl = None
@@ -1021,7 +1025,7 @@ def rule_jit_donated_reuse(ctx: Ctx) -> list[Finding]:
     out: list[Finding] = []
     if not donators:
         return out
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not (isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Name)
                 and node.func.id in donators):
@@ -1075,7 +1079,7 @@ def rule_jit_implicit_transfer(ctx: Ctx) -> list[Finding]:
     # device-valued local names: single-name targets assigned from a
     # jit-wrapped or jnp-producing call, keyed by enclosing function
     dev_by_fn: dict[int, set[str]] = {}
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if isinstance(node, ast.Assign) and len(node.targets) == 1 \
                 and isinstance(node.targets[0], ast.Name) \
                 and isinstance(node.value, ast.Call) \
@@ -1091,7 +1095,7 @@ def rule_jit_implicit_transfer(ctx: Ctx) -> list[Finding]:
             and _device_producer(expr, reg)
 
     out: list[Finding] = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, ast.Call):
             continue
         fnkey = id(_enclosing_function(ctx, node) or ctx.tree)
@@ -1137,7 +1141,7 @@ def rule_bare_deadline(ctx: Ctx) -> list[Finding]:
                                           "time.monotonic"))
 
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, ast.BinOp):
             continue
         if isinstance(node.op, ast.Add) \
@@ -1174,7 +1178,7 @@ def rule_adhoc_timing(ctx: Ctx) -> list[Finding]:
                                           "time.time"))
 
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if isinstance(node, ast.BinOp) and isinstance(node.op, ast.Sub) \
                 and is_clock(node.left):
             out.append(Finding(
@@ -1212,7 +1216,7 @@ def rule_admission_bypass(ctx: Ctx) -> list[Finding]:
     #: names bound from get_resident_loop(...) anywhere in the file —
     #: one hop of dataflow catches `loop = get_resident_loop(c)`
     tainted: set[str] = set()
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if isinstance(node, ast.Assign) \
                 and isinstance(node.value, ast.Call) \
                 and _final_ident(node.value.func) \
@@ -1239,7 +1243,7 @@ def rule_admission_bypass(ctx: Ctx) -> list[Finding]:
         return None
 
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, ast.Call):
             continue
         hit = bypasses(node)
@@ -1311,7 +1315,7 @@ def rule_shared_state_unlocked(ctx: Ctx) -> list[Finding]:
     two writers interleaving between read and write. ``__init__``
     writes are pre-publication and exempt both ways."""
     out = []
-    for cls in ast.walk(ctx.tree):
+    for cls in ctx.nodes:
         if not isinstance(cls, ast.ClassDef):
             continue
         methods = [n for n in cls.body if isinstance(
@@ -1360,7 +1364,7 @@ def rule_check_then_act(ctx: Ctx) -> list[Finding]:
     check and the act. Lock-holding conventions (``with <lockish>:``,
     ``*_locked`` names, locked decorators) exempt the site."""
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, ast.If):
             continue
         fn = _enclosing_function(ctx, node)
@@ -1419,7 +1423,7 @@ def rule_cond_wait_no_loop(ctx: Ctx) -> list[Finding]:
     must re-check in a loop — the shape schedcheck's notify scheduling
     exercises directly."""
     out = []
-    for node in ast.walk(ctx.tree):
+    for node in ctx.nodes:
         if not isinstance(node, ast.Call) \
                 or not isinstance(node.func, ast.Attribute) \
                 or node.func.attr != "wait" \
