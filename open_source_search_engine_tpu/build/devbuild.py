@@ -394,11 +394,17 @@ def _doc_meta(sr_tab, dl_tab, docidx, sr_rows, lg_rows, n):
                                    "n_lanes"))
 def _cube_rows(payload, docc, starts, cum, D: int, n_positions: int,
                total: int, n_lanes: int):
-    """Materialized [Vc, P, D] cube rows by one flattened scatter. The
-    scatter destination is derived from the resident docc column
-    (docidx<<4 | occ), so the host ships only the per-slot (start,
-    cumlen) descriptors — no posting-sized upload on either build
-    path."""
+    """Materialized cube rows by one flattened scatter, handed back in
+    the ONE form the resident cube has: quarter rows ``[Vc·4, P/4, D]``
+    (row ``s·4 + q`` holds occurrences q·P/4 .. (q+1)·P/4 - 1 of slot
+    s's term, for every doc) — the array the FD kernel DMAs from as it
+    stands. The flat → quarter-row relayout (a copy of the whole cube
+    on a TPU) is paid here, once a base build, so that no wave program
+    pays it. The scatter destination is derived from the resident docc
+    column (docidx<<4 | occ), so the host ships only the per-slot
+    (start, cumlen) descriptors — no posting-sized upload on either
+    build path."""
+    P4 = n_positions // 4
     with jax.named_scope("build.cube_row_targets"):
         R = starts.shape[0]
         lane = jnp.arange(n_lanes, dtype=jnp.int32)
@@ -412,8 +418,10 @@ def _cube_rows(payload, docc, starts, cum, D: int, n_positions: int,
         dst = jnp.where(lane < cum[-1],
                         (row * n_positions + occ) * D + dxi, total)
     with jax.named_scope("build.cube_row_scatter"):
-        return jnp.zeros((total,), _U32).at[dst].set(payload[src],
+        flat = jnp.zeros((total,), _U32).at[dst].set(payload[src],
                                                      mode="drop")
+    with jax.named_scope("build.cube_quarter_rows"):
+        return flat.reshape(total // (P4 * D), P4, D)
 
 
 # ---------------------------------------------------------------------------

@@ -42,7 +42,11 @@ becomes two dense phases:
   its per-docid loop; here they route to a second kernel that scores
   the WHOLE doc axis exactly: the heaviest terms' position cubes are
   **materialized at build time** as [P, D] rows (plain slices at query
-  time — zero gather), smaller sublists (bigrams, deltas) scatter
+  time — zero gather; resident as ONE array of quarter rows,
+  ``d_cube`` [Vc·4, P/4, D_cap] uint32, the form the fused FD kernel
+  DMAs from, so no wave program copies or relayouts the cube: a
+  term's [P, D] row is four consecutive quarter rows, and the last
+  slot stays all-zero), smaller sublists (bigrams, deltas) scatter
   their postings in at posting granularity, and the same
   scorer.min_scores runs over [T, P, D]. Dense full-lane compute is
   exactly what the VPU is good at — no pruning needed, no escalation
@@ -802,8 +806,10 @@ class DeviceIndex:
 
         # --- cube rows: the very heaviest terms' [P, D] position cubes,
         # materialized so the full-cube kernel (F2) reads them as plain
-        # slices. Built device-side by one scatter from the posting
-        # columns — no multi-hundred-MB host upload ---
+        # slices and the FD kernel DMAs their quarter rows. Built
+        # device-side by one scatter from the posting columns — no
+        # multi-hundred-MB host upload — and kept as quarter rows
+        # [Vc·4, P/4, D_cap], the only form the cube has ---
         # adaptive budget: columns + dense rows are obligatory; the
         # cube takes what HBM can spare up to the hard cap
         nb_est = _bucket(max(n, 1), COL_QUANTUM)
@@ -816,8 +822,8 @@ class DeviceIndex:
             CUBE_BUDGET_BYTES,
             max(1 << 30, HBM_USABLE_BYTES - cols_bytes - dense_bytes
                 - WAVE_RESERVE_BYTES))
-        # Vc also buckets to a power of two AND its flat [Vc·P·D] index
-        # space must stay inside int32 for the build scatter — budget
+        # Vc also buckets to a power of two AND its Vc·P·D elements
+        # must stay inside int32 for the build's flat scatter — budget
         # against the bucketed size (at 250k docs the raw count 161
         # bucketed to 256 → exactly 2^31 elements → overflow)
         vc_cap = 4
@@ -904,7 +910,8 @@ class DeviceIndex:
                 total=total,
                 n_lanes=_bucket(max(int(c_cum[-1]), 1), COL_QUANTUM))
         else:
-            self.d_cube = jnp.zeros((total,), jnp.uint32)
+            self.d_cube = jnp.zeros((Vc * 4, P // 4, self.D_cap),
+                                    jnp.uint32)
         self._base_fp = fp
         self.full_rebuilds += 1
         log.info("device base built: %d postings, %d docs, %d terms "
@@ -2585,7 +2592,8 @@ def _full_cube(d_payload, d_docc, d_cube, d_dense_cnt,
     D = d_dead.shape[0]
     N = d_payload.shape[0]
     P = n_positions
-    VcPD = d_cube.shape[0]
+    P4 = P // 4
+    Vc = d_cube.shape[0] // 4
     big = jnp.float32(9.99e8)
 
     def one(c_slot, c_dslot, c_group, c_base, c_quota, c_syn,
@@ -2606,9 +2614,11 @@ def _full_cube(d_payload, d_docc, d_cube, d_dense_cnt,
             V = d_dense_cnt.shape[0] // D
             for r in range(Rc):
                 gate = c_slot[r] >= 0
+                # a slot's [P, D] row = its four quarter rows
                 row = jax.lax.dynamic_slice(
-                    d_cube, (jnp.clip(c_slot[r], 0, VcPD // (P * D) - 1)
-                             * P * D,), (P * D,)).reshape(P, D)
+                    d_cube, (jnp.clip(c_slot[r], 0, Vc - 1) * 4,
+                             jnp.int32(0), jnp.int32(0)),
+                    (4, P4, D)).reshape(P, D)
                 cnt = jax.lax.dynamic_slice(
                     d_dense_cnt, (jnp.clip(c_dslot[r], 0, V - 1) * D,),
                     (D,)).astype(jnp.int32)
@@ -2728,13 +2738,11 @@ def _direct_cube(d_cube, d_payload, d_docc, d_siterank,
     Output format matches _full_cube."""
     D = d_dead.shape[0]
     P = n_positions
-    P4 = P // 4
     N = d_payload.shape[0]
-    Vc = d_cube.shape[0] // (P * D)
+    Vc4 = d_cube.shape[0]
     big = jnp.float32(9.99e8)
-    quarter_rows = d_cube.reshape(Vc * 4, P4 * D)
 
-    from .pallas_scores import fd_scores_fused, use_fused
+    from .pallas_scores import use_fused
     if use_fused(D):
         return _direct_cube_fused(
             d_cube, d_payload, d_docc, d_siterank, d_doclang, d_dead,
@@ -2752,8 +2760,7 @@ def _direct_cube(d_cube, d_payload, d_docc, d_siterank,
         live = ~d_dead
         sc = counts
         with jax.named_scope("fd.cube_rows"):
-            rows = quarter_rows[
-                jnp.clip(g_quarter, 0, Vc * 4 - 1)].reshape(T, 4, P4, D)
+            rows = d_cube[jnp.clip(g_quarter, 0, Vc4 - 1)]  # [T,4,P4,D]
             synbit = (g_qsyn.astype(jnp.uint32)
                       << jnp.uint32(31))[:, :, None, None]
             rows = jnp.where(rows != 0, rows | synbit, rows)

@@ -323,9 +323,11 @@ def _fd_call(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
 
     ``g_quarter``/``g_qsyn`` [B, T·4] int32 — absolute quarter-row
     indices into the resident cube + per-quarter synonym flags;
-    ``d_cube`` the flat resident cube [Vc·P·D]; ``tail_cube``
-    [B, T, P, D] uint32 — the XLA-scattered posting tail (zeros where
-    the query has none); ``dead_i32`` [1, D]."""
+    ``d_cube`` the resident cube as it is built and kept, quarter rows
+    [Vc·4, P/4, D]: the kernel's HBM operand as it stands, so no wave
+    program copies or relayouts it; ``tail_cube`` [B, T, P, D] uint32
+    — the XLA-scattered posting tail (zeros where the query has none);
+    ``dead_i32`` [1, D]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -333,12 +335,7 @@ def _fd_call(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
     assert TQ == 4 * T
     D = dead_i32.shape[1]
     assert D % TILE_D == 0
-    P4 = P // 4
-    Vc4 = d_cube.shape[0] // (P4 * D)
-    # the flat cube -> quarter rows relayout: a copy of the whole
-    # resident cube on the device, named so the trace can say so
-    with jax.named_scope("fd.cube_relayout"):
-        rows3 = d_cube.reshape(Vc4, P4, D)
+    assert d_cube.shape[1:] == (P // 4, D), d_cube.shape
     # (B, 1, T) so every block dim equals an array dim (Mosaic requires
     # sublane block dims to match the array or divide 8)
     fw = freqw.astype(jnp.float32).reshape(B, 1, T)
@@ -347,7 +344,7 @@ def _fd_call(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.ANY),   # resident rows: HBM
     ]
-    operands = [rows3]
+    operands = [d_cube]
     if has_tail:
         in_specs.append(
             pl.BlockSpec((1, T, P, TILE_D),

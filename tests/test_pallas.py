@@ -66,6 +66,19 @@ class TestMinScoresFused:
         np.testing.assert_allclose(np.asarray(pal), np.asarray(ref))
 
 
+def _assert_same_ranking(ref_ids, ref_scores, ids, scores, label):
+    """Scores agree to the last-ulp reduction order; docids agree at
+    strictly-untied ranks (tie order is not part of the contract)."""
+    np.testing.assert_allclose(scores, ref_scores, rtol=1e-5,
+                               err_msg=label)
+    for r in range(len(ref_scores)):
+        tied = ((r > 0 and ref_scores[r - 1] == ref_scores[r])
+                or (r + 1 < len(ref_scores)
+                    and ref_scores[r + 1] == ref_scores[r]))
+        if not tied:
+            assert ref_ids[r] == ids[r], (label, r)
+
+
 class TestFusedEndToEnd:
     def test_fd_route_matches_jnp_path(self, tmp_path):
         """Index a corpus whose common multi-term queries take the FD
@@ -101,15 +114,7 @@ class TestFusedEndToEnd:
                     assert di.route_counts["fd"] > 0  # FD exercised
             for q, a, b in zip(queries, outs["0"], outs["force"]):
                 assert a[2] == b[2], q                   # n_matched
-                np.testing.assert_allclose(b[1], a[1], rtol=1e-5,
-                                           err_msg=q)   # scores
-                # docids equal at strictly-untied ranks
-                for r in range(len(a[1])):
-                    tied = ((r > 0 and a[1][r - 1] == a[1][r])
-                            or (r + 1 < len(a[1])
-                                and a[1][r + 1] == a[1][r]))
-                    if not tied:
-                        assert a[0][r] == b[0][r], (q, r)
+                _assert_same_ranking(a[0], a[1], b[0], b[1], q)
         finally:
             for k, v in saved.items():
                 if v is None:
@@ -117,3 +122,81 @@ class TestFusedEndToEnd:
                 else:
                     os.environ[k] = v
             dv._direct_cube.clear_cache()
+
+
+class TestQuarterRowCube:
+    """The resident cube has ONE form, quarter rows [Vc·4, P/4, D_cap]
+    (the FD kernel's operand as it stands): the build hands back what
+    the old flat scatter held, reshaped, and every reader — the fused
+    FD kernel (interpret mode here), the jnp ``_direct_cube`` body and
+    F2's ``_full_cube`` — answers from it as the host flat path does."""
+
+    @pytest.fixture(scope="class")
+    def env(self, tmp_path_factory):
+        from open_source_search_engine_tpu.build import docproc
+        from open_source_search_engine_tpu.index.collection import \
+            Collection
+        from open_source_search_engine_tpu.parallel.routecheck import \
+            ROUTE_ENV, route_docs
+        from open_source_search_engine_tpu.query import engine
+        import open_source_search_engine_tpu.query.devindex as dv
+
+        saved = {k: os.environ.get(k) for k in
+                 list(ROUTE_ENV) + ["OSSE_PALLAS"]}
+        os.environ.update(ROUTE_ENV)
+        coll = Collection("q", str(tmp_path_factory.mktemp("qrc")))
+        coll.conf.pqr_enabled = False   # kernel parity: pre-PQR scores
+        docproc.index_batch(coll, route_docs(256, "qrc"))
+        coll.posdb.dump()
+        coll.titledb.dump()
+        yield coll, engine.get_device_index(coll)
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+        dv._direct_cube.clear_cache()
+
+    def test_build_hands_back_the_flat_scatter_as_quarter_rows(self, env):
+        _, di = env
+        P, D, Vc = di.P, di.D_cap, di.Vc
+        assert len(di.cube_slot_of) > 0
+        payload = np.asarray(di.d_payload)
+        docc = np.asarray(di.d_docc)
+        flat = np.zeros(Vc * P * D, np.uint32)      # the former form
+        for termid, slot in di.cube_slot_of.items():
+            ti = int(np.searchsorted(di.dir_termids, np.uint64(termid)))
+            a, b = int(di.dir_pstart[ti]), int(di.dir_pstart[ti + 1])
+            occ = (docc[a:b] & 0xF).astype(np.int64)
+            doc = (docc[a:b] >> 4).astype(np.int64)
+            flat[(slot * P + occ) * D + doc] = payload[a:b]
+        cube = np.asarray(di.d_cube)
+        assert cube.shape == (Vc * 4, P // 4, D)
+        assert flat.any()
+        assert np.array_equal(cube, flat.reshape(Vc * 4, P // 4, D))
+        assert not cube[4 * di.cube_zero_slot:].any()  # absent quarter
+
+    @pytest.mark.parametrize("pallas", ["0", "force"],
+                             ids=["jnp", "fused"])
+    def test_fd_and_f2_answer_as_the_host_flat_path(self, env, pallas):
+        from open_source_search_engine_tpu.query import engine
+        import open_source_search_engine_tpu.query.devindex as dv
+
+        coll, di = env
+        os.environ["OSSE_PALLAS"] = pallas
+        dv._direct_cube.clear_cache()
+        for q, route in (("alpha beta", "fd"), ("alpha gamma", "fd"),
+                         ("boxes dogs", "f2")):
+            before = di.route_counts[route]
+            dev = engine.search_device(coll, q, topk=8,
+                                       site_cluster=False,
+                                       with_snippets=False)
+            assert di.route_counts[route] == before + 1, (q, route)
+            host = engine.search(coll, q, topk=8, site_cluster=False,
+                                 with_snippets=False)
+            assert dev.total_matches == host.total_matches, q
+            _assert_same_ranking(
+                [r.docid for r in host.results],
+                [r.score for r in host.results],
+                [r.docid for r in dev.results],
+                [r.score for r in dev.results], q)
