@@ -372,12 +372,16 @@ def _serve_and_check(n_docs: int, srv, dispatched: dict) -> None:
     check(waves.get("devindex.wave_f1_n1", 0) > 0
           and waves.get("devindex.wave_f2_n1", 0) > 0,
           f"wave timers: {waves}")
-    singles = [b for k, b in dispatched
-               if k == "devindex._two_phase" and b[5] < b[4]]
-    multis = [b for k, b in dispatched
-              if k == "devindex._two_phase" and b[5] == b[4]]
-    check(bool(singles) and bool(multis),
-          f"two-phase waves: single {singles}, multi-term {multis}")
+    # every two-phase wave (single-term and F1 groups alike: one width
+    # a rung since PR 30) rode a program of the set the index
+    # enumerated, which start-up had dispatched before the first query
+    # (a rung above the ladder, which a query may escalate to, is not
+    # of the set)
+    two_phase = {b for k, b in dispatched if k == "devindex._two_phase"
+                 and b[4] <= devindex.F1_RUNGS[-1] * devindex.KAPPA_FLOOR}
+    check(bool(two_phase) and two_phase <= set(di.f1_programs()),
+          f"two-phase waves outside the enumerated set: "
+          f"{sorted(two_phase - set(di.f1_programs()))}")
     kernels = {}
     for (name, bucket), (fn, sds, statics) in dispatched.items():
         if name == "devindex._two_phase":

@@ -1161,7 +1161,7 @@ class MeshResident:
             di.refresh()
 
     def warm(self) -> None:
-        list(self._pool.map(lambda di: di.warm(), self.indexes))
+        list(self._pool.map(lambda di: di.warm_f1(), self.indexes))
 
     def _global_df(self, termid: int) -> int:
         """Cluster-wide document frequency, memoized per (termid,

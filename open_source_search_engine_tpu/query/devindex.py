@@ -132,24 +132,79 @@ _WAVE_STAT = {(k, n): f"devindex.wave_{k}_n{n}"
               for k in ("f1", "f2", "f1+f2") for n in _WAVE_NBUCKETS}
 
 
+#: an F1 wave's κ rung on the same histogram family (one record a
+#: wave, over its round's fetch interval): the ladder's three rungs and
+#: everything above them — four names, fixed at import
+_WAVE_KAPPA_STAT = {k: f"devindex.wave_f1_k{k}"
+                    for k in (256, 2048, 8192, "max")}
+#: which programs a span of time dispatched, as counters: a program key
+#: takes the next slot at its first dispatch (the last slot takes every
+#: key past the table), and every dispatch counts its slot — the number
+#: of slots that moved is the number of distinct programs (a lower
+#: bound where several indexes serve)
+_PROGRAM_SLOT_STAT = tuple(f"devindex.program_slot.{i:02d}"
+                           for i in range(64))
+#: a query each, where ``route_counts`` is bumped (first route only)
+_ROUTE_STAT = {r: f"devindex.route.{r}" for r in ("f1", "fd", "f2")}
+
+
+def _f1_rows(mrd: int, mrs: int, mls: int, upper: bool = False
+             ) -> tuple[int, int, int]:
+    """(Rd, Rs, Lsp) of an F1 wave whose widest rider has ``mrd`` dense
+    rows, ``mrs`` sparse rows and a longest sparse run of ``mls``: the
+    first tier of the chain that covers all three (``upper``: a rung
+    above the first, which rides the long-run tiers only)."""
+    for t in (F1_UPPER_TIERS if upper else F1_TIERS):
+        if mrd <= t[0] and mrs <= t[1] and mls <= t[2]:
+            return t
+    # past 16 rows: outside the enumerated set, bucketed as before
+    return (16 if mrd <= 16 else _bucket(mrd, 64),
+            16 if mrs <= 16 else _bucket(mrs, 64), LSP_MAX)
+
+
 def _wave_nbucket(n: int) -> int:
     for b in _WAVE_NBUCKETS:
         if n <= b:
             return b
     return _WAVE_NBUCKETS[-1]
 
-#: shape-bucket floors (distinct shape tuples = one XLA compile each)
-RD_FLOOR = 4      # dense rows
-RS_FLOOR = 4      # sparse rows
-#: sparse gather lane buckets (length-bucketed termlist tiles, SURVEY
-#: §7 stage-8): waves whose longest sparse run is short ride a short
-#: tile instead of paying the full 2048-lane gather per row — the
-#: padding bytes were most of the sparse HBM traffic for everyday
-#: queries (the dense threshold D_cap//64 keeps runs under the top)
-LSP_BUCKETS = (128, 512, 2048)
-LSP_FLOOR = LSP_BUCKETS[0]
-B_FLOOR = 4
+#: sparse gather lane tiles (length-bucketed termlist tiles, SURVEY
+#: §7 stage-8) are the third number of an F1 tier below: waves whose
+#: longest sparse run is short ride a short tile instead of paying the
+#: full 2048-lane gather per row — the padding bytes were most of the
+#: sparse HBM traffic for everyday queries (the dense threshold
+#: D_cap//64 keeps runs under the top)
 KAPPA_FLOOR = 256  # phase-2 candidate count
+
+#: THE CLOSED F1 PROGRAM SPACE. A ``_two_phase`` program is keyed on
+#: (B, Rd, Rs, Lsp, κ, k2) plus T and three flags, and a wave takes the
+#: MAXIMUM of its riders' row counts and run lengths — so two queries
+#: that each ran alone can form a key neither reached. Invariant: for
+#: any set of F1 plans ``_issue_waves`` puts into one ``_run_batch``
+#: call, of queries of up to T_FLOOR plain words with the default
+#: filter and sort, the key is a member of ``f1_programs()``, which
+#: the index enumerates from its D_cap alone. How: the row shape
+#: (Rd, Rs, Lsp) is the first TIER of a chain that covers the wave (a
+#: chain, so the join of any riders is a tier); a wave holds four
+#: plans (B = 4: a fuller batch is more waves, not a wider program);
+#: on the ladder's three rungs phase 2 scores every selected
+#: candidate (k2 = κ, for single-group plans too); and a rung above
+#: the first rides the two long-run tiers only (escapees are few).
+#: Nine programs, sized by what a server can compile at start-up
+#: (PERF.md, Findings PR 30: ~120 s cold; each wider B bucket, each
+#: separate k2 would be as many again). Outside the set, and shaped as
+#: before: five words and more (T = 8), boolean tables, filters,
+#: sorts, κ above 32·KAPPA_FLOOR up to the terminal D_cap, and plans
+#: past 16 rows (``devindex.f1.key_outside_set`` counts their
+#: dispatches).
+F1_TIERS = ((4, 2, 128), (4, 2, 512), (4, 4, 512), (4, 4, 2048),
+            (16, 16, 2048))
+#: the tiers a rung above the first may ride (the last two)
+F1_UPPER_TIERS = F1_TIERS[-2:]
+F1_B = 4
+#: the κ ladder's rungs, in KAPPA_FLOORs; above the last the rung is
+#: the need's own bucket, up to D_cap
+F1_RUNGS = (1, 8, 32)
 DOC_UPD_FLOOR = 64
 
 #: doc-capacity quantum (D_cap bucket unit)
@@ -529,6 +584,11 @@ class DeviceIndex:
         #: dispatches by (program name, shape bucket), counted where
         #: every wave program goes through (``_costed``)
         self.dispatches: dict[tuple, int] = {}
+        self._slot_of: dict[tuple, str] = {}    # key -> its slot's counter
+        #: ``warm_f1`` ran on this index (its programs are this
+        #: process's; a fresh index after a rebuild has the same shapes
+        #: unless D_cap or a column bucket moved, and then warms again)
+        self._f1_warmed = False
         #: resident-plan cache (the termlist-cache role, RdbCache): the
         #: per-query host planning pass — directory binary searches, df
         #: lookups, slot planning, row layout — repeats byte-identically
@@ -914,6 +974,10 @@ class DeviceIndex:
                                     jnp.uint32)
         self._base_fp = fp
         self.full_rebuilds += 1
+        # D_cap or a column bucket may have moved: the set is enumerated
+        # anew, and its programs are other programs
+        self._f1_keys = frozenset(self.f1_programs())
+        self._f1_warmed = False
         log.info("device base built: %d postings, %d docs, %d terms "
                  "(%d dense rows, %d cube rows, cap %d)", n, Db,
                  len(self.dir_termids), len(dense_terms),
@@ -1609,11 +1673,12 @@ class DeviceIndex:
 
         f2 = [i for i in live if _route_f2(i)]
         f1 = [i for i in live if i not in set(f2)]
-        self.route_counts["f1"] += len(f1)
-        self.route_counts["fd"] += sum(
-            1 for i in f2 if plans[i].direct_ok)
-        self.route_counts["f2"] += sum(
-            1 for i in f2 if not plans[i].direct_ok)
+        n_fd = sum(1 for i in f2 if plans[i].direct_ok)
+        for route, n in (("f1", len(f1)), ("fd", n_fd),
+                         ("f2", len(f2) - n_fd)):
+            self.route_counts[route] += n
+            if n:
+                g_stats.count(_ROUTE_STAT[route], n)
 
         # wave loop: issue EVERY sub-batch dispatch, fetch ALL outputs
         # in one device_get (one host sync), then parse; queries whose
@@ -1658,8 +1723,15 @@ class DeviceIndex:
             # bounds are distance-free (up to ~400× loose), so
             # bound order ≉ exact order and truncation would
             # escalate nearly every query (measured 57%). Multi-
-            # group plans score every selected candidate.
-            if plans[i].n_scored <= 1:
+            # group plans score every selected candidate — and on the
+            # ladder's rungs single-group plans do too: one width a
+            # rung keeps the enumerated program set at one program a
+            # (tier, rung), and one-word and several-word queries of a
+            # rung ride one wave (κ = 256: twice the 128 phase-2
+            # gathers a one-word query would need; PERF.md, PR 30).
+            # Above the ladder the truncation is worth its programs.
+            if (plans[i].n_scored <= 1
+                    and kapi > F1_RUNGS[-1] * KAPPA_FLOOR):
                 k2i = min(max(k2v, plans[i].k2_min), kapi)
             else:
                 k2i = kapi
@@ -1668,10 +1740,7 @@ class DeviceIndex:
                  plans[i].filters, plans[i].sortby), []).append(i)
         for (kappa, k2g, *_spec), idxs in sorted(
                 groups.items(), key=lambda kv: str(kv[0])):
-            # terminal rungs chunk small so the [T, P, k2]·B
-            # phase-2 intermediates stay bounded at k2 = D_cap
-            step = self._f1_bmax() if k2g <= 32 * KAPPA_FLOOR \
-                else self._f1_step_terminal()
+            step = self._f1_step(k2g)
             for a in range(0, len(idxs), step):
                 chunk = idxs[a:a + step]
                 waves.append(("f1", kappa, k2g, chunk,
@@ -1738,6 +1807,10 @@ class DeviceIndex:
             stat = _WAVE_STAT.get((kinds, _wave_nbucket(len(waves))))
             if stat is not None:
                 trace.record(stat, t_fetch, t_got)
+            for w in waves:
+                if w[0] == "f1":
+                    trace.record(_WAVE_KAPPA_STAT.get(
+                        w[1], _WAVE_KAPPA_STAT["max"]), t_fetch, t_got)
             fetched = int(sum(np.asarray(o).nbytes for o in outs))
             # device-time attribution: device_get blocks until every
             # issued wave completes (the block_until_ready delta), so
@@ -1802,153 +1875,76 @@ class DeviceIndex:
                 f2_nsel, pending.bmax) if (f1_next or f2_next) else []
         return results
 
-    def warm(self) -> int:
-        """Precompile the shape variants everyday queries hit (one dummy
-        dispatch each; results discarded) — cold XLA compiles landing
-        mid-measurement doubled run-to-run variance. Not exhaustive:
-        deep-paging k2 sizes, terminal escalation rungs, and >64-row
-        plans still compile on first use (rare by construction). About
-        150 programs: tens of minutes on a cold chip (each FD variant
-        alone compiled 89 s on the v5e), so nothing on a request's thread
-        calls this — a caller that wants no compile inside its window
-        (bench.py) does. Compiles persist in the XLA compilation cache
-        (utils/compilecache.py), so warm() after a restart is cheap."""
+    def f1_programs(self) -> list[tuple]:
+        """The closed F1 program space (``F1_TIERS``' invariant), as
+        ``_costed`` buckets (B, Rd, Rs, Lsp, κ, k2) at T = T_FLOOR with
+        no table, filter or sort — enumerated from D_cap alone, no
+        query seen. First rung: every tier; the two rungs above it:
+        the long-run tiers. In dispatch order: ``warm_f1`` walks it as
+        it stands."""
+        kap = [min(r * KAPPA_FLOOR, self.D_cap) for r in F1_RUNGS]
+        out = [(F1_B, *tier, kap[0], kap[0]) for tier in F1_TIERS]
+        for kappa in kap[1:]:
+            out += [(F1_B, *tier, kappa, kappa) for tier in F1_UPPER_TIERS]
+        return list(dict.fromkeys(out))   # a small D_cap folds rungs
+
+    def warm_f1(self) -> int:
+        """Dispatch every program of ``f1_programs()`` once (a dummy
+        wave each, results discarded), so that no F1 query of the set
+        can meet a compile afterwards. The served path calls this at
+        start-up, after the base is built and before the index is
+        handed to the resident loop (``SearchHTTPServer._warm_device``
+        -> ``ResidencyManager.loop_for(warm=True)``); a second call on
+        a warmed index returns at once. Compiles persist in the XLA
+        compilation cache
+        (utils/compilecache.py): cold it is seconds a program, after a
+        restart a load. FD and F2 programs are not part of it (a fused
+        FD variant compiles for 90 s: ROADMAP S1)."""
+        if self._f1_warmed:
+            return 0
         T = T_FLOOR
         z = np.zeros
 
-        def dummy(ns: int = 1, np_rows: int = 1,
-                  nd: int = 1) -> ResidentPlan:
+        def dummy(nd: int, ns: int, run: int) -> ResidentPlan:
             req = z(T, bool)
             req[0] = True
+            one = lambda n, dt=np.int32: np.ones(n, dt)
             return ResidentPlan(
                 d_slot=z(nd, np.int32), d_group=z(nd, np.int32),
-                d_base=z(nd, np.int32), d_quota=np.ones(nd, np.int32),
+                d_base=z(nd, np.int32), d_quota=one(nd),
                 d_syn=z(nd, np.uint32),
-                s_start=z(ns, np.int32), s_len=np.ones(ns, np.int32),
+                s_start=z(ns, np.int32), s_len=np.full(ns, run, np.int32),
                 s_group=z(ns, np.int32), s_base=z(ns, np.int32),
-                s_quota=np.ones(ns, np.int32), s_syn=z(ns, np.uint32),
-                s_isbase=np.ones(ns, bool),
+                s_quota=one(ns), s_syn=z(ns, np.uint32),
+                s_isbase=one(ns, bool),
                 c_slot=z(1, np.int32), c_dslot=z(1, np.int32),
                 c_group=z(1, np.int32), c_base=z(1, np.int32),
-                c_quota=np.ones(1, np.int32), c_syn=z(1, np.uint32),
-                p_start=z(np_rows, np.int32),
-                p_len=np.ones(np_rows, np.int32),
-                p_group=z(np_rows, np.int32), p_base=z(np_rows, np.int32),
-                p_quota=np.ones(np_rows, np.int32),
-                p_syn=z(np_rows, np.uint32),
-                p_isbase=np.ones(np_rows, bool),
+                c_quota=one(1), c_syn=z(1, np.uint32),
+                p_start=z(1, np.int32), p_len=one(1),
+                p_group=z(1, np.int32), p_base=z(1, np.int32),
+                p_quota=one(1), p_syn=z(1, np.uint32),
+                p_isbase=one(1, bool),
                 freq_weight=np.full(T, 0.5, np.float32),
                 required=req, negative=z(T, bool), scored=req.copy(),
                 counts=req.copy(), table=pad_table(None), qlang=0,
                 matchable=True)
 
-        outs = []
-        k2 = min(128, self.D_cap)
-        kap = min(KAPPA_FLOOR, self.D_cap)
-        shape_grid = ((1, 1), (2, 1), (1, 2), (3, 3), (5, 5), (17, 1))
-        b1 = self._f1_bmax()
-        # one nb per runtime B bucket (4/8/16/32/64), capped by the
-        # HBM budget so warm never compiles a shape runtime can't use
-        nbs = tuple(sorted({min(nb, b1) for nb in (1, 5, 9, 17, 33)}))
-        for ns, nd in shape_grid:          # κ=256 base rung
-            for nb in nbs:                 # B buckets the budget allows
-                # single-group (k2=128) AND multi-group (k2=κ) widths
-                outs.append(self._run_batch(
-                    [dummy(ns=ns, nd=nd)] * nb, kap, min(k2, kap)))
-                outs.append(self._run_batch(
-                    [dummy(ns=ns, nd=nd)] * nb, kap, kap))
-        kap8 = min(KAPPA_FLOOR * 8, self.D_cap)
-        for ns, nd in ((1, 1), (2, 1), (1, 2), (3, 3)):  # κ=2048 rung
-            for nb in (1, 5, 9, 33):     # B = 4 / 8 / 32 / 64
-                outs.append(self._run_batch(
-                    [dummy(ns=ns, nd=nd)] * nb, kap8, min(k2, kap8)))
-                outs.append(self._run_batch(
-                    [dummy(ns=ns, nd=nd)] * nb, kap8, kap8))
-        # Lsp length buckets: the dummies above (s_len=1) warm the
-        # 128-lane tile; mid/long sparse runs hit the 512/2048-lane
-        # variants — warm those on the common shapes only
-        for lsp_len in LSP_BUCKETS[1:]:
-            for ns, nd in ((1, 1), (2, 1), (3, 3)):
-                pL = dummy(ns=ns, nd=nd)
-                pL.s_len[0] = lsp_len
-                for nb in ((1, 5) if b1 > 4 else (1,)):
-                    outs.append(self._run_batch(
-                        [pL] * nb, kap, min(k2, kap)))
-                    outs.append(self._run_batch([pL] * nb, kap, kap))
-        # escalation rungs: (κ, k2) widen together, B=4 (few escapees)
-        kap32 = min(KAPPA_FLOOR * 32, self.D_cap)
-        outs.append(self._run_batch([dummy()], kap8,
-                                    min(KAPPA_FLOOR * 2, kap8)))
-        outs.append(self._run_batch([dummy()], kap32,
-                                    min(KAPPA_FLOOR * 8, kap32)))
-        for ns, nd in ((1, 1), (2, 1), (3, 3)):  # multi-group escapees
-            outs.append(self._run_batch([dummy(ns=ns, nd=nd)], kap32,
-                                        kap32))
-            outs.append(self._run_batch([dummy(ns=ns, nd=nd)] * 5,
-                                        kap32, kap32))
-        # B > 4 buckets exist only when the HBM budget allows them
-        nb_big = (1, 5) if self._f2_bmax() > 4 else (1,)
-        nb_fd = (1, 5) if self._fd_bmax() > 4 else (1,)
-        # selection rungs match search_batch's f2_floor ladder
-        ns0 = 4096 if self.D_cap >= (1 << 19) else 2048
-        for n_sel in (ns0, 4 * ns0):  # F2 base + first escalation rung
-            for np_rows in (1, 9):
-                for nb in nb_big:  # B = 4 and (budget allowing) B = bmax
-                    p = dummy(np_rows=np_rows)
-                    p.p_len[:] = 1
-                    outs.append(self._run_batch_f2(
-                        [p] * nb, k2, min(n_sel, self.D_cap)))
-                    p2 = dummy(np_rows=np_rows)
-                    p2.p_len[0] = F2_LPOST_FLOOR + 1  # big-Lp bucket
-                    outs.append(self._run_batch_f2(
-                        [p2] * nb, k2, min(n_sel, self.D_cap)))
-        # FD (direct-cube) shapes: B = 4 and B = 16 buckets, with and
-        # without scatter tails (delta postings put every fresh write
-        # on the tail, so the Lp=512 and Lp=4096 variants are everyday)
-        pd = dummy()
-        pd.g_quarter = np.zeros((T, 4), np.int32)
-        pd.g_qsyn = np.zeros((T, 4), np.uint32)
-        pd0 = dummy()  # no-tail variant (pure quarter-row waves)
-        pd0.g_quarter = np.zeros((T, 4), np.int32)
-        pd0.g_qsyn = np.zeros((T, 4), np.uint32)
-        pd0.p_len[:] = 0
-        pt = dummy(np_rows=5)  # Rp=8 bucket
-        pt.g_quarter = np.zeros((T, 4), np.int32)
-        pt.g_qsyn = np.zeros((T, 4), np.uint32)
-        pt.p_len[:] = 1
-        pl = dummy()
-        pl.g_quarter = np.zeros((T, 4), np.int32)
-        pl.g_qsyn = np.zeros((T, 4), np.uint32)
-        pl.p_len[0] = 513  # Lp=4096 bucket
-        pl2 = dummy()
-        pl2.g_quarter = np.zeros((T, 4), np.int32)
-        pl2.g_qsyn = np.zeros((T, 4), np.uint32)
-        pl2.p_len[0] = F2_LPOST_FLOOR + 1  # Lp=16384 bucket (big
-        # bigram scatter tails — one unwarmed hit cost a 91 s compile
-        # inside a measured pass)
-        for n_sel in (ns0, 4 * ns0):
-            for nb in nb_fd:
-                outs.append(self._run_batch_fd(
-                    [pd] * nb, k2, min(n_sel, self.D_cap)))
-                outs.append(self._run_batch_fd(
-                    [pd0] * nb, k2, min(n_sel, self.D_cap)))
-                if n_sel == ns0:
-                    outs.append(self._run_batch_fd(
-                        [pt] * nb, k2, min(n_sel, self.D_cap)))
-                    outs.append(self._run_batch_fd(
-                        [pl] * nb, k2, min(n_sel, self.D_cap)))
-                    outs.append(self._run_batch_fd(
-                        [pl2] * nb, k2, min(n_sel, self.D_cap)))
-        jax.device_get(outs)
-        return len(outs)
+        with trace.timed_span("devindex.warm_f1"):
+            keys = self.f1_programs()
+            outs = [self._run_batch([dummy(rd, rs, lsp)], kappa, k2)
+                    for _, rd, rs, lsp, kappa, k2 in keys]
+            jax.device_get(outs)
+        g_stats.count("devindex.f1.programs_enumerated", len(keys))
+        self._f1_warmed = True
+        return len(keys)
 
     def warm_plans(self) -> None:
         """Build-time pre-warm of the host lazies the FIRST query would
         otherwise pay (the cold-plan spike: ``devindex.plan`` max
         1168 ms vs 0.3 ms min): the docid argsort + inverse permutation
-        and the clusterdb sitehash/langid columns, a few ms. The kernel
-        shape-grid sweep is :meth:`warm`, and is the caller's call —
-        this runs on the thread of the first request a server gets."""
+        and the clusterdb sitehash/langid columns, a few ms. The F1
+        program set is :meth:`warm_f1`'s, which the served path calls
+        at start-up."""
         self._docid_pos(np.empty(0, np.uint64))
         self._cluster_cols()
 
@@ -1986,23 +1982,17 @@ class DeviceIndex:
             need = max(KAPPA_FLOOR, 2 * topk, p.kappa_min)
         else:
             need = max(KAPPA_FLOOR, 2 * topk, p.driver_df, p.kappa_min)
-        for rung in (KAPPA_FLOOR, 8 * KAPPA_FLOOR, 32 * KAPPA_FLOOR):
-            if need <= rung:
-                return min(rung, self.D_cap)
+        for r in F1_RUNGS:
+            if need <= r * KAPPA_FLOOR:
+                return min(r * KAPPA_FLOOR, self.D_cap)
         return min(_bucket(need, KAPPA_FLOOR), self.D_cap)
 
-    def _f1_bmax(self) -> int:
-        """Largest F1 wave B the HBM budget allows (power of two ≤ 64):
-        phase-1 intermediates run ~128·D bytes per lane (the single
-        [T, D] scatter target — base dead-masking happens at gather
-        time — plus the [T, D] bound chains) — at 100k docs B=64 fits
-        easily; at a 1M-doc shard it must drop or the wave OOMs next
-        to the ~9 GB resident set."""
-        cap = max(4, (2 << 30) // (128 * self.D_cap))
-        b = 4
-        while b * 2 <= cap and b < 64:
-            b *= 2
-        return b
+    def _f1_step(self, k2: int) -> int:
+        """How many plans one ``_run_batch`` call takes: a wave's B, or
+        fewer on a terminal rung, whose [T, P, k2]·B phase-2
+        intermediates must stay bounded at k2 = D_cap."""
+        return F1_B if k2 <= F1_RUNGS[-1] * KAPPA_FLOOR \
+            else self._f1_step_terminal()
 
     def _f1_step_terminal(self) -> int:
         """Terminal-rung (k2 = D_cap) chunk size: the exact-scoring
@@ -2011,8 +2001,7 @@ class DeviceIndex:
 
     def _f2_bmax(self) -> int:
         """F2 batch cap: full-cube intermediates are ~48 bytes/doc/query
-        ([T,P,D] cube+validity+scores) — bound them to ~1.5 GB (wave
-        RTT is ~100 ms, so doubling B nearly halves F2 wall time)."""
+        ([T,P,D] cube+validity+scores) — bound them to ~1.5 GB."""
         per_q = 48 * MAX_POSITIONS * self.D_cap
         return max(4, min(16, (1536 << 20) // max(per_q, 1)))
 
@@ -2033,7 +2022,7 @@ class DeviceIndex:
         under the live packed layout (f16 impacts, uint8 doc meta,
         length-bucketed Lsp tiles) or the legacy unpacked one (f32
         impacts, int32 meta, flat 2048-lane tiles). Shares _run_batch's
-        bucket ladders so the model moves when the layout does; the
+        tier chain so the model moves when the layout does; the
         per-plan Lsp tile is the fine-grained bound (real waves pay
         their rung-group's max). BENCH_DISPATCH enforces packed/legacy
         ≤ 0.7 on this model with a nonzero exit."""
@@ -2044,15 +2033,10 @@ class DeviceIndex:
         B = max(len(plans), 1)
         total = 0.0
         for p in plans:
-            mrs = max(len(p.s_start), 1)
-            Rs = 2 if mrs <= 2 else (4 if mrs <= 4 else (
-                16 if mrs <= 16 else _bucket(mrs, 64)))
             mls = int(p.s_len.max()) if len(p.s_len) else 0
-            Lsp = next(b for b in LSP_BUCKETS if mls <= b) if packed \
-                else LSP_BUCKETS[-1]
-            mrd = max(len(p.d_slot), 1)
-            Rd = 2 if mrd <= 2 else (4 if mrd <= 4 else (
-                16 if mrd <= 16 else _bucket(mrd, 64)))
+            Rd, Rs, Lsp = _f1_rows(max(len(p.d_slot), 1),
+                                   max(len(p.s_start), 1),
+                                   mls if packed else LSP_MAX)
             T = max(len(p.required), 1)
             k2 = min(128, D)
             # sparse lane gathers: doc4 + imp + rs4 + cnt1 + dead1
@@ -2074,9 +2058,16 @@ class DeviceIndex:
         a bandwidth/compute verdict next to the modeled wave bytes.
         Every dispatch is counted by (program, shape bucket) in
         ``self.dispatches``: which program each wave rode is the
-        index's own record (``/admin/device``), devwatch on or off."""
+        index's own record (``/admin/device``), devwatch on or off;
+        and in its slot's counter (``_PROGRAM_SLOT_STAT``), so that a
+        reader of g_stats can tell how many programs a window rode."""
         key = (name, tuple(int(x) for x in bucket))
-        self.dispatches[key] = self.dispatches.get(key, 0) + 1
+        n = self.dispatches.get(key, 0)
+        self.dispatches[key] = n + 1
+        if n == 0:
+            self._slot_of[key] = _PROGRAM_SLOT_STAT[
+                min(len(self._slot_of), len(_PROGRAM_SLOT_STAT) - 1)]
+        g_stats.count(self._slot_of[key])
         if devwatch.enabled():
             devwatch.note_cost(
                 name, bucket,
@@ -2085,45 +2076,30 @@ class DeviceIndex:
         return fn(*args, **statics)
 
     def _run_batch(self, plans: list[ResidentPlan], kappa: int, k2: int):
-        # pinned bucket ladders — every (Rd, Rs, κ, B) combination that
-        # everyday queries can hit is finite and enumerable, so warm()
-        # can precompile ALL of them and a measured window never eats
-        # a cold compile (run-to-run bench variance traced to exactly
-        # that)
+        # the row shape is a TIER of a chain (F1_TIERS): the wave pays
+        # for its widest rider — dense rows, sparse rows, and the lane
+        # tile of its longest sparse run (runs chunk at LSP_MAX in the
+        # planner, so the top tile always fits) — rounded up to the
+        # first tier that covers all three, so whichever riders meet in
+        # a wave the shape is one the index enumerated (f1_programs)
         mrd = max([len(p.d_slot) for p in plans] + [1])
-        Rd = 2 if mrd <= 2 else (4 if mrd <= 4 else (
-            16 if mrd <= 16 else _bucket(mrd, 64)))
         mrs = max([len(p.s_start) for p in plans] + [1])
-        Rs = 2 if mrs <= 2 else (4 if mrs <= 4 else (
-            16 if mrs <= 16 else _bucket(mrs, 64)))
-        # length-bucketed lane tile: the wave pays for its LONGEST
-        # sparse run's bucket (runs chunk at LSP_MAX in the planner, so
-        # the top bucket always fits); short-list waves stop paying
-        # 2048-lane padding — most of their sparse HBM bytes
         mls = max([int(p.s_len.max()) if len(p.s_len) else 0
                    for p in plans] + [0])
-        Lsp = next(b for b in LSP_BUCKETS if mls <= b)
+        Rd, Rs, Lsp = _f1_rows(mrd, mrs, mls, kappa > KAPPA_FLOOR)
         T = max(len(p.required) for p in plans)
-        # B buckets: every per-lane cost (phase-1 chains, phase-2
-        # gathers) scales with B INCLUDING pad lanes, while the
-        # dispatch+fetch round trip is fixed — so big batches amortize,
-        # small ones (single-query latency, minority rungs) drop to
-        # B=4. κ no longer constrains B: phase 2 is k2-wide (k2 ≪ κ),
-        # so big-κ rungs only pay a wider selection pass
-        bmax = self._f1_bmax()
-        if len(plans) <= 4:
-            B = 4
-        elif len(plans) <= 8:
-            B = 8
-        elif len(plans) <= 16:
-            B = 16
-        elif len(plans) <= 32:
-            B = 32
-        else:
-            B = 64
-        B = min(B, bmax)
+        # one B: every per-lane cost (phase-1 chains, phase-2 gathers)
+        # scales with B INCLUDING pad lanes, and a wider bucket is a
+        # program of its own to compile (``_issue_waves`` chunks at B)
+        B = F1_B
         if len(plans) > B:  # stray caller overshoot: correctness first
             B = _bucket(len(plans), 4)
+        use_table = any(p.has_table for p in plans)
+        d_filter, d_sort, uf, us = self._filter_sort_cols(plans[0])
+        bucket = (B, Rd, Rs, Lsp, kappa, k2)
+        if (T != T_FLOOR or use_table or uf or us
+                or bucket not in self._f1_keys):
+            g_stats.count("devindex.f1.key_outside_set")
 
         def pad_plan(p: ResidentPlan | None):
             if p is None:
@@ -2169,20 +2145,17 @@ class DeviceIndex:
         # host args ride the (async) dispatch; returned WITHOUT fetching
         # — the caller fetches every wave's output in ONE device_get
         # (each separate blocking fetch is a host sync of its own)
-        d_filter, d_sort, uf, us = self._filter_sort_cols(plans[0])
         modeled = self.wave_bytes_per_query(plans) * B \
             if devwatch.enabled() else None
         return self._costed(
-            "devindex._two_phase", (B, Rd, Rs, Lsp, kappa, k2),
-            modeled, _two_phase,
+            "devindex._two_phase", bucket, modeled, _two_phase,
             self.d_payload, self.d_doc, self.d_imp, self.d_rs,
             self.d_cnt, self.d_dense_imp, self.d_dense_rs,
             self.d_dense_cnt,
             self.d_siterank, self.d_doclang, self.d_dead,
             np.int32(self.n_docs), d_filter, d_sort, sel, *args,
             n_positions=self.P, lsp=Lsp, kappa=kappa, k2=k2,
-            use_table=any(p.has_table for p in plans),
-            use_filter=uf, use_sort=us)
+            use_table=use_table, use_filter=uf, use_sort=us)
 
     def _run_batch_f2(self, plans: list[ResidentPlan], k2: int,
                       n_sel: int):

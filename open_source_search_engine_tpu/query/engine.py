@@ -401,6 +401,10 @@ def get_device_index(coll: Collection):
                 fresh = DeviceIndex(coll)
                 fresh.warm_plans()  # before the swap: first query on
                 # the fresh index must not re-pay the cold-plan spike
+                if di._f1_warmed:
+                    fresh.warm_f1()  # ... nor, under a server that
+                    # warmed the old one, meet a column bucket that
+                    # moved with the rebuild
                 with lock:
                     coll._device_index = fresh
             except Exception:  # noqa: BLE001 — keep serving the old
@@ -415,14 +419,15 @@ def get_device_index(coll: Collection):
     return di
 
 
-def get_resident_loop(coll: Collection, deadline=None):
+def get_resident_loop(coll: Collection, deadline=None,
+                      warm: bool = False):
     """The collection's ResidentLoop — owned by the tenant plane's
     :class:`~..serve.tenancy.ResidencyManager` (LRU hot set, parked
     cold tenants, single-flight cold start). The lazy import mirrors
     get_mesh_resident's: the serve layer imports this module at load,
     so the reverse edge resolves at call time only."""
     from ..serve.tenancy import g_residency
-    return g_residency.loop_for(coll, deadline=deadline)
+    return g_residency.loop_for(coll, deadline=deadline, warm=warm)
 
 
 def build_device_index(coll, device=None):

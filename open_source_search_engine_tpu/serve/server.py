@@ -1638,8 +1638,14 @@ class SearchHTTPServer:
         # each resident index's own count of dispatches by (program,
         # shape bucket): kept in DeviceIndex._costed, devwatch or not
         body["dispatches"] = []
+        # ... and beside it, which kernel each query was first routed
+        # to (``DeviceIndex.route_counts``; ``devindex.route.*`` in
+        # g_stats are the same bumps, summed over collections)
+        body["routes"] = {}
         for name, coll in sorted(dict(self.colldb.colls).items()):
             di = getattr(coll, "_device_index", None)
+            if di is not None:
+                body["routes"][name] = dict(di.route_counts)
             for (prog, bucket), n in sorted(
                     dict(di.dispatches if di is not None else {}).items()):
                 body["dispatches"].append(
@@ -1682,6 +1688,11 @@ class SearchHTTPServer:
             f"<td>{d['bucket']}</td><td>{d['dispatches']}</td></tr>"
             for d in body["dispatches"]) \
             or "<tr><td colspan=4>none</td></tr>"
+        routes = "".join(
+            f"<tr><td>{c}</td><td>{r['f1']}</td><td>{r['fd']}</td>"
+            f"<td>{r['f2']}</td></tr>"
+            for c, r in body["routes"].items()) \
+            or "<tr><td colspan=4>none</td></tr>"
         pk = snap["peaks"]
         return 200, (
             "<html><head><title>gb device</title></head><body>"
@@ -1706,6 +1717,9 @@ class SearchHTTPServer:
             "<h2>dispatches per (program, shape bucket)</h2>"
             "<table border=1><tr><th>coll</th><th>program</th>"
             f"<th>bucket</th><th>dispatches</th></tr>{disp}</table>"
+            "<h2>first route per query</h2>"
+            "<table border=1><tr><th>coll</th><th>f1</th><th>fd</th>"
+            f"<th>f2</th></tr>{routes}</table>"
             "</body></html>"), "text/html"
 
     #: waterfall bar palette — one color per host, assigned by hash so
@@ -2031,6 +2045,7 @@ class SearchHTTPServer:
             def do_POST(self):
                 self._serve("POST")
 
+        self._warm_device()
         self._httpd = ThreadingHTTPServer((self.host, self.port), Handler)
         # TLS plane (reference links -lssl and serves https off gb.pem,
         # TcpServer.cpp / Makefile:113): wrap the listening socket when
@@ -2058,6 +2073,34 @@ class SearchHTTPServer:
         self._stop_sampling.clear()
         self._sampler = threads.spawn("statsdb", self._sample_loop)
         log.info("http server on %s:%d", self.host, self.port)
+
+    def _warm_device(self) -> None:
+        """Start-up's cold start, before the socket listens: every
+        collection this server already holds pages of gets its device
+        base built, its closed F1 program set dispatched once
+        (``DeviceIndex.warm_f1``: a compile or a cache load each) and
+        its resident loop — so a listening server means that no F1
+        query of the set can meet a compile behind the batcher's and
+        the loop's waits, which are shorter than one. A collection
+        filled later is promoted by its first request, as before, and
+        compiles what its queries hit. FD's programs are not part of
+        it (90 s each: ROADMAP S1)."""
+        if (not self.conf.serve_device or self.cluster is not None
+                or self.sharded is not None):
+            return
+        names = set(self.colldb.colls)
+        if "main" in self.colldb.names():
+            names.add("main")
+        hot = int(getattr(self.conf, "tenant_hot", 0))
+        for name in sorted(names)[:hot or None]:
+            coll = self.colldb.get(name, create=False)
+            if not coll.num_docs:
+                continue
+            try:
+                engine.get_resident_loop(coll, warm=True)
+            except Exception as e:  # noqa: BLE001 -- serve cold instead
+                log.warning("device warm-up of %r failed (%r); its first "
+                            "request cold-starts it", name, e)
 
     def stop(self) -> None:
         self._stop_sampling.set()

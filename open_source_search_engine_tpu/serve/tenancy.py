@@ -130,10 +130,13 @@ class ResidencyManager:
 
     # --- the hot path -----------------------------------------------------
 
-    def loop_for(self, coll, deadline=None):
+    def loop_for(self, coll, deadline=None, warm: bool = False):
         """The collection's ResidentLoop, promoting a cold tenant
         first (single-flight). This IS ``engine.get_resident_loop``
-        now — the lifecycle the engine used to open-code lives here."""
+        now — the lifecycle the engine used to open-code lives here.
+        ``warm`` is start-up's (``SearchHTTPServer._warm_device``): the
+        index is handed to the loop with its closed F1 program set
+        dispatched once. A request's thread never asks for it."""
         name = getattr(coll, "name", "coll")
         while True:
             stale = False
@@ -165,7 +168,7 @@ class ResidencyManager:
                 self.release(name)  # outside the lock: park joins
                 continue
             if leader:
-                return self._promote(name, coll, fl)
+                return self._promote(name, coll, fl, warm)
             loop = self._ride(name, fl, deadline)
             if loop is not None:
                 return loop
@@ -195,7 +198,8 @@ class ResidencyManager:
             raise fl.err
         return fl.loop
 
-    def _promote(self, name: str, coll, fl: _Flight):
+    def _promote(self, name: str, coll, fl: _Flight,
+                 warm: bool = False):
         """The leader's cold start: build (or delta-refresh) the
         device base, spawn the loop, account the bytes, evict LRU
         tenants past the hot-set bounds."""
@@ -204,6 +208,15 @@ class ResidencyManager:
         t0 = time.perf_counter()
         try:
             di = engine.get_device_index(coll)
+            if warm:
+                # start-up hands the loop an index whose closed F1
+                # program set has been dispatched once. (A base rebuilt
+                # later in the background is warmed before its swap,
+                # engine.get_device_index; one rebuilt in place, where
+                # two sets do not fit, compiles what its queries hit:
+                # warming it on the loop's thread would hold every
+                # request behind the whole set.)
+                di.warm_f1()
             loop = ResidentLoop(
                 lambda: engine.get_device_index(coll),
                 gen_fn=lambda: coll.posdb.version,

@@ -70,19 +70,15 @@ def main():
     # shape-bucket distribution
     from collections import Counter
 
-    from open_source_search_engine_tpu.query.devindex import (
-        LSP_FLOOR, RD_FLOOR, RS_FLOOR)
-    from open_source_search_engine_tpu.query.packer import _bucket
+    from open_source_search_engine_tpu.query.devindex import _f1_rows
     c = Counter()
     for qp in plans:
         p = di.plan(qp)
         if not p.matchable:
             c["unmatchable"] += 1
             continue
-        c[(_bucket(max(len(p.d_slot), 1), RD_FLOOR),
-           _bucket(max(len(p.s_start), 1), RS_FLOOR),
-           _bucket(int(p.s_len.max()) if len(p.s_len) else 1,
-                   LSP_FLOOR))] += 1
+        c[_f1_rows(max(len(p.d_slot), 1), max(len(p.s_start), 1),
+                   int(p.s_len.max()) if len(p.s_len) else 0)] += 1
     print("shape buckets (Rd,Rs,Lsp):", dict(c), file=sys.stderr)
     print(f"escalations: {di.escalations}", file=sys.stderr)
 
