@@ -476,9 +476,12 @@ def search_device_batch(coll: Collection, queries, *, topk: int = 10,
     ResidentLoop: the dispatch is an enqueue onto a loop that is
     already double-buffering waves, not a fresh issue→block round trip.
     ``results_lock``, when given, is held ONLY around the host
-    post-processing (titledb reads mutate rdblite state) — never
-    around the device wait, so a server can overlap batch N's wave
-    with batch N-1's snippets."""
+    post-processing (titledb reads mutate rdblite state), once a batch
+    and whole: never around the submit or the device wait. That alone
+    overlaps nothing: the tails of one lock run one after another, so
+    batch N's wave runs under batch N-1's tail only where the caller
+    keeps more batches out than are in their tails at once (the
+    server's ``QueryBatcher``: ``2 * resident.DEPTH``)."""
     import contextlib
     plans = [q if isinstance(q, QueryPlan) else _compile_cached(q, lang)
              for q in queries]

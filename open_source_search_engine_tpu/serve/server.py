@@ -31,7 +31,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from pathlib import Path
 
 from ..index.collection import CollectionDb
-from ..query import devcheck, engine
+from ..query import devcheck, engine, resident
 from ..query.summary import highlight
 from ..utils import chaos as chaos_mod
 from ..utils import deadline as deadline_mod
@@ -77,13 +77,19 @@ class QueryBatcher:
         #: (key, query, holder, parent span | None, deadline, tier,
         #: tenant, the rider's stage ledgers, when it was enqueued)
         self._queue: list[tuple] = []
-        self._inflight = 0  # device waves currently dispatched
+        #: batches handed to the pool and not finished yet (waiting in
+        #: its queue or on a worker); it only gates the collect window
+        self._inflight = 0
         self._alive = True
-        # two executors so batch N's host post-processing (titledb
-        # reads, clustering) overlaps batch N+1's device waves
-        # (device_get releases the GIL)
+        # a worker lives a batch's whole life: submit to the resident
+        # loop, wait for the wave, build the results under the server's
+        # core lock. The tails run one after another under that lock,
+        # so with DEPTH waves in flight there have to be a wave's
+        # worth of batches at the loop BEYOND those whose results are
+        # being built: 2 * DEPTH out, or the loop's queue is empty at
+        # every collect and host and device take turns (PERF.md, PR 31)
         from concurrent.futures import ThreadPoolExecutor
-        self._pool = ThreadPoolExecutor(2)
+        self._pool = ThreadPoolExecutor(2 * resident.DEPTH)
         self._thread = threads.spawn("query-batcher", self._loop)
 
     @property
@@ -462,17 +468,14 @@ class SearchHTTPServer:
 
     def _run_device_batch(self, key: tuple, queries: list[str]):
         cname, topk, offset = key
-        # resident-loop dispatch: the device wave runs OUTSIDE the core
-        # lock (the ResidentLoop serializes issue/collect itself), so a
-        # wave in flight no longer blocks injects or the next batch.
-        # The lock still covers the collection lookup and — via
-        # results_lock — the host post-processing, which reads the
-        # single-writer Rdb/titledb structures.
-        t_lock = time.perf_counter()
-        with self._lock:
-            t_held = time.perf_counter()
-            coll = self.colldb.get(cname)
-        trace_mod.record("query.lock_wait", t_lock, t_held)
+        # nothing on the way to the resident loop's queue may take the
+        # core lock (the registry has a lock of its own, as for the
+        # front door's lookup): a submit that queues behind another
+        # batch's results tail leaves the loop with nothing to issue
+        # while it collects. The core lock covers, via results_lock,
+        # the host post-processing alone, which reads the single-writer
+        # Rdb/titledb structures.
+        coll = self.colldb.get(cname)
         return engine.search_device_batch(
             coll, queries, topk=topk, offset=offset,
             resident=True, results_lock=self._lock)

@@ -91,11 +91,12 @@ REQUEST_STAGES = (
     "admission.queue_delay",    # admit entered -> admitted
     "batcher.queue_wait",       # enqueued -> its batch formed
     "batcher.pool_wait",        # batch formed -> a pool thread runs it
-    "query.lock_wait",          # waiting for the server's core lock
     "resident.queue_wait",      # submit -> taken by the loop
     "resident.issue_wave",      # plan, route, async dispatch
     "resident.inflight_wait",   # issue done -> its collect begins
     "resident.collect_wave",    # blocking fetch, escalation rounds
+    "query.lock_wait",          # the results tail waits for the core
+                                # lock, its one acquisition a batch
     "query.results_work",       # results built under the core lock
     "batcher.wake",             # result set -> the rider's thread runs
     "serve.render",             # render_results

@@ -8,7 +8,9 @@ refreshed index (Ticket.generation proves which base scored it).
 """
 
 import threading
+import time
 
+import numpy as np
 import pytest
 
 from open_source_search_engine_tpu.build import docproc
@@ -18,6 +20,9 @@ from open_source_search_engine_tpu.query.engine import (
     _compile_cached, get_device_index, get_resident_loop,
     search_device_batch)
 from open_source_search_engine_tpu.query.resident import ResidentLoop
+from open_source_search_engine_tpu.utils.stats import g_stats
+
+from .polling import wait_until
 
 DOCS = {
     "http://a.example.com/fruit": """
@@ -232,6 +237,13 @@ class _FakeIndex:
         return [(p, None, 0) for p in pending]
 
 
+def _resident_counters():
+    """(waves issued, of them with another wave in flight), so far."""
+    c = g_stats.snapshot()["counters"]
+    return (c.get("resident.issue", 0),
+            c.get("resident.issue_overlapped", 0))
+
+
 class TestTimeline:
     def test_issue_overlapped_counts_issues_with_a_wave_in_flight(self):
         """One ticket a wave (``max_batch`` 1). Wave 1 is issued alone
@@ -239,15 +251,8 @@ class TestTimeline:
         released the loop issues wave 2 with nothing in flight, then
         waves 3 and 4 each beside the wave before: 4 issues, 2 of them
         overlapped — exactly."""
-        from open_source_search_engine_tpu.utils.stats import g_stats
-
-        def counters():
-            c = g_stats.snapshot()["counters"]
-            return (c.get("resident.issue", 0),
-                    c.get("resident.issue_overlapped", 0))
-
         di = _FakeIndex()
-        i0, o0 = counters()
+        i0, o0 = _resident_counters()
         loop = ResidentLoop(lambda: di, lambda: 0, max_batch=1,
                             name="overlap")
         try:
@@ -260,7 +265,7 @@ class TestTimeline:
         finally:
             di.hold.set()
             loop.stop()
-        i1, o1 = counters()
+        i1, o1 = _resident_counters()
         assert (i1 - i0, o1 - o0) == (4, 2)
 
     def test_a_ticket_carries_its_submitters_ledgers(self):
@@ -282,3 +287,221 @@ class TestTimeline:
             assert all(ms >= 0.0 for _, ms in led.rows)
         finally:
             loop.stop()
+
+
+# ---------------------------------------------------------------------------
+# host and device do not take turns (PR 31): the server's batcher keeps
+# 2 * DEPTH batches out and nothing before the loop's queue takes the
+# server's core lock, so the loop has a ticket queued when it collects
+# ---------------------------------------------------------------------------
+
+class _SleepyIndex:
+    """The loop's duck type with a device that takes its time: an
+    issue costs the host ``ISSUE_S``, waves run on the device one after
+    another for ``DEVICE_S`` each, a collect blocks until its wave is
+    done. No jax anywhere."""
+
+    _built_version = 0
+    ISSUE_S, DEVICE_S = 0.002, 0.012
+
+    def __init__(self):
+        self.ready = 0.0
+        self.collected = 0
+
+    def issue_batch(self, plans, topk=64, lang=0):
+        time.sleep(self.ISSUE_S)
+        self.ready = max(self.ready, time.perf_counter()) + self.DEVICE_S
+        return self.ready, list(plans)
+
+    def collect_batch(self, pending):
+        ready, plans = pending
+        time.sleep(max(0.0, ready - time.perf_counter()))
+        self.collected += 1
+        none = np.zeros(0, np.int64)
+        return [(none, np.zeros(0, np.float32), 1) for _ in plans]
+
+    def sitehash_of(self, docids):
+        return np.zeros(len(docids), np.uint32)
+
+    langid_of = sitehash_of
+
+
+@pytest.fixture()
+def sleepy_server(tmp_path, monkeypatch):
+    """A ``SearchHTTPServer`` (not listening) whose batches ride the
+    real path (``QueryBatcher`` -> ``_run_device_batch`` ->
+    ``search_device_batch(resident=True, results_lock=server.core)``
+    -> a real ``ResidentLoop``) over a ``_SleepyIndex``, one query a
+    batch, with a results tail that holds the core lock for
+    ``tail_s``: shorter than a wave, as where the device sets the
+    pace."""
+    from open_source_search_engine_tpu.serve.server import (
+        SearchHTTPServer)
+    tail_s = 0.005
+    srv = SearchHTTPServer(tmp_path, port=0)
+    srv._batcher.MAX_B = 1
+    di = _SleepyIndex()
+    # one plan a wave, as a toy-cell FD query rides a program alone
+    loop = ResidentLoop(lambda: di, lambda: 0, max_batch=1,
+                        name="sleepy")
+    monkeypatch.setattr(engine, "get_resident_loop",
+                        lambda coll, deadline=None, warm=False: loop)
+
+    def slow_tail(get_doc, docids, scores, plan, **kw):
+        time.sleep(tail_s)
+        return [], 0
+
+    monkeypatch.setattr(engine, "build_results", slow_tail)
+    try:
+        yield srv, loop, di
+    finally:
+        loop.stop()
+        srv._batcher.stop()
+
+
+class TestHostAndDeviceOverlap:
+    def test_wave_n_plus_1_is_issued_under_wave_ns_results_tails(
+            self, sleepy_server):
+        """Twelve closed-loop clients, ten queries each: more than half
+        of the waves are issued while another is in flight. With two
+        batches out and the lookup under the core lock (the parent) it
+        was one wave in a hundred here. Bounded by its joins."""
+        srv, loop, di = sleepy_server
+        errors = []
+
+        def client(i):
+            try:
+                for k in range(10):
+                    res = srv._batcher.search(("main", 10, 0),
+                                              f"client{i} query{k}",
+                                              timeout=60)
+                    assert res.total_matches == 1
+            except BaseException as exc:  # noqa: BLE001
+                errors.append((i, exc))
+
+        i0, o0 = _resident_counters()
+        ts = [threading.Thread(target=client, args=(i,), daemon=True)
+              for i in range(12)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+        assert not errors, errors
+        assert not any(t.is_alive() for t in ts)
+        i1, o1 = _resident_counters()
+        assert i1 - i0 == 120       # one query a batch, one a wave
+        assert (o1 - o0) / (i1 - i0) > 0.5, (i1 - i0, o1 - o0)
+
+    def test_a_batch_is_submitted_while_the_core_lock_is_held(
+            self, sleepy_server):
+        """Another thread holds ``server.core`` (an inject, a results
+        tail): a batch still reaches the loop and its wave is issued
+        and collected; only its results tail waits for the lock."""
+        srv, loop, di = sleepy_server
+        held, release = threading.Event(), threading.Event()
+
+        def holder():
+            with srv._lock:
+                held.set()
+                release.wait(60)
+
+        h = threading.Thread(target=holder, daemon=True)
+        h.start()
+        got = []
+        rider = threading.Thread(
+            target=lambda: got.append(srv._batcher.search(
+                ("main", 10, 0), "under the lock", timeout=60)),
+            daemon=True)
+        try:
+            assert held.wait(60)
+            rider.start()
+            wait_until(lambda: di.collected == 1, timeout=60,
+                       desc="the wave collected under a held core lock")
+            assert loop.waves_issued == 1 and not got
+        finally:
+            release.set()
+        rider.join(60)
+        h.join(60)
+        assert [r.total_matches for r in got] == [1]
+
+
+class TestBatchesOut:
+    def test_no_more_than_twice_depth_out_errors_reach_riders_and_stop_fails_the_queue(  # noqa: E501
+            self):
+        """The batcher's bound on batches out is ``2 * DEPTH`` (the
+        pool's workers, each living one batch); a failing batch fails
+        each of its riders and no other; ``stop()`` fails what is still
+        queued. Bounded by its waits."""
+        from open_source_search_engine_tpu.query.resident import DEPTH
+        from open_source_search_engine_tpu.serve.server import (
+            QueryBatcher)
+        release = threading.Event()
+        gauge = threading.Lock()
+        out = {"now": 0, "most": 0, "ran": []}
+
+        def run_batch(key, queries):
+            with gauge:
+                out["now"] += 1
+                out["most"] = max(out["most"], out["now"])
+                out["ran"].append(key)
+            try:
+                release.wait(60)
+                if key == "boom":
+                    raise RuntimeError("kernel on fire")
+                return [q.upper() for q in queries]
+            finally:
+                with gauge:
+                    out["now"] -= 1
+
+        def rider(key, q, into):
+            try:
+                into.append(b.search(key, q, timeout=60))
+            except BaseException as exc:  # noqa: BLE001
+                into.append(exc)
+
+        def ride(key, q):
+            into = []
+            t = threading.Thread(target=rider, args=(key, q, into),
+                                 daemon=True)
+            t.start()
+            return t, into
+
+        b = QueryBatcher(run_batch)
+        b.MAX_B, b.WINDOW_S = 3, 60.0
+        try:
+            # an idle batcher launches the first at once; with a batch
+            # out it collects MAX_B same-key riders: three ride "boom"
+            first = ride("k0", "a")
+            wait_until(lambda: out["ran"] == ["k0"], desc="first batch")
+            boom = [ride("boom", q) for q in "xyz"]
+            wait_until(lambda: "boom" in out["ran"], desc="boom batch")
+            # ... and the window never closes for lone riders: cut it
+            b.WINDOW_S = 0.0
+            rest = [ride(f"k{i}", "b") for i in range(1, 3 * DEPTH + 1)]
+            wait_until(lambda: out["now"] == 2 * DEPTH,
+                       desc="every worker holds a batch")
+            wait_until(lambda: b._inflight == 2 + 3 * DEPTH,
+                       desc="every batch handed to the pool")
+            assert out["most"] == 2 * DEPTH
+            # a long window again: the next riders stay in the queue
+            b.WINDOW_S = 60.0
+            queued = [ride("late", q) for q in "pq"]
+            wait_until(lambda: len(b._queue) == 2, desc="two queued")
+            b.stop()
+            for t, into in queued:
+                t.join(60)
+                assert isinstance(into[0], RuntimeError) \
+                    and "stopped" in str(into[0])
+            release.set()
+            for t, into in [first] + boom + rest:
+                t.join(60)
+                assert not t.is_alive()
+        finally:
+            release.set()
+            b.stop()
+        assert out["most"] == 2 * DEPTH
+        assert first[1] == ["A"]
+        for t, into in boom:
+            assert isinstance(into[0], RuntimeError) \
+                and "kernel on fire" in str(into[0])
+        assert [into for t, into in rest] == [["B"]] * (3 * DEPTH)

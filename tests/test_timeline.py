@@ -22,6 +22,7 @@ import urllib.request
 
 import pytest
 
+from open_source_search_engine_tpu.query.resident import DEPTH
 from open_source_search_engine_tpu.serve import serve
 from open_source_search_engine_tpu.serve.server import QueryBatcher
 from open_source_search_engine_tpu.utils import trace as tm
@@ -36,7 +37,8 @@ DOC = ("<html><head><title>Solar panels guide</title></head><body>"
        "</body></html>")
 
 #: what one request through the device path leaves, in order; the core
-#: lock is taken twice on the batch path (collection lookup, results)
+#: lock is taken once on the batch path (the results tail: the
+#: collection lookup has the registry's own lock since PR 31)
 ONE_REQUEST = list(tm.REQUEST_STAGES)
 
 
@@ -81,18 +83,17 @@ def test_one_request_leaves_each_stage_once_in_order(tmp_path,
         srv.stop()
     ledger, request_ms = closed[1]
     names = [n for n, _ in ledger.rows]
-    # each stage once, but for the core lock's two acquisitions
-    assert sorted(names) == sorted(ONE_REQUEST + ["query.lock_wait"])
-    assert list(ledger.stages()) == ONE_REQUEST     # in order
+    # each stage once: the core lock's one acquisition is the tail's
+    assert names == ONE_REQUEST                     # in order
+    assert list(ledger.stages()) == ONE_REQUEST
     assert all(ms >= 0.0 for _, ms in ledger.rows)
     staged = sum(ms for n, ms in ledger.rows if n != "serve.unaccounted")
     assert staged <= request_ms
     assert ledger.stages()["serve.unaccounted"] == \
         pytest.approx(request_ms - staged, abs=1e-6)
-    # g_stats saw the same: one more of each, two of the lock's
+    # g_stats saw the same: one more of each, the lock's too
     for s in tm.REQUEST_STAGES:
-        assert _count(s) - before[s] == (2 if s == "query.lock_wait"
-                                         else 1), s
+        assert _count(s) - before[s] == 1, s
 
 
 # ---------------------------------------------------------------------------
@@ -158,36 +159,37 @@ def test_three_riders_of_one_batch_each_hold_its_stages():
 def test_a_held_pool_shows_as_pool_wait():
     release = threading.Event()
     running = []
+    workers = 2 * DEPTH     # every pool thread lives one batch
 
     def run_batch(key, queries):
         running.append(key)
-        if key != "third":
+        if key != "last":
             release.wait(60)
         return list(queries)
 
     b = QueryBatcher(run_batch)
     try:
-        held = [_Rider(b, "one", "a")]
-        wait_until(lambda: running == ["one"], desc="pool thread 1 held")
-        held.append(_Rider(b, "two", "b"))
-        wait_until(lambda: running == ["one", "two"],
-                   desc="pool thread 2 held")
+        held = []
+        for i in range(workers):
+            held.append(_Rider(b, f"held{i}", "a"))
+            wait_until(lambda: len(running) == i + 1,
+                       desc=f"pool thread {i + 1} held")
         formed = _count("batcher.queue_wait")
-        third = _Rider(b, "third", "c")
+        last = _Rider(b, "last", "c")
         wait_until(lambda: _count("batcher.queue_wait") == formed + 1,
-                   desc="third batch formed")
+                   desc="last batch formed")
         t_hold = time.perf_counter()    # formed, and no thread to run it
         time.sleep(0.05)
-        assert running == ["one", "two"]
+        assert "last" not in running and len(running) == workers
         hold_ms = (time.perf_counter() - t_hold) * 1000.0
         release.set()
-        for r in held + [third]:
+        for r in held + [last]:
             r.join(60)
     finally:
         release.set()
         b.stop()
-    assert third.res == "c"
-    assert third.ledger.stages()["batcher.pool_wait"] >= hold_ms
+    assert last.res == "c"
+    assert last.ledger.stages()["batcher.pool_wait"] >= hold_ms
 
 
 # ---------------------------------------------------------------------------
