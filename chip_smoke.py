@@ -1,7 +1,7 @@
 """chip_smoke.py — the served query path, once, on the chip.
 
 The quickest proof that this program still starts on a TPU: one
-process builds the bench corpus through the real indexing pipeline,
+process builds the toy corpus through the real indexing pipeline,
 serves it from ``SearchHTTPServer`` (HTTP handler → admission →
 QueryBatcher → resident loop → DeviceIndex), drives every kernel route
 with a few dozen requests, and compares every answer with the host flat
@@ -51,7 +51,8 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import jax
 
-import bench
+from benchmarks.corpora import zipf_html
+from benchmarks.lib import spec
 from open_source_search_engine_tpu import native
 from open_source_search_engine_tpu.build import docproc
 from open_source_search_engine_tpu.index.collection import Collection
@@ -65,6 +66,18 @@ from open_source_search_engine_tpu.utils import (compilecache, devwatch,
                                                  jitwatch)
 from open_source_search_engine_tpu.utils.parms import Conf
 from open_source_search_engine_tpu.utils.stats import g_stats
+
+#: the toy corpus is the benchmark's: one rule (``zipf_html``), the
+#: parameters of its ``gbshard-80k`` configuration, a seed of the smoke's
+CORPUS_SEED = 42
+CORPUS_PARAMS = spec.load_json(
+    spec.BENCH / "configs" / "gbshard-80k.json")["corpus"]["params"]
+
+
+def corpus(n_docs: int):
+    """(url, html) of the toy corpus's first ``n_docs`` pages."""
+    return zipf_html.pages(CORPUS_SEED, 0, n_docs, CORPUS_PARAMS)
+
 
 #: documents of the bulk inject that pushes a 4-word query's un-dumped
 #: postings past FD_SCATTER_MAX_LANES (4 words × 16 stored positions ×
@@ -110,10 +123,9 @@ def search_url(q: str) -> str:
 
 
 def index_flat(coll, n_docs: int) -> None:
-    """The bench generator through the real pipeline, as bench.py
-    main() runs it."""
+    """The toy corpus through the real pipeline, 512 pages a batch."""
     chunk: list = []
-    for doc in bench._gen_docs(n_docs):
+    for doc in corpus(n_docs):
         chunk.append(doc)
         if len(chunk) >= 512:
             docproc.index_batch(coll, chunk)
@@ -126,7 +138,7 @@ def dump(coll) -> None:
     """Memtable → runs: the served queries read the on-disk base."""
     # PQR's per-domain demotion is rank-dependent, so it stamps
     # different scores onto docs that tie in base score — compare the
-    # undemoted ranking (bench.py does the same for its recall)
+    # undemoted ranking (the benchmark's configurations do the same)
     coll.conf.pqr_enabled = False
     coll.posdb.dump()
     coll.titledb.dump()
@@ -188,8 +200,8 @@ def _tie(a: float, b: float) -> bool:
 
 def _compare(q: str, ans: dict, search) -> float:
     """One served answer against a reference path (``search(**kw)``):
-    recall@10 as bench.py defines it — the relevant set is every
-    reference docid scoring ≥ the reference's 10th-best score — with
+    recall@10 — the relevant set is every reference docid scoring ≥
+    the reference's 10th-best score — with
     the tie-run semantics of ``routecheck.assert_tie_run_parity``: this
     corpus saturates, so a heavy query's top scores tie across
     thousands of documents and the two paths pick different members of
@@ -435,7 +447,7 @@ def run_mesh(n_docs: int) -> None:
         t0 = time.perf_counter()
         sc = ShardedCollection("main", os.path.join(base, "mesh"),
                                n_shards=4)
-        for doc in bench._gen_docs(n_docs):
+        for doc in corpus(n_docs):
             sc.index_document(*doc)
         flat = Collection("main", os.path.join(base, "flat"))
         index_flat(flat, n_docs)

@@ -10,7 +10,6 @@ one-shot operation (``main.cpp:1084-3887``: ``gb inject``, ``gb dump``,
     python -m open_source_search_engine_tpu search --dir ./data "query"
     python -m open_source_search_engine_tpu crawl  --dir ./data --seeds U
     python -m open_source_search_engine_tpu save   --dir ./data
-    python -m open_source_search_engine_tpu bench
 
 ``serve`` is the long-running node: collections + HTTP API + autosave +
 orderly signal shutdown (``Process.cpp:1299`` autosave clock,
@@ -253,17 +252,6 @@ def cmd_repair(args) -> int:
     return 0
 
 
-def cmd_bench(args) -> int:
-    import runpy
-
-    bench_py = Path(__file__).resolve().parent.parent / "bench.py"
-    if not bench_py.exists():
-        print("bench.py not found next to the package", file=sys.stderr)
-        return 1
-    runpy.run_path(str(bench_py), run_name="__main__")
-    return 0
-
-
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m open_source_search_engine_tpu",
@@ -346,9 +334,6 @@ def main(argv: list[str] | None = None) -> int:
                        help="rebuild index Rdbs from titledb")
     _add_dir(p)
     p.set_defaults(fn=cmd_repair)
-
-    p = sub.add_parser("bench", help="run the repo benchmark")
-    p.set_defaults(fn=cmd_bench)
 
     args = ap.parse_args(argv)
     return args.fn(args)

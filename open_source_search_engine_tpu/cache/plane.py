@@ -37,7 +37,7 @@ test's ClusterClient) and drops off the admin page and the membudget
 gauges without ceremony.
 
 ``OSSE_CACHE=0`` disables the whole plane (every lookup misses, every
-put is dropped) — the A/B switch the cache bench and cluster client use.
+put is dropped).
 """
 
 from __future__ import annotations
@@ -130,8 +130,8 @@ class GenCache:
         self.gen_fn = gen_fn
         self.cost_fn = cost_fn or _estimate_cost
         self.desc = desc
-        #: per-cache kill switch (the bench's A/B lever): False makes
-        #: every lookup miss and every put a no-op
+        #: per-cache kill switch: False makes every lookup miss and
+        #: every put a no-op
         self.enabled = True
         self._d: dict[Hashable, tuple[float, Any, int, Any]] = {}
         self._bytes = 0

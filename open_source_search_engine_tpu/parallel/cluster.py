@@ -135,8 +135,7 @@ class ShardNodeServer:
 
     def __init__(self, data_dir: str | Path, host: str = "127.0.0.1",
                  port: int = 0, use_device: bool = False,
-                 use_cache: bool = True, shard: int = 0,
-                 replica: int = 0,
+                 shard: int = 0, replica: int = 0,
                  cluster_map: "HostsConf | None" = None):
         self.coll = Collection("shard", data_dir)
         #: this node's seat in the fleet and the Hostdb-style map it was
@@ -195,8 +194,6 @@ class ShardNodeServer:
             "node.search", ttl_s=30.0, max_entries=4096,
             gen_fn=lambda: _coll.posdb.version,
             desc="per-shard /rpc/search replies (Msg39 result cache)")
-        if not use_cache:
-            self._search_cache.enabled = False
         #: metrics registry served by /rpc/stats — the process-wide
         #: g_stats by default; in-process multi-node tests inject a
         #: private Stats per node so a scrape-merge is a real merge
@@ -965,8 +962,7 @@ class ClusterClient:
     """Routes adds/reads/queries across the node processes."""
 
     def __init__(self, conf: HostsConf, use_heartbeat: bool = True,
-                 parms=None, transport: Transport | None = None,
-                 use_cache: bool = True):
+                 parms=None, transport: Transport | None = None):
         self.conf = conf
         #: optional global Conf (utils.parms) — supplies alert_cmd etc.
         self.parms = parms
@@ -1003,9 +999,6 @@ class ClusterClient:
             "cluster.results", ttl_s=30.0, max_entries=1024,
             gen_fn=self.gen_vector,
             desc="merged cluster SERPs (Msg17/Msg40Cache role)")
-        if not use_cache:
-            self._leg_cache.enabled = False
-            self._result_cache.enabled = False
         self._queues = {(s, r): _HostQueue()
                         for s in range(conf.n_shards)
                         for r in range(conf.n_replicas)}

@@ -32,8 +32,8 @@ process boundary.
   no restarts (the replies carry pids to prove it).
 
 Data dirs use ShardedCollection's naming (``shard_SSS[_rR]``), so a
-fleet base dir doubles as a grid for the offline ``rebalance`` path —
-the cross-process shard-split gate in bench.py rides that.
+fleet base dir doubles as a grid for the offline ``rebalance`` path
+(``tests/test_fleet.py`` re-shards a shut-down fleet's grid that way).
 """
 
 from __future__ import annotations

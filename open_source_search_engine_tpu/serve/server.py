@@ -56,9 +56,9 @@ class QueryBatcher:
     throughput mode, which a one-lock-per-request server can never
     reach: its ceiling is 1/latency qps regardless of device speed).
 
-    Requests enqueue and wait; a single worker drains the queue in
-    same-parameter batches of ≤ MAX_B. Errors propagate to every waiter
-    of the failing batch."""
+    Requests enqueue and wait; one thread cuts same-parameter batches
+    of ≤ MAX_B and hands each to a pool of ``2 * resident.DEPTH`` workers,
+    each living a batch's whole life. Errors reach the batch's waiters."""
 
     MAX_B = 64
     WINDOW_S = 0.002  # brief collect window once a first query arrives

@@ -38,8 +38,8 @@ scale the ROADMAP asks for.
   refused (the shed-before-refuse ladder, one rung lower).
 
 ``/admin/tenants`` (serve/server.py) renders :meth:`snapshot`;
-``BENCH_TENANTS=1`` (bench.py) drives a Zipf distribution over ~1k
-collections against the gates in the ROADMAP item.
+``tests/test_tenancy.py`` drives a seeded Zipf stream over more
+collections than slots through the front door and holds the invariants.
 """
 
 from __future__ import annotations
@@ -108,8 +108,8 @@ class ResidencyManager:
         self._tenants: dict[str, _Tenant] = {}
         self._flights: dict[str, _Flight] = {}
         self._seq = 0
-        #: recent cold-start walls (ms) — /admin/tenants p99 and the
-        #: BENCH_TENANTS bound read this, bounded so it never grows
+        #: recent cold-start walls (ms): /admin/tenants reads its p50
+        #: and p99 from this; bounded so it never grows
         self.coldstart_ms: deque[float] = deque(maxlen=4096)
 
     # --- wiring -----------------------------------------------------------

@@ -1,8 +1,7 @@
 """The one compile-cache rule.
 
 Every entry point that jits (``serve``, ``search --device``,
-``bench.py``, ``chip_smoke.py``) calls :func:`configure` before its
-first jit. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+``chip_smoke.py``) calls :func:`configure` before its first jit. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
 itself and nothing is set in code; otherwise the persistent cache
 lives in ONE fixed directory inside the checkout. The path is part of
 every entry's key, so it never carries a pid, a time or a temp name —

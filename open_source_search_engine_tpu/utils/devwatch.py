@@ -353,8 +353,8 @@ class DevWatch:
         pays one ``lower().compile().cost_analysis()`` via ``thunk``
         (the compile itself is warm — the real dispatch right after
         compiles the same shapes anyway); every later dispatch is a
-        dict hit + counter bump, which is what keeps the devwatch-on
-        overhead under the BENCH_DEVOBS 2% gate."""
+        dict hit + counter bump, so a watched wave costs what an
+        unwatched one does."""
         if not self.enabled:
             return
         key = (kernel, tuple(int(x) for x in bucket))

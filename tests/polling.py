@@ -2,8 +2,8 @@
 
 A fixed ``time.sleep(X)`` encodes a guess about scheduler timing: too
 short flakes under load, too long taxes every run. Poll the actual
-condition instead — the open-loop load harness (bench.py BENCH_LOAD)
-exposed exactly these guesses by running the suite on saturated boxes.
+condition instead — running the suite on saturated boxes exposed
+exactly these guesses.
 """
 
 from __future__ import annotations

@@ -7,9 +7,8 @@ asserts the protocol's interleaving invariant. ``explore(fn)`` runs it
 under N seeded schedules; any assertion, deadlock, or leaked thread
 fails the schedule and shrinks to a minimal preemption trace.
 
-Shared between ``tests/test_schedcheck.py`` (fast suite,
-``OSSE_SCHED_BUDGET=64`` in check.sh) and ``bench.py``'s
-``BENCH_SCHED=1`` deep run (1024 schedules per scenario).
+``tests/test_schedcheck.py`` runs them: ``OSSE_SCHED_BUDGET`` (64)
+schedules a scenario in tier-1 and in check.sh, 1024 under ``-m slow``.
 
 The ``_Buggy*`` subclasses at the bottom re-introduce, TEST-LOCALLY,
 the two historical interleaving bugs (PR 4's cache generation
@@ -292,8 +291,8 @@ def scenario_rdb_dailymerge() -> None:
         shutil.rmtree(d, ignore_errors=True)
 
 
-#: the registry both the fast suite (tests) and the deep run (bench)
-#: iterate — name → zero-arg scenario
+#: the registry the fast suite and the deep run iterate: name →
+#: zero-arg scenario
 SCENARIOS = {
     "resident_refresh": scenario_resident_refresh,
     "tenancy_promotion": scenario_tenancy_promotion,

@@ -442,9 +442,18 @@ class TestChaosOverload:
                 "serve.search.interactive")
             assert lat is not None and lat["count"] > 0
             assert lat["p99_ms"] < 3000.0
+            # the gate shed BEFORE the membudget had to refuse
+            assert _count("membudget.reject.serve") == 0
             # the gate drained: no leaked slots, no metastable queue
             wait_until(srv.admission.idle, timeout=5.0,
                        desc="admission gate drained")
+            # and recovered: the next request, of the tier that was
+            # shed, is admitted and answered (the twin is still wedged)
+            code, _, _ = srv.handle(
+                "GET", "/search",
+                {"q": "cluster shared number3", "tier": "crawlbot"},
+                b"", client_ip="7.7.7.8")
+            assert code == 200
         finally:
             g_chaos.disable()
             srv.stop()

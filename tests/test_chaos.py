@@ -621,12 +621,9 @@ class TestMemBudgetChaos:
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
-def test_soak_gate(monkeypatch, tmp_path):
-    import bench
-    monkeypatch.setenv("BENCH_SOAK_QUERIES", "48")
-    monkeypatch.setenv("BENCH_SOAK_PAGES", "24")
-    monkeypatch.setenv("BENCH_DIR", str(tmp_path))
-    rep = bench.main_soak()
+def test_soak_gate(tmp_path):
+    from tests import soak_scenario
+    rep = soak_scenario.run(tmp_path, n_pages=24, n_queries=48)
     assert rep["ok"], rep
     assert rep["lost_queries"] == 0
     assert rep["counters"]["deadline.abandoned"] > 0

@@ -81,8 +81,11 @@ class TestBaseBitExact:
         # make this test compare the cache against itself)
         monkeypatch.setenv("OSSE_DEVBUILD", "1")
         before = g_stats.counters.get("build.devbuild_fallback", 0)
+        built = g_stats.counters.get("build.device_base", 0)
         dev = DeviceIndex(c)
+        # the base was built by the device plane, and by nothing else
         assert g_stats.counters.get("build.devbuild_fallback", 0) == before
+        assert g_stats.counters.get("build.device_base", 0) == built + 1
         monkeypatch.setenv("OSSE_DEVBUILD", "0")
         host = DeviceIndex(c)
         assert host._base_fp == dev._base_fp

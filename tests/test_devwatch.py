@@ -255,6 +255,52 @@ class TestRoofline:
                    if e["kernel"].startswith("devindex."))
         assert ent["flops"] > 0 and ent["bytes"] > 0
         assert ent["dispatches"] >= 2
+        # an entry, costed, for EVERY (program, bucket) the index's own
+        # count says it dispatched: no wave program rides unmodelled
+        di = get_device_index(coll)
+        costed = {(e["kernel"], tuple(e["bucket"])): e for e in roofs}
+        assert di.dispatches and set(di.dispatches) <= set(costed)
+        for key, n in di.dispatches.items():
+            e = costed[key]
+            assert e["dispatches"] == n, key
+            assert e["flops"] is not None and e["bytes"] is not None, key
+
+
+# ---------------------------------------------------------------------------
+# the backend doctor (tools/devdoctor.py)
+# ---------------------------------------------------------------------------
+
+class TestDoctor:
+    def test_record_names_the_backend_and_a_cpu_is_no_accelerator(
+            self, monkeypatch, capsys):
+        """The record every backend report is built from: platform,
+        kind, count, jax version, topology, null-safe memory_stats. On
+        this CPU with JAX_PLATFORMS=cpu the verdict is the benign
+        ``no-accelerator`` (exit 2); the same backend where the
+        environment promises a TPU is a ``fallback`` (exit 1)."""
+        import jax
+
+        from tools import devdoctor
+        rec = devdoctor.stamp()
+        assert rec == devdoctor.stamp() == devdoctor.probe()
+        d0 = jax.devices()[0]
+        assert (rec["platform"], rec["device_kind"], rec["device_count"],
+                rec["jax_version"]) == (
+            d0.platform, d0.device_kind, len(jax.devices()),
+            jax.__version__)
+        assert rec["memory_stats"] is None or all(
+            isinstance(v, int) for v in rec["memory_stats"].values())
+        assert len(rec["topology"]["devices"]) == min(
+            rec["device_count"], 16)
+        json.dumps(rec)
+
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert devdoctor.probe()["doctor"] == "no-accelerator"
+        assert devdoctor.main() == devdoctor.EXIT_NO_ACCEL
+        assert json.loads(capsys.readouterr().out)["platform"] == "cpu"
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        assert devdoctor.probe()["doctor"] == "fallback"
+        assert devdoctor.main() == devdoctor.EXIT_FALLBACK
 
 
 # ---------------------------------------------------------------------------
@@ -289,8 +335,8 @@ class TestNoop:
         assert "NOOP-OK" in p.stdout
 
     def test_disabled_calls_are_cheap(self):
-        # the strict 2% gate lives in BENCH_DEVOBS=1; this is the
-        # CI-safe sanity bound that the off path stays a few branches
+        # a sanity bound that the off path stays a few branches (what
+        # the plane costs a served wave is the chip's to say: PERF.md)
         t0 = time.perf_counter()
         for _ in range(20000):
             devwatch.note_round(coll="c", device_s=0.0)

@@ -7,6 +7,7 @@ entry points, no log handlers, no config flip, no counters), and
 enable/disable must restore every hook exactly.
 """
 
+import itertools
 import json
 import logging
 import subprocess
@@ -192,3 +193,108 @@ def test_cold_trace_is_not_a_retrace(watch):
     ev = [e for e in snap["events"] if e["kind"] == "first_trace"]
     assert ev[0]["fn"] == "_cold"
     assert "test_jitwatch.py" in ev[0]["site"]
+
+
+# --- steady-state discipline over the program's own waves ------------------
+
+def _tiny_docs(n, tag):
+    """Pages of one fixed shape (same word count, same title length)."""
+    return [(f"http://{tag}{i % 7}.jit.test/d{i}",
+             f"<html><head><title>Jit page {tag}</title></head><body>"
+             f"<p>steady words filler token{i % 11} extra{i % 5} "
+             f"rare{i % 13} bucket probe.</p></body></html>")
+            for i in range(n)]
+
+
+#: 1-3 words, with and without a match: several plan shapes a wave
+_WAVE_QUERIES = ["steady", "words token3", "filler extra2 rare5",
+                 "token7", "rare9 probe", "steady bucket filler",
+                 "extra4", "nothinghere", "token1 token2"]
+#: plans a wave, crossing the batch buckets (4 | 8) in both directions
+_WAVE_SIZES = (1, 3, 5, 2, 8, 4)
+
+
+def _resident_waves(tmp_path):
+    from open_source_search_engine_tpu.build import docproc
+    from open_source_search_engine_tpu.index.collection import Collection
+    from open_source_search_engine_tpu.query import engine
+
+    coll = Collection("jitres", tmp_path)
+    docproc.index_batch(coll, _tiny_docs(60, "r"))
+    plans = [engine._compile_cached(q, 0) for q in _WAVE_QUERIES]
+    loop = engine.get_resident_loop(coll)
+    # one ticket in flight: the loop merges whatever is queued when it
+    # takes a batch, so two in flight make a wave's size a matter of
+    # timing, and a size the warm-up never met compiles by right
+    steps = [lambda n=n: loop.submit(plans[:n], topk=10).wait(timeout=120)
+             for n in _WAVE_SIZES]
+    return steps, loop.stop
+
+
+def _mesh_waves(tmp_path):
+    from open_source_search_engine_tpu.parallel.sharded import (
+        MeshResident, ShardedCollection)
+    from open_source_search_engine_tpu.query import engine
+
+    sc = ShardedCollection("jitmesh", tmp_path, n_shards=4)
+    for url, html in _tiny_docs(48, "m"):
+        sc.index_document(url, html)
+    mr = MeshResident(sc)
+    msi = mr._serve_index()
+    plans = [engine._compile_cached(q, 0) for q in _WAVE_QUERIES]
+    steps = [lambda n=n: msi.collect_batch(
+                 msi.issue_batch(plans[:n], topk=10))
+             for n in _WAVE_SIZES]
+    return steps, mr.stop
+
+
+def _delta_folds(tmp_path):
+    from open_source_search_engine_tpu.build import docproc
+    from open_source_search_engine_tpu.index.collection import Collection
+    from open_source_search_engine_tpu.query.devindex import DeviceIndex
+
+    coll = Collection("jitfold", tmp_path)
+    docproc.index_batch(coll, _tiny_docs(40, "b"))
+    coll.posdb.dump()
+    coll.titledb.dump()
+    idx = DeviceIndex(coll)
+    wave = itertools.count(1)
+
+    def fold():
+        # the memtable is folded whole at every refresh: 16 more pages
+        # of the one shape each time, all inside one padded bucket
+        docproc.index_batch(coll, _tiny_docs(16, f"f{next(wave)}x"))
+        assert idx.refresh()
+
+    return [fold] * 3, lambda: None
+
+
+@pytest.mark.parametrize("case", ["resident", "mesh", "delta_fold"])
+def test_steady_state_waves_compile_and_sync_nothing(case, tmp_path, watch):
+    """After the warm passes, the same waves again: no compile, no
+    retrace, and no transfer outside the blessed boundary sites —
+    resident-loop waves and mesh waves of batch sizes on both sides of
+    a bucket boundary, and delta folds that stay in one bucket."""
+    steps, stop = {"resident": _resident_waves, "mesh": _mesh_waves,
+                   "delta_fold": _delta_folds}[case](tmp_path)
+    try:
+        # two warm passes: a pruning miss in the first raises the cached
+        # plan's ``kappa_min``, and the query's second meeting may then
+        # ride another program (a `_direct_cube` where F1 escalated)
+        for _ in range(2):
+            for step in steps:
+                step()
+        jitwatch.reset()
+        for _ in range(2):
+            for step in steps:
+                step()
+        snap = jitwatch.snapshot()
+    finally:
+        stop()
+    t = snap["totals"]
+    noisy = [(e["kind"], e["site"], e.get("fn")) for e in snap["events"]
+             if e["kind"] in ("retrace", "first_trace")
+             or (e["kind"] == "transfer" and not e["boundary"])]
+    assert (t["compiles"], t["retraces"], t["transfers_offboundary"]) \
+        == (0, 0, 0), noisy
+    assert t["transfers"] > 0, "the waves never crossed the boundary?"

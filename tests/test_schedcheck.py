@@ -5,7 +5,9 @@ Two halves:
 
 * **Always-run** (tier-1, armed or not): the jitwatch/lockcheck no-op
   contract — outside an active ``explore()`` every factory returns the
-  plain ``threading`` primitive and ``sched_point`` is free.
+  plain ``threading`` primitive and ``sched_point`` is free; and, from
+  an unarmed session, one armed child process that explores the five
+  scenario suites and must find no failure.
 * **Armed-only** (``OSSE_SCHED=1``, check.sh schedcheck step): the
   explorer itself — byte-identical replay, toy lost-update found and
   shrunk, ABBA deadlock detection, both seeded historical bugs
@@ -15,8 +17,12 @@ Two halves:
 """
 
 import functools
+import json
 import os
+import subprocess
+import sys
 import threading
+from pathlib import Path
 
 import pytest
 
@@ -25,6 +31,7 @@ from open_source_search_engine_tpu.utils import lockcheck, schedcheck, threads
 from tests import sched_scenarios
 
 BUDGET = int(os.environ.get("OSSE_SCHED_BUDGET", "64"))
+ROOT = Path(__file__).resolve().parent.parent
 
 armed = pytest.mark.skipif(
     not schedcheck.ENABLED,
@@ -64,6 +71,30 @@ class TestUnarmedNoOp:
     def test_monotonic_unpatched_when_idle(self):
         import time
         assert time.monotonic is schedcheck._REAL_MONOTONIC
+
+
+    def test_an_armed_process_explores_every_scenario_clean(self):
+        """Arming is read at import, so an unarmed session (the driver's
+        tier-1 run) explores in a child: every scenario suite, BUDGET
+        seeded schedules each, no failure and no idle scenario."""
+        if schedcheck.ENABLED:
+            pytest.skip("armed session: TestScenarioSuites explores here")
+        code = (
+            "import json\n"
+            "from open_source_search_engine_tpu.utils import schedcheck\n"
+            "from tests import sched_scenarios\n"
+            f"print(json.dumps({{n: schedcheck.explore(f, schedules={BUDGET})"
+            " for n, f in sorted(sched_scenarios.SCENARIOS.items())}))\n")
+        env = dict(os.environ, OSSE_SCHED="1", JAX_PLATFORMS="cpu")
+        p = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                           capture_output=True, text=True, timeout=600)
+        assert p.returncode == 0, p.stderr[-4000:]  # a ScheduleFailure's
+        # shrunk thread/lock timeline is its message
+        out = json.loads(p.stdout.splitlines()[-1])
+        assert sorted(out) == sorted(sched_scenarios.SCENARIOS)
+        for name, rep in out.items():
+            assert rep["failures"] == 0 and rep["schedules"] == BUDGET, name
+            assert rep["yield_points"] > 0, name
 
 
 # --- toy workloads for the explorer itself ---------------------------------
@@ -223,8 +254,8 @@ class TestSeededRegressions:
 @armed
 @pytest.mark.slow
 class TestDeepExploration:
-    """The BENCH_SCHED=1 deep run's pytest twin: 1024 schedules per
-    scenario, still zero findings."""
+    """The deep run: 1024 schedules per scenario, still zero
+    findings."""
 
     @pytest.mark.parametrize("name", sorted(sched_scenarios.SCENARIOS))
     def test_scenario_clean_deep(self, name):

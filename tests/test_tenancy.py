@@ -23,8 +23,10 @@ the engine/crawlbot wiring):
   HBM nor keeps billing the budget.
 """
 
+import random
 import threading
 import types
+from collections import OrderedDict
 
 import pytest
 
@@ -485,6 +487,66 @@ class TestAdminPage:
                     'outcome="served"}') in body
         finally:
             srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# the front door over more tenants than slots
+# ---------------------------------------------------------------------------
+
+class TestZipfOverTheFrontDoor:
+    def test_a_seeded_zipf_stream_is_answered_whole_and_rides_the_lru(
+            self, tmp_path):
+        """One server, 24 collections, 8 slots, 150 queries whose only
+        random draw is the collection (Zipf 1.5, seeded): every arrival
+        is answered 200, nothing is shed, the membudget refuses nothing
+        (parking is the relief valve), the resident count stays inside
+        the budget, and the hits and cold starts are exactly those of an
+        LRU of 8 over the same stream."""
+        n_colls, slots, n_q = 24, 8, 150
+        srv = SearchHTTPServer(tmp_path, port=0)
+        try:
+            names = [f"t{i:02d}" for i in range(n_colls)]
+            for name in names:
+                coll = srv.colldb.get(name)
+                # cache off: every request must reach the engine
+                coll.conf.result_cache_ttl = 0
+                coll.conf.pqr_enabled = False
+                docproc.index_document(coll, f"http://{name}.test/p",
+                                       DOC.format(t=name))
+            g_residency.configure(max_resident=slots)
+            g_stats.reset()
+            rng = random.Random(23)
+            weights = [1.0 / (r + 1) ** 1.5 for r in range(n_colls)]
+            lru: OrderedDict = OrderedDict()
+            hits = colds = 0
+            for qi in range(n_q):
+                c = rng.choices(range(n_colls), weights=weights, k=1)[0]
+                code, _, _ = srv.handle(
+                    "GET", "/search",
+                    {"q": QUERIES[qi % 4], "c": names[c]}, b"")
+                assert code == 200, (qi, names[c])
+                if c in lru:
+                    hits += 1
+                    lru.move_to_end(c)
+                else:
+                    colds += 1
+                    lru[c] = True
+                    if len(lru) > slots:
+                        lru.popitem(last=False)
+            assert colds > slots  # the tail did churn through the slots
+            assert (_count("tenancy.hit"), _count("tenancy.coldstart")) \
+                == (hits, colds)
+            snap = g_residency.snapshot()
+            assert snap["coldstarts"] == colds
+            assert snap["resident"] == len(lru) == slots
+            assert set(g_residency.resident_names()) == {
+                names[c] for c in lru}
+            counters = g_stats.snapshot()["counters"]
+            assert not [k for k, v in counters.items() if v and k.startswith(
+                ("membudget.reject.", "admission.shed."))], counters
+        finally:
+            srv.stop()
+        assert g_residency.resident_names() == []
 
 
 # ---------------------------------------------------------------------------

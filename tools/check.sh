@@ -34,7 +34,8 @@ fi
 # 3. fast tier-1 slice: the lint gate, the jit plane, the query
 #    stack (the layers a typical PR touches), and the seeded chaos
 #    smoke — deterministic fault schedules, deadline propagation, twin
-#    failover; the full soak gate stays behind `-m slow` / BENCH_SOAK=1
+#    failover; the full soak scenario stays behind `-m slow`
+#    (tests/test_chaos.py::test_soak_gate)
 JAX_PLATFORMS=cpu python -m pytest tests/test_lint.py \
     tests/test_jitwatch.py tests/test_query.py tests/test_chaos.py \
     tests/test_statsplane.py tests/test_devwatch.py \
@@ -44,82 +45,9 @@ JAX_PLATFORMS=cpu python -m pytest tests/test_lint.py \
 # 3b. schedule exploration: the five protocol scenario suites plus the
 #     seeded historical-bug regressions under the armed explorer — 64
 #     seeded interleavings per suite, deterministic and replayable
-#     (the 1024-schedule deep run lives behind BENCH_SCHED=1 / -m slow)
+#     (the 1024-schedule deep run is the same file under `-m slow`)
 OSSE_SCHED=1 OSSE_SCHED_BUDGET=64 JAX_PLATFORMS=cpu \
     python -m pytest tests/test_schedcheck.py \
     -q -m 'not slow' -p no:cacheprovider
 
-# 4. SLO gate: 2-node fleet, mergeable-histogram scrape, burn-rate
-#    math; exits nonzero unless the merged histogram is populated,
-#    the burn math is finite, and scrape overhead stays under 1% of
-#    query wall time
-BENCH_SLO=1 JAX_PLATFORMS=cpu python bench.py
-
-# 5. admission smoke: a SHORT open-loop sweep at low qps (generous
-#    latency bounds — this is a CI box, not a perf rig); exits nonzero
-#    unless overload sheds tier-correctly, every shed is counted, and
-#    the queue drains post-burst (bench.py main_load docstring)
-BENCH_LOAD=1 BENCH_LOAD_QPS=6,12 BENCH_LOAD_SECONDS=2 \
-    BENCH_LOAD_P99_MS=2000 BENCH_LOAD_OVER_P99_MS=3000 \
-    JAX_PLATFORMS=cpu python bench.py
-
-# 6. fleet smoke: 2×2 REAL node processes under open-loop load —
-#    wedge→SIGKILL a primary with the twin absorbing every query
-#    (hedge fired+won, zero lost), journal-replay rejoin, rolling
-#    restart through the admission gate, live parm broadcast, and the
-#    2→3 cross-process shard split; exits nonzero unless every gate
-#    holds and no child process survives teardown
-BENCH_FLEET=1 BENCH_FLEET_SECONDS=5 BENCH_FLEET_QPS=8 \
-    JAX_PLATFORMS=cpu python bench.py
-
-# 7. tenant smoke: a SHORT Zipf sweep over 64 collections with a
-#    32-slot residency budget — gates the hot-set residency hit rate,
-#    bounded post-compile cold starts, zero membudget refusals, and
-#    weighted-fair quotas keeping a quiet tenant shed-free under a
-#    flood (bench.py main_tenants docstring; the 1k-collection shape
-#    runs nightly via BENCH_TENANTS=1 defaults)
-BENCH_TENANTS=1 BENCH_TENANTS_COLLS=64 BENCH_TENANTS_HOT=32 \
-    BENCH_TENANTS_QUERIES=300 \
-    JAX_PLATFORMS=cpu python bench.py
-
-# 8. mesh serving smoke: a SHORT scale curve of the in-jit Msg3a merge
-#    (subprocess per point, forced host devices) — gates the 4-shard
-#    in-jit merge's speedup over the single-chip path on the same
-#    corpus, zero compiles/retraces/off-boundary transfers across
-#    varying-batch steady-state mesh waves, and twin failover with
-#    zero lost queries (bench.py main_mesh docstring; full sizes run
-#    nightly via BENCH_MESH=1 defaults)
-BENCH_MESH=1 BENCH_MESH_SHARDS=1,4 BENCH_MESH_DPS=80 \
-    BENCH_MESH_QUERIES=32 BENCH_MESH_JIT_WAVES=24 \
-    BENCH_MESH_FAILOVER_DOCS=60 \
-    JAX_PLATFORMS=cpu python bench.py
-
-# 9. ingest-plane smoke: a SMALL corpus through the device posting
-#    sort/dedup/pack pipeline — gates bitwise parity against the host
-#    oracle (columns, dir tables, f16 impacts), a cold device rebuild
-#    under a CI-box bound, and zero compiles/retraces across repeated
-#    same-bucket delta folds (bench.py main_build docstring; the
-#    100k-doc < 60 s shape runs nightly via BENCH_BUILD=1 defaults)
-BENCH_BUILD=1 BENCH_BUILD_DOCS=400 BENCH_BUILD_PARITY_DOCS=200 \
-    BENCH_BUILD_REBUILD_S=300 \
-    JAX_PLATFORMS=cpu python bench.py
-
-# 10. device-telemetry smoke: the backend doctor (rc=2 "no
-#     accelerator" is benign on CI boxes; rc=1 means a TPU host is
-#     misbehaving — init-failed or silent CPU fallback), then the
-#     devwatch gate — <2% steady-state overhead with the plane armed,
-#     HBM ledger == the index's own accounting (and memory_stats
-#     within 5% where the backend reports it), a roofline entry per
-#     dispatched shape bucket, the doctor stamp on the JSON line
-#     (bench.py main_devobs docstring)
-JAX_PLATFORMS=cpu python -m tools.devdoctor || [ $? -eq 2 ]
-BENCH_DEVOBS=1 BENCH_DEVOBS_DOCS=160 BENCH_DEVOBS_WAVES=40 \
-    JAX_PLATFORMS=cpu python bench.py
-
-# 11. concurrency smoke: the schedule-exploration gate at a SHORT
-#     budget (the nightly deep run uses the 1024-schedule default) —
-#     exits nonzero on any schedule failure, printing the failing seed
-#     and shrunk preemption trace (bench.py main_sched docstring)
-BENCH_SCHED=1 BENCH_SCHED_SCHEDULES=64 \
-    JAX_PLATFORMS=cpu python bench.py
 echo "check.sh: OK"

@@ -1,8 +1,8 @@
 """devdoctor — what backend did this process get?
 
-A bench leg or a server that silently runs on the CPU backend of a TPU
-host files host numbers next to device numbers. This probe makes the
-resolved backend loud and machine-readable:
+A measurement or a server that silently runs on the CPU backend of a
+TPU host files host numbers next to device numbers. This probe makes
+the resolved backend loud and machine-readable:
 
 * ``probe()`` initializes the backend ONCE — an init failure raises,
   nothing retries and nothing is carried past it — and records
@@ -12,12 +12,17 @@ resolved backend loud and machine-readable:
 * The verdict distinguishes ``ok`` (accelerator up), ``no-accelerator``
   (CPU box, CPU run — benign) and ``fallback`` (a TPU was expected —
   the environment says so — but jax resolved CPU: exit 1).
-* ``stamp()`` is the memoized record ``bench._backend_record()``
-  merges into EVERY BENCH_* JSON line.
+* ``stamp()`` is the memoized record of one process's backend.
 
 CLI: ``python -m tools.devdoctor`` prints the probe JSON and exits
 0 (ok), 1 (fallback — a TPU host is misbehaving; an init failure
 raises), 2 (no accelerator present — benign on CI boxes).
+
+Callers: an operator at the command line (the first thing to run on a
+chip machine that misbehaves), and ``tests/test_devwatch.py``, which
+holds the record's keys and the CPU verdict. The benchmark's runner
+and ``chip_smoke.py`` name the device on their own lines; neither
+imports this module.
 """
 
 from __future__ import annotations
@@ -76,8 +81,7 @@ def probe() -> dict:
 
 
 def stamp() -> dict:
-    """The memoized per-process backend stamp bench merges into every
-    BENCH_* JSON line."""
+    """The memoized per-process backend record (``probe()``, once)."""
     global _stamp_cache
     if _stamp_cache is None:
         _stamp_cache = probe()
