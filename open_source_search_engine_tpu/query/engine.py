@@ -87,13 +87,27 @@ class SearchResults:
     degraded: bool = False
 
 
+#: PostQueryRerank window: only the top PQR_SCAN merged results are
+#: reranked (reference m_pqr_docsToScan) — the window is FIXED by rank,
+#: not by the requested page, so pagination stays consistent: every
+#: page request reranks the same top-48 and slices its own rows out
+PQR_SCAN = 48
+
+
+def pqr_window(conf=None) -> int:
+    """Ranks the PostQueryRerank reads: PQR_SCAN, none where the
+    collection's ``pqr_enabled`` is off (no conf in reach — the
+    cluster client — reranks with the defaults)."""
+    return 0 if conf is not None and not conf.pqr_enabled else PQR_SCAN
+
+
 def build_results(get_doc, docids, scores, plan: QueryPlan, *,
                   topk: int, with_snippets: bool = True,
                   site_cluster: bool = True,
                   dedup_content: bool = True,
-                  site_of=None,
-                  page: tuple[int, int] | None = None
-                  ) -> tuple[list[Result], int]:
+                  site_of=None, site_col=None,
+                  page: tuple[int, int] | None = None,
+                  conf=None) -> tuple[list[Result], int]:
     """Msg40's post-merge stage: walk merged candidates best-first, fetch
     titlerecs from the owning store (Msg20/Msg22), apply content-hash
     dedup (Msg40's checksum dedup of identical pages) and site clustering
@@ -102,44 +116,58 @@ def build_results(get_doc, docids, scores, plan: QueryPlan, *,
     ``get_doc`` is docid → titlerec dict (routes to the owning shard in
     the mesh path). Returns (results, number hidden by cluster/dedup).
 
+    Clusterdb's sitehash column backs the clustering where the caller
+    has it: ``site_col`` (the hashes of ``docids``, row for row —
+    :func:`site_column`) or ``site_of`` (docid → hash, asked once a
+    row); without either the titlerec's site string does.
+
     ``page`` = (offset, n): the rendered page window. When given (and
-    clusterdb columns back the clustering, ``site_of``), only ranks in
-    the PQR_SCAN rerank prefix or inside the page window fetch a
-    titlerec — rows in the gap between them exist solely to hold a rank
-    for deep paging, so they carry docid+score only. Content-hash dedup
-    needs the titlerec and is therefore skipped for gap rows (site
-    clustering is not: the sitehash column works without a fetch)."""
+    the column is there), a row costs a titledb read only where the
+    answer uses the record: inside ``conf``'s rerank window
+    (:func:`pqr_window`), inside the page, or ahead of the page within
+    PQR_SCAN, where content-hash dedup decides what the page shows.
+    Every other row exists solely to hold a rank, so it carries
+    docid+score only: content-hash dedup needs the titlerec and is
+    skipped for such gap rows (site clustering is not: the sitehash
+    column works without a fetch)."""
     from . import summary as summary_mod
 
     words = plan.match_words()
+    has_col = site_of is not None or site_col is not None
+    by_col = site_cluster and has_col
+    fetch_to = None  # rows from this rank on, the page apart, are gaps
+    if page is not None and has_col:
+        page_end = page[0] + page[1]
+        fetch_to = max(pqr_window(conf), min(page_end, PQR_SCAN))
+    gaps = 0
     per_site: dict = {}
     seen_hashes: set[int] = set()
     results: list[Result] = []
     clustered = 0
-    for docid, score in zip(docids, scores):
+    for i, (docid, score) in enumerate(zip(docids, scores)):
         if len(results) >= topk:
             break
         if score <= 0.0:
             continue
-        if site_cluster and site_of is not None:
+        sh = 0
+        if by_col:
             # clusterdb-driven clustering (Msg51.h:96): the sitehash
             # column decides BEFORE any titledb fetch, so hidden
             # results never decompress a titlerec
-            sh = site_of(int(docid))
+            sh = site_col[i] if site_col is not None \
+                else site_of(int(docid))
             if sh and per_site.get(sh, 0) >= MAX_PER_SITE:
                 clustered += 1
                 continue
         rank = len(results)
-        if (page is not None and site_of is not None
-                and rank >= PQR_SCAN
-                and not (page[0] <= rank < page[0] + page[1])):
-            # gap row: never reranked (rank ≥ PQR_SCAN), never rendered
-            # (outside the page) — skip the titledb fetch entirely
-            if site_cluster and site_of is not None:
-                sh = site_of(int(docid))
-                if sh:
-                    per_site[sh] = per_site.get(sh, 0) + 1
+        if fetch_to is not None and rank >= fetch_to \
+                and not (page[0] <= rank < page_end):
+            # gap row: never reranked, never rendered, behind the dedup
+            # prefix — skip the titledb fetch entirely
+            if sh:
+                per_site[sh] = per_site.get(sh, 0) + 1
             results.append(Result(docid=int(docid), score=float(score)))
+            gaps += 1
             continue
         rec = get_doc(int(docid))
         r = Result(docid=int(docid), score=float(score))
@@ -154,8 +182,7 @@ def build_results(get_doc, docids, scores, plan: QueryPlan, *,
                     clustered += 1
                     continue
                 seen_hashes.add(ch)
-            if site_cluster and site_of is not None:
-                sh = site_of(int(docid))
+            if by_col:
                 if sh:
                     per_site[sh] = per_site.get(sh, 0) + 1
             elif site_cluster and r.site:
@@ -169,14 +196,22 @@ def build_results(get_doc, docids, scores, plan: QueryPlan, *,
                 rec.get("text", ""), words,
                 description=rec.get("meta_description", ""))
         results.append(r)
+    if gaps:
+        g_stats.count("query.gap_row", gaps)
     return results, clustered
 
 
-#: PostQueryRerank window: only the top PQR_SCAN merged results are
-#: reranked (reference m_pqr_docsToScan) — the window is FIXED by rank,
-#: not by the requested page, so pagination stays consistent: every
-#: page request reranks the same top-48 and slices its own rows out
-PQR_SCAN = 48
+def site_column(di, docids) -> list[int]:
+    """Clusterdb's sitehash of every docid at once (0 where the index
+    holds none): one search of the docid column a query where
+    ``DeviceIndex.sitehash_of`` makes one a row. It stands here and not
+    beside ``sitehash_of`` because a line moved above ``_costed`` in
+    devindex.py moves the wave programs' compile-cache keys."""
+    sh, _ = di._cluster_cols()
+    rows, ok = di._docid_pos(np.asarray(docids, np.uint64))
+    out = np.zeros(len(rows), np.int64)
+    out[ok] = sh[rows[ok]]
+    return out.tolist()
 
 
 def apply_pqr(results, conf=None, qlang: int = 0, langid_of=None) -> None:
@@ -184,7 +219,7 @@ def apply_pqr(results, conf=None, qlang: int = 0, langid_of=None) -> None:
     role; factors from the collection conf, defaults when no conf is
     in reach — the cluster client)."""
     from .rerank import post_query_rerank
-    if conf is not None and not conf.pqr_enabled:
+    if not pqr_window(conf):
         return
     kw = {}
     if conf is not None:
@@ -370,9 +405,9 @@ def get_device_index(coll: Collection):
             di = getattr(coll, "_device_index", None)
             if di is None:
                 di = DeviceIndex(coll)
-                # satellite of the resident-loop PR: pay the cold-plan
-                # spike (BENCH_r04: devindex.plan max 1168ms) at build
-                # time, not on the first user query
+                # pay the cold-plan spike (a first devindex.plan took
+                # over a second) at build time, not on the first user
+                # query
                 di.warm_plans()
                 coll._device_index = di
         return di
@@ -509,13 +544,16 @@ def search_device_batch(coll: Collection, queries, *, topk: int = 10,
     # one titlerec memo for the whole batch: build_results, PQR,
     # page snippets and facets all re-read the same top docids
     doc_memo: dict[int, dict | None] = {}
+    fetches = 0
 
     def get_doc(d: int):
+        nonlocal fetches
         d = int(d)
         if d in doc_memo:
             return doc_memo[d]
         if len(doc_memo) >= 4096:
             doc_memo.clear()
+        fetches += 1
         rec = docproc.get_document(coll, docid=d)
         doc_memo[d] = rec
         return rec
@@ -535,7 +573,8 @@ def search_device_batch(coll: Collection, queries, *, topk: int = 10,
                 get_doc,
                 docids, scores, plan, topk=max(topk + offset, PQR_SCAN),
                 with_snippets=False, site_cluster=site_cluster,
-                site_of=di.sitehash_of, page=(offset, topk))
+                site_col=site_column(di, docids), page=(offset, topk),
+                conf=coll.conf)
             page = finish_page(
                 results, offset=offset, topk=topk, conf=coll.conf,
                 qlang=plan.lang, langid_of=di.langid_of,
@@ -548,6 +587,7 @@ def search_device_batch(coll: Collection, queries, *, topk: int = 10,
                 suggestion=_suggest(coll, plan)
                 if n_matched == 0 else None,
                 facets=compute_facets(plan, docids, get_doc)))
+    g_stats.count("query.titlerec_fetch", fetches)
     if results_lock is not None:
         trace.record("query.lock_wait", batch_span.t0, t_held)
     trace.record("query.results_work", t_held, batch_span.t1,

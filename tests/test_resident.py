@@ -352,6 +352,7 @@ def sleepy_server(tmp_path, monkeypatch):
         return [], 0
 
     monkeypatch.setattr(engine, "build_results", slow_tail)
+    monkeypatch.setattr(engine, "site_column", lambda di, docids: [])
     try:
         yield srv, loop, di
     finally:
