@@ -2224,6 +2224,10 @@ class DeviceIndex:
         no per-query cube assembly."""
         T = max(len(p.required) for p in plans)
         B = 4 if len(plans) <= 4 else max(self._fd_bmax(), len(plans))
+        # lanes from len(plans) on are padding: the fused kernel is told
+        # how many are live and spends nothing on the rest
+        g_stats.count("devindex.fd.lanes", B)
+        g_stats.count("devindex.fd.pad_lanes", B - len(plans))
         zq = 4 * getattr(self, "cube_zero_slot", 0)
         cs = np.full((B, T, 4), zq, np.int32)
         sy = np.zeros((B, T, 4), np.uint32)
@@ -2283,7 +2287,8 @@ class DeviceIndex:
             None, _direct_cube,
             d_cube, self.d_payload, self.d_docc,
             self.d_siterank, self.d_doclang, self.d_dead,
-            np.int32(self.n_docs), d_filter, d_sort, cs, sy, *args,
+            np.int32(self.n_docs), d_filter, d_sort, cs, sy,
+            np.array([len(plans)], np.int32), *args,
             n_positions=self.P, lpost=Lp, k2=k2,
             n_sel=min(n_sel, self.D_cap),
             use_table=any(p.has_table for p in plans),
@@ -2683,7 +2688,7 @@ def _full_cube(d_payload, d_docc, d_cube, d_dense_cnt,
                                    "use_sort"))
 def _direct_cube(d_cube, d_payload, d_docc, d_siterank,
                  d_doclang, d_dead, n_docs_total, d_filter, d_sort,
-                 g_quarter, g_qsyn,
+                 g_quarter, g_qsyn, n_live,
                  p_start, p_len, p_group, p_base, p_quota, p_syn,
                  p_isbase,
                  freqw, required, negative, scored, counts, table, qlang,
@@ -2708,7 +2713,10 @@ def _direct_cube(d_cube, d_payload, d_docc, d_siterank,
     the same scatter the generic F2 runs, capped by the planner at
     FD_SCATTER_MAX_LANES. Scoring is the very same ``min_scores``
     every other path runs, so parity is bit-for-bit by construction.
-    Output format matches _full_cube."""
+    Output format matches _full_cube. ``n_live`` [1] int32 counts the
+    wave's lanes that hold a query (the rest are padding): data, not a
+    static, so it adds no program; the fused kernel skips the padding,
+    this body scores it."""
     D = d_dead.shape[0]
     P = n_positions
     N = d_payload.shape[0]
@@ -2719,7 +2727,7 @@ def _direct_cube(d_cube, d_payload, d_docc, d_siterank,
     if use_fused(D):
         return _direct_cube_fused(
             d_cube, d_payload, d_docc, d_siterank, d_doclang, d_dead,
-            n_docs_total, d_filter, d_sort, g_quarter, g_qsyn,
+            n_docs_total, d_filter, d_sort, g_quarter, g_qsyn, n_live,
             p_start, p_len, p_group, p_base, p_quota, p_syn, p_isbase,
             freqw, required, negative, scored, counts, table, qlang,
             n_positions=n_positions, lpost=lpost, k2=k2, n_sel=n_sel,
@@ -2802,7 +2810,7 @@ def _direct_cube(d_cube, d_payload, d_docc, d_siterank,
 
 def _direct_cube_fused(d_cube, d_payload, d_docc, d_siterank,
                        d_doclang, d_dead, n_docs_total, d_filter,
-                       d_sort, g_quarter, g_qsyn,
+                       d_sort, g_quarter, g_qsyn, n_live,
                        p_start, p_len, p_group, p_base, p_quota,
                        p_syn, p_isbase,
                        freqw, required, negative, scored, counts,
@@ -2855,7 +2863,7 @@ def _direct_cube_fused(d_cube, d_payload, d_docc, d_siterank,
             # pure quarter-row wave: no tail cube at all
             ms, presbits = fd_scores_fused_notail(
                 g_quarter.reshape(B, T * 4),
-                g_qsyn.reshape(B, T * 4).astype(jnp.int32),
+                g_qsyn.reshape(B, T * 4).astype(jnp.int32), n_live,
                 d_cube, d_dead.astype(jnp.int32).reshape(1, D),
                 freqw, counts.astype(jnp.float32), T=T, P=P,
                 interpret=interp)
@@ -2864,7 +2872,7 @@ def _direct_cube_fused(d_cube, d_payload, d_docc, d_siterank,
                                       p_base, p_syn, p_isbase)
             ms, presbits = fd_scores_fused(
                 g_quarter.reshape(B, T * 4),
-                g_qsyn.reshape(B, T * 4).astype(jnp.int32),
+                g_qsyn.reshape(B, T * 4).astype(jnp.int32), n_live,
                 d_cube, tails, d_dead.astype(jnp.int32).reshape(1, D),
                 freqw, counts.astype(jnp.float32), T=T, P=P,
                 interpret=interp)

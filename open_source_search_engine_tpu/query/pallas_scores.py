@@ -223,8 +223,8 @@ def _min_scores_fused(cube, freqw, counts, interpret: bool = False):
 
 # --------------------------------------------------------------- FD path
 
-def _fd_kernel(gq_ref, syn_ref, rows_hbm, *rest, T: int, P: int,
-               has_tail: bool):
+def _fd_kernel(gq_ref, syn_ref, nlive_ref, rows_hbm, *rest, T: int,
+               P: int, has_tail: bool):
     """Grid (B, D/TILE): ONE step per (query, doc tile). The step
     issues T·4 async DMAs pulling the query's quarter-row slices from
     the HBM-resident cube straight into the VMEM scratch (a grid axis
@@ -232,7 +232,12 @@ def _fd_kernel(gq_ref, syn_ref, rows_hbm, *rest, T: int, P: int,
     form is ~16× fewer steps), waits, assembles, scores. Waves whose
     every query is pure quarter-rows (no posting tail — the common FD
     case) compile WITHOUT the tail input, skipping a cube-sized HBM
-    write+read per query."""
+    write+read per query.
+
+    Lanes from ``nlive_ref[0]`` on are the wave's padding: their cube
+    is all zero and their counts False, so they score exactly
+    (ms 1.0, presence 0) — written as such, with no DMA and no
+    scoring."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -247,75 +252,83 @@ def _fd_kernel(gq_ref, syn_ref, rows_hbm, *rest, T: int, P: int,
     d = pl.program_id(1)
     TQ = T * 4
     TD = acc_ref.shape[2]
+    lane_live = b < nlive_ref[0]
 
     def dma(tq):
         return pltpu.make_async_copy(
             rows_hbm.at[gq_ref[b, tq], :, pl.dslice(d * TD, TD)],
             acc_ref.at[tq], sems.at[tq])
 
-    for tq in range(TQ):
-        dma(tq).start()
-    for tq in range(TQ):
-        dma(tq).wait()
+    @pl.when(lane_live)
+    def _score():
+        for tq in range(TQ):
+            dma(tq).start()
+        for tq in range(TQ):
+            dma(tq).wait()
 
-    # per-quarter synonym bit, read from the prefetched scalars and
-    # OR'd in place (a [TQ]→[TQ,1,1] vector broadcast is an
-    # unsupported Mosaic shape cast; the scalar form also skips the
-    # no-synonym common case entirely)
-    for tq in range(TQ):
-        sb = (syn_ref[b, tq].astype(jnp.uint32) << jnp.uint32(31))
+        # per-quarter synonym bit, read from the prefetched scalars and
+        # OR'd in place (a [TQ]→[TQ,1,1] vector broadcast is an
+        # unsupported Mosaic shape cast; the scalar form also skips the
+        # no-synonym common case entirely)
+        for tq in range(TQ):
+            sb = (syn_ref[b, tq].astype(jnp.uint32) << jnp.uint32(31))
 
-        @pl.when(sb != 0)
-        def _orsyn(tq=tq, sb=sb):
-            r = acc_ref[tq]
-            acc_ref[tq] = jnp.where(r != 0, r | sb, r)
+            @pl.when(sb != 0)
+            def _orsyn(tq=tq, sb=sb):
+                r = acc_ref[tq]
+                acc_ref[tq] = jnp.where(r != 0, r | sb, r)
 
-    rows = acc_ref[...]                             # [T·4, P4, TD]
-    live = dead_ref[0] == 0                         # [TD]
-    cube = jnp.where(live[None, None, :], rows.reshape(T, P, TD),
-                     jnp.uint32(0))
-    if has_tail:
-        # tail postings were dead-filtered at scatter time (delta
-        # postings of re-added docs live PAST the dead mask) — OR
-        # after masking. Slot ranges are disjoint by the slot plan.
-        cube = cube | tail_ref[0]
-    ms, pres = _score_tile(cube, fw_ref[0, 0], cnt_ref[0, 0], T, P)
-    ms_ref[0, 0] = ms
-    pres_ref[0, 0] = pres
+        rows = acc_ref[...]                         # [T·4, P4, TD]
+        live = dead_ref[0] == 0                     # [TD]
+        cube = jnp.where(live[None, None, :], rows.reshape(T, P, TD),
+                         jnp.uint32(0))
+        if has_tail:
+            # tail postings were dead-filtered at scatter time (delta
+            # postings of re-added docs live PAST the dead mask) — OR
+            # after masking. Slot ranges are disjoint by the slot plan.
+            cube = cube | tail_ref[0]
+        ms, pres = _score_tile(cube, fw_ref[0, 0], cnt_ref[0, 0], T, P)
+        ms_ref[0, 0] = ms
+        pres_ref[0, 0] = pres
+
+    @pl.when(jnp.logical_not(lane_live))
+    def _pad():
+        ms_ref[0, 0] = jnp.ones((TD,), jnp.float32)
+        pres_ref[0, 0] = jnp.zeros((TD,), jnp.int32)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("T", "P", "interpret"))
-def _fd_scores_fused(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
-                     freqw, counts, T: int, P: int,
+def _fd_scores_fused(g_quarter, g_qsyn, n_live, d_cube, tail_cube,
+                     dead_i32, freqw, counts, T: int, P: int,
                      interpret: bool = False):
-    return _fd_call(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
-                    freqw, counts, T=T, P=P, interpret=interpret,
-                    has_tail=True)
+    return _fd_call(g_quarter, g_qsyn, n_live, d_cube, tail_cube,
+                    dead_i32, freqw, counts, T=T, P=P,
+                    interpret=interpret, has_tail=True)
 
 
-def fd_scores_fused(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
-                    freqw, counts, T: int, P: int,
+def fd_scores_fused(g_quarter, g_qsyn, n_live, d_cube, tail_cube,
+                    dead_i32, freqw, counts, T: int, P: int,
                     interpret: bool = False):
     """Tail-carrying variant (see _fd_kernel)."""
     d_cube = _guard_cube(d_cube, "pallas.fd")
-    return _fd_scores_fused(g_quarter, g_qsyn, d_cube, tail_cube,
-                            dead_i32, freqw, counts, T=T, P=P,
-                            interpret=interpret)
+    return _fd_scores_fused(g_quarter, g_qsyn, n_live, d_cube,
+                            tail_cube, dead_i32, freqw, counts, T=T,
+                            P=P, interpret=interpret)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("T", "P", "interpret"))
-def fd_scores_fused_notail(g_quarter, g_qsyn, d_cube, dead_i32,
+def fd_scores_fused_notail(g_quarter, g_qsyn, n_live, d_cube, dead_i32,
                            freqw, counts, T: int, P: int,
                            interpret: bool = False):
     """No-tail variant: pure quarter-row waves."""
-    return _fd_call(g_quarter, g_qsyn, d_cube, None, dead_i32,
+    return _fd_call(g_quarter, g_qsyn, n_live, d_cube, None, dead_i32,
                     freqw, counts, T=T, P=P, interpret=interpret,
                     has_tail=False)
 
 
-def _fd_call(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
+def _fd_call(g_quarter, g_qsyn, n_live, d_cube, tail_cube, dead_i32,
              freqw, counts, T: int, P: int,
              interpret: bool, has_tail: bool):
     """The direct-cube route, fused: returns (min_score [B, D] f32,
@@ -323,6 +336,8 @@ def _fd_call(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
 
     ``g_quarter``/``g_qsyn`` [B, T·4] int32 — absolute quarter-row
     indices into the resident cube + per-quarter synonym flags;
+    ``n_live`` [1] int32 — the wave's lanes that hold a query, the
+    first ``n_live`` (the rest are padding, answered without work);
     ``d_cube`` the resident cube as it is built and kept, quarter rows
     [Vc·4, P/4, D]: the kernel's HBM operand as it stands, so no wave
     program copies or relayouts it; ``tail_cube`` [B, T, P, D] uint32
@@ -340,34 +355,47 @@ def _fd_call(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
     # sublane block dims to match the array or divide 8)
     fw = freqw.astype(jnp.float32).reshape(B, 1, T)
     cnt = counts.astype(jnp.float32).reshape(B, 1, T)
+    n_tiles = D // TILE_D
+
+    def held(b, d, nl):
+        """(lane, tile) of the block a step reads: a padding lane keeps
+        the last live lane's last tile, the block already in VMEM, so
+        the pipeline fetches nothing for it."""
+        live = b < nl[0]
+        return (jnp.where(live, b, jnp.maximum(nl[0] - 1, 0)),
+                jnp.where(live, d, n_tiles - 1))
+
+    def tail_block(b, d, gq, syn, nl):
+        lane, tile = held(b, d, nl)
+        return lane, 0, 0, tile
+
+    def dead_block(b, d, gq, syn, nl):
+        return 0, held(b, d, nl)[1]
 
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.ANY),   # resident rows: HBM
     ]
     operands = [d_cube]
     if has_tail:
-        in_specs.append(
-            pl.BlockSpec((1, T, P, TILE_D),
-                         lambda b, d, gq, syn: (b, 0, 0, d)))
+        in_specs.append(pl.BlockSpec((1, T, P, TILE_D), tail_block))
         operands.append(tail_cube)
     in_specs += [
-        pl.BlockSpec((1, TILE_D),
-                     lambda b, d, gq, syn: (0, d)),
+        pl.BlockSpec((1, TILE_D), dead_block),
         pl.BlockSpec((1, 1, T),
-                     lambda b, d, gq, syn: (b, 0, 0)),
+                     lambda b, d, gq, syn, nl: (b, 0, 0)),
         pl.BlockSpec((1, 1, T),
-                     lambda b, d, gq, syn: (b, 0, 0)),
+                     lambda b, d, gq, syn, nl: (b, 0, 0)),
     ]
     operands += [dead_i32, fw, cnt]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,       # g_quarter, g_qsyn
-        grid=(B, D // TILE_D),
+        num_scalar_prefetch=3,       # g_quarter, g_qsyn, n_live
+        grid=(B, n_tiles),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, TILE_D),
-                         lambda b, d, gq, syn: (b, 0, d)),
+                         lambda b, d, gq, syn, nl: (b, 0, d)),
             pl.BlockSpec((1, 1, TILE_D),
-                         lambda b, d, gq, syn: (b, 0, d)),
+                         lambda b, d, gq, syn, nl: (b, 0, d)),
         ],
         scratch_shapes=[
             pltpu.VMEM((T * 4, P // 4, TILE_D), jnp.uint32),
@@ -384,7 +412,7 @@ def _fd_call(g_quarter, g_qsyn, d_cube, tail_cube, dead_i32,
         out_shape=[jax.ShapeDtypeStruct((B, 1, D), jnp.float32),
                    jax.ShapeDtypeStruct((B, 1, D), jnp.int32)],
         interpret=interpret,
-    )(g_quarter, g_qsyn, *operands)
+    )(g_quarter, g_qsyn, n_live, *operands)
     return ms[:, 0], pres[:, 0]
 
 

@@ -66,6 +66,93 @@ class TestMinScoresFused:
         np.testing.assert_allclose(np.asarray(pal), np.asarray(ref))
 
 
+def _fd_wave(rng, B, n_live, T, P, D, Vc, tail):
+    """One FD wave as ``_run_batch_fd`` lays it out: a quarter-row cube
+    [Vc·4, P/4, D] whose last slot is all zero, random queries in the
+    first ``n_live`` lanes, and padding after them (every quarter on
+    the zero slot, counts False, no tail)."""
+    slots, _ = _rand_cube(rng, Vc - 1, P, D)
+    cube = np.concatenate([slots.reshape((Vc - 1) * 4, P // 4, D),
+                           np.zeros((4, P // 4, D), np.uint32)])
+    zq = 4 * (Vc - 1)
+    gq = np.full((B, T, 4), zq, np.int32)
+    sy = np.zeros((B, T, 4), np.int32)
+    fw = np.full((B, T), 0.5, np.float32)
+    counts = np.zeros((B, T), np.float32)
+    tails = np.zeros((B, T, P, D), np.uint32)
+    for b in range(n_live):
+        gq[b] = 4 * rng.integers(0, Vc - 1, T)[:, None] + np.arange(4)
+        sy[b] = rng.integers(0, 2, (T, 4))
+        fw[b] = rng.random(T) * 0.5 + 0.2
+        counts[b] = rng.random(T) < 0.7
+        counts[b, 0] = 1.0
+        if tail:
+            # the slot plan keeps tail postings off the quarter rows'
+            rows = cube[gq[b].reshape(-1)].reshape(T, P, D)
+            tails[b] = np.where(rows == 0, _rand_cube(
+                rng, T, P, D, density=0.02)[0], 0)
+    dead = (rng.random((1, D)) < 0.05).astype(np.int32)
+    return (gq.reshape(B, T * 4), sy.reshape(B, T * 4), cube, tails,
+            dead, fw, counts)
+
+
+class TestFdPadLanes:
+    """The fused FD kernel skips a wave's padding lanes (no DMA, no
+    scoring) and writes what scoring them would: min_score 1.0 and
+    presence 0. Every lane's output is bit for bit the kernel's with
+    every lane scored, and each live lane scores its assembled cube as
+    ``scorer.min_scores`` does."""
+
+    @pytest.mark.parametrize("tail", [False, True],
+                             ids=["notail", "tail"])
+    @pytest.mark.parametrize("B,n_live", [(4, 1), (4, 2), (4, 3),
+                                          (4, 4), (16, 5)])
+    def test_pad_lanes_cost_nothing_and_answer_the_same(self, B, n_live,
+                                                        tail):
+        from open_source_search_engine_tpu.query.pallas_scores import (
+            fd_scores_fused, fd_scores_fused_notail)
+        T, P, D, Vc = 3, 16, TILE_D * 2, 8
+        rng = np.random.default_rng(100 * B + n_live)
+        gq, sy, cube, tails, dead, fw, counts = _fd_wave(
+            rng, B, n_live, T, P, D, Vc, tail)
+
+        def run(n):
+            nl = np.array([n], np.int32)
+            if tail:
+                out = fd_scores_fused(gq, sy, nl, cube, tails, dead, fw,
+                                      counts, T=T, P=P, interpret=True)
+            else:
+                out = fd_scores_fused_notail(gq, sy, nl, cube, dead, fw,
+                                             counts, T=T, P=P,
+                                             interpret=True)
+            return [np.asarray(x) for x in out]
+
+        ms, pres = run(n_live)
+        ms_all, pres_all = run(B)
+        assert ms.shape == (B, D) and pres.shape == (B, D)
+        assert np.array_equal(ms.view(np.uint32), ms_all.view(np.uint32))
+        assert np.array_equal(pres, pres_all)
+        assert (ms[n_live:] == np.float32(1.0)).all()
+        assert (pres[n_live:] == 0).all()
+        # live lanes against the reference scoring of the assembled cube
+        for b in range(n_live):
+            rows = cube[gq[b]]                          # [T·4, P/4, D]
+            rows = np.where(rows != 0,
+                            rows | (sy[b].astype(np.uint32)
+                                    << np.uint32(31))[:, None, None],
+                            rows).reshape(T, P, D)
+            lane = np.where(dead[0] == 0, rows, 0).astype(np.uint32)
+            lane |= tails[b]
+            ref, present = scorer.min_scores(
+                jnp.asarray(lane), jnp.asarray(lane != 0),
+                jnp.asarray(fw[b]), jnp.asarray(counts[b] > 0.5))
+            np.testing.assert_allclose(ms[b], np.asarray(ref),
+                                       rtol=1e-5, atol=1e-7)
+            bits = (np.asarray(present).astype(np.int32)
+                    << np.arange(T)[:, None]).sum(0)
+            assert np.array_equal(pres[b], bits)
+
+
 def _assert_same_ranking(ref_ids, ref_scores, ids, scores, label):
     """Scores agree to the last-ulp reduction order; docids agree at
     strictly-untied ranks (tie order is not part of the contract)."""
@@ -179,19 +266,16 @@ class TestQuarterRowCube:
     @pytest.mark.parametrize("pallas", ["0", "force"],
                              ids=["jnp", "fused"])
     def test_fd_and_f2_answer_as_the_host_flat_path(self, env, pallas):
+        """One query a batch (an FD wave of three pad lanes), then five
+        FD queries in one batch (a B 16 wave of eleven)."""
         from open_source_search_engine_tpu.query import engine
         import open_source_search_engine_tpu.query.devindex as dv
 
         coll, di = env
         os.environ["OSSE_PALLAS"] = pallas
         dv._direct_cube.clear_cache()
-        for q, route in (("alpha beta", "fd"), ("alpha gamma", "fd"),
-                         ("boxes dogs", "f2")):
-            before = di.route_counts[route]
-            dev = engine.search_device(coll, q, topk=8,
-                                       site_cluster=False,
-                                       with_snippets=False)
-            assert di.route_counts[route] == before + 1, (q, route)
+
+        def same_as_host(q, dev):
             host = engine.search(coll, q, topk=8, site_cluster=False,
                                  with_snippets=False)
             assert dev.total_matches == host.total_matches, q
@@ -200,3 +284,89 @@ class TestQuarterRowCube:
                 [r.score for r in host.results],
                 [r.docid for r in dev.results],
                 [r.score for r in dev.results], q)
+
+        for q, route in (("alpha beta", "fd"), ("alpha gamma", "fd"),
+                         ("boxes dogs", "f2")):
+            before = di.route_counts[route]
+            lanes = _fd_lanes()
+            dev = engine.search_device(coll, q, topk=8,
+                                       site_cluster=False,
+                                       with_snippets=False)
+            assert di.route_counts[route] == before + 1, (q, route)
+            if route == "fd":
+                assert _fd_lanes() == (lanes[0] + 4, lanes[1] + 3), q
+            same_as_host(q, dev)
+        before = di.route_counts["fd"]
+        lanes = _fd_lanes()
+        devs = engine.search_device_batch(coll, FD_FIVE, topk=8,
+                                          site_cluster=False,
+                                          with_snippets=False)
+        assert di.route_counts["fd"] == before + 5
+        assert _fd_lanes() == (lanes[0] + 16, lanes[1] + 11)
+        for q, dev in zip(FD_FIVE, devs):
+            same_as_host(q, dev)
+
+    @pytest.mark.parametrize("n", [1, 5])
+    def test_an_fd_wave_counts_its_pad_lanes_and_keeps_its_key(
+            self, env, monkeypatch, n):
+        """``_run_batch_fd`` counts B lanes and B - n pad lanes, hands
+        the kernel the live count as data, and dispatches the program
+        key and statics the bucket rule has always given."""
+        import open_source_search_engine_tpu.query.devindex as dv
+        from open_source_search_engine_tpu.query.compiler import \
+            compile_query
+
+        _, di = env
+        plans = [di.plan(compile_query(q, 0)) for q in FD_FIVE[:n]]
+        seen = []
+
+        def note(self, name, bucket, modeled, fn, *args, **statics):
+            seen.append((name, tuple(int(x) for x in bucket), args,
+                         statics))
+        monkeypatch.setattr(dv.DeviceIndex, "_costed", note)
+        lanes = _fd_lanes()
+        di._run_batch_fd(plans, 128, 2048)
+        # the bucket rule, as it stood before pad lanes were skipped
+        B = 4 if n <= 4 else max(di._fd_bmax(), n)
+        T = max(len(p.required) for p in plans)
+        mrp = max([len(p.p_start) for p in plans] + [1])
+        Rp = 4 if mrp <= 4 else dv._bucket(mrp, 8)
+        assert _fd_lanes() == (lanes[0] + B, lanes[1] + B - n)
+        assert B == (4 if n == 1 else 16)
+        [(name, bucket, args, statics)] = seen
+        assert name == "devindex._direct_cube"
+        assert bucket == (B, T, Rp, 0, 128, min(2048, di.D_cap))
+        assert set(statics) == {"n_positions", "lpost", "k2", "n_sel",
+                                "use_table", "use_filter", "use_sort"}
+        assert statics["lpost"] == 0 and statics["k2"] == 128
+        # g_quarter, g_qsyn, then the live count: [n] int32, traced
+        assert args[9].shape == (B, T, 4) and args[10].shape == (B, T, 4)
+        assert args[11].dtype == np.int32 and args[11].tolist() == [n]
+
+
+@pytest.mark.parametrize("counters,want", [
+    ({"devindex.fd.lanes": 8.0, "devindex.fd.pad_lanes": 5.0}, 62.5),
+    ({"devindex.fd.lanes": 16.0, "devindex.fd.pad_lanes": 0.0}, 0.0),
+    ({"query": 40.0}, None),            # the parent: no such counters
+    ({"devindex.fd.lanes": 0.0, "devindex.fd.pad_lanes": 0.0}, None),
+])
+def test_fd_pad_share_reads_the_counters_or_nothing(counters, want):
+    import importlib.util
+    from pathlib import Path
+    path = (Path(__file__).resolve().parents[1] / "benchmarks"
+            / "layer_metrics" / "fd_pad_share.py")
+    spec = importlib.util.spec_from_file_location("fd_pad_share", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    assert reader.read({"counters": counters}) == want
+
+
+#: FD queries of the route corpus that meet in one pure quarter-row wave
+FD_FIVE = ["alpha beta", "alpha gamma", "beta gamma", "alpha boxes",
+           "beta dogs"]
+
+
+def _fd_lanes() -> tuple[float, float]:
+    from open_source_search_engine_tpu.utils.stats import g_stats
+    c = g_stats.snapshot()["counters"]
+    return c.get("devindex.fd.lanes", 0), c.get("devindex.fd.pad_lanes", 0)

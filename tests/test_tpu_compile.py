@@ -95,6 +95,7 @@ def test_fd_scores_fused_compiles_with_no_cube_copy(one_chip, tail):
     T = 4
     head = (_sds(one_chip, (B, T * 4), jnp.int32),
             _sds(one_chip, (B, T * 4), jnp.int32),
+            _sds(one_chip, (1,), jnp.int32),
             _sds(one_chip, (VC * 4, P // 4, D), jnp.uint32))
     rest = (_sds(one_chip, (1, D), jnp.int32),
             _sds(one_chip, (B, T), jnp.float32),
