@@ -92,6 +92,7 @@ from __future__ import annotations
 
 import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from functools import partial
 
@@ -110,7 +111,7 @@ from ..utils.stats import g_stats
 from . import devcheck, weights
 from .compiler import SUB_SYNONYM, QueryPlan, compile_query
 from .packer import (IMPACT_SCALE, MAX_POSITIONS, T_FLOOR, TABLE_SIZE,
-                     _bucket, _pad1, demote_impacts, group_flags,
+                     WIDE_T, _bucket, _pad1, demote_impacts, group_flags,
                      pack_payload, pad_table)
 from .scorer import final_multipliers, min_scores, presence_table_ok
 
@@ -144,17 +145,22 @@ _WAVE_KAPPA_STAT = {k: f"devindex.wave_f1_k{k}"
 #: bound where several indexes serve)
 _PROGRAM_SLOT_STAT = tuple(f"devindex.program_slot.{i:02d}"
                            for i in range(64))
-#: a query each, where ``route_counts`` is bumped (first route only)
+#: a query each, where ``route_counts`` is bumped (first route only);
+#: ``.t8``: of them, the queries of the ``WIDE_T`` bucket
 _ROUTE_STAT = {r: f"devindex.route.{r}" for r in ("f1", "fd", "f2")}
+_ROUTE_STAT_WIDE = {r: f"devindex.route.{r}.t8" for r in ("f1", "fd", "f2")}
 
 
-def _f1_rows(mrd: int, mrs: int, mls: int, upper: bool = False
-             ) -> tuple[int, int, int]:
+def _f1_rows(mrd: int, mrs: int, mls: int, upper: bool = False,
+             wide: bool = False) -> tuple[int, int, int]:
     """(Rd, Rs, Lsp) of an F1 wave whose widest rider has ``mrd`` dense
     rows, ``mrs`` sparse rows and a longest sparse run of ``mls``: the
     first tier of the chain that covers all three (``upper``: a rung
-    above the first, which rides the long-run tiers only)."""
-    for t in (F1_UPPER_TIERS if upper else F1_TIERS):
+    above the first, which rides the long-run tiers only; ``wide``: a
+    wave of ``WIDE_T`` groups, which rides the top tier only)."""
+    chain = F1_TIERS[-1:] if wide else (
+        F1_UPPER_TIERS if upper else F1_TIERS)
+    for t in chain:
         if mrd <= t[0] and mrs <= t[1] and mls <= t[2]:
             return t
     # past 16 rows: outside the enumerated set, bucketed as before
@@ -181,22 +187,26 @@ KAPPA_FLOOR = 256  # phase-2 candidate count
 #: MAXIMUM of its riders' row counts and run lengths — so two queries
 #: that each ran alone can form a key neither reached. Invariant: for
 #: any set of F1 plans ``_issue_waves`` puts into one ``_run_batch``
-#: call, of queries of up to T_FLOOR plain words with the default
-#: filter and sort, the key is a member of ``f1_programs()``, which
-#: the index enumerates from its D_cap alone. How: the row shape
-#: (Rd, Rs, Lsp) is the first TIER of a chain that covers the wave (a
-#: chain, so the join of any riders is a tier); a wave holds four
-#: plans (B = 4: a fuller batch is more waves, not a wider program);
-#: on the ladder's three rungs phase 2 scores every selected
-#: candidate (k2 = κ, for single-group plans too); and a rung above
-#: the first rides the two long-run tiers only (escapees are few).
-#: Nine programs, sized by what a server can compile at start-up
-#: (PERF.md, Findings PR 30: ~120 s cold; each wider B bucket, each
-#: separate k2 would be as many again). Outside the set, and shaped as
-#: before: five words and more (T = 8), boolean tables, filters,
-#: sorts, κ above 32·KAPPA_FLOOR up to the terminal D_cap, and plans
-#: past 16 rows (``devindex.f1.key_outside_set`` counts their
-#: dispatches).
+#: call, of queries of up to T_FLOOR plain words, or of WIDE_T,
+#: with the default filter and sort, the key is a member of
+#: ``f1_programs()``, which the index enumerates from its D_cap alone.
+#: How: the row shape (Rd, Rs, Lsp) is the first TIER of a chain that
+#: covers the wave (a chain, so the join of any riders is a tier); a
+#: wave holds four plans (B = 4: a fuller batch is more waves, not a
+#: wider program); on the ladder's three rungs phase 2 scores every
+#: selected candidate (k2 = κ, for single-group plans too); and a rung
+#: above the first rides the two long-run tiers only (escapees are
+#: few). Nine programs at T_FLOOR, sized by what a server can compile
+#: at start-up (PERF.md, Findings: ~120 s cold; each wider B
+#: bucket, each separate k2 would be as many again). A wave of five
+#: to eight words (T = WIDE_T) rides the top tier alone, one
+#: program a rung: its words and their bigrams hold at most 16 rows
+#: where each sublist is one run, and ``warm_f1`` takes its three
+#: programs with the nine. Its key carries T; the T_FLOOR keys do not.
+#: Outside the set, and shaped as before: nine words and more,
+#: boolean tables, filters, sorts, κ above 32·KAPPA_FLOOR up to the
+#: terminal D_cap, and plans past 16 rows
+#: (``devindex.f1.key_outside_set`` counts their dispatches).
 F1_TIERS = ((4, 2, 128), (4, 2, 512), (4, 4, 512), (4, 4, 2048),
             (16, 16, 2048))
 #: the tiers a rung above the first may ride (the last two)
@@ -1673,12 +1683,16 @@ class DeviceIndex:
 
         f2 = [i for i in live if _route_f2(i)]
         f1 = [i for i in live if i not in set(f2)]
-        n_fd = sum(1 for i in f2 if plans[i].direct_ok)
-        for route, n in (("f1", len(f1)), ("fd", n_fd),
-                         ("f2", len(f2) - n_fd)):
-            self.route_counts[route] += n
-            if n:
-                g_stats.count(_ROUTE_STAT[route], n)
+        fd = [i for i in f2 if plans[i].direct_ok]
+        for route, idx in (("f1", f1), ("fd", fd),
+                           ("f2", [i for i in f2 if not plans[i].direct_ok])):
+            self.route_counts[route] += len(idx)
+            if idx:
+                g_stats.count(_ROUTE_STAT[route], len(idx))
+            wide = sum(1 for i in idx
+                       if len(plans[i].required) == WIDE_T)
+            if wide:
+                g_stats.count(_ROUTE_STAT_WIDE[route], wide)
 
         # wave loop: issue EVERY sub-batch dispatch, fetch ALL outputs
         # in one device_get (one host sync), then parse; queries whose
@@ -1877,15 +1891,18 @@ class DeviceIndex:
 
     def f1_programs(self) -> list[tuple]:
         """The closed F1 program space (``F1_TIERS``' invariant), as
-        ``_costed`` buckets (B, Rd, Rs, Lsp, κ, k2) at T = T_FLOOR with
-        no table, filter or sort — enumerated from D_cap alone, no
-        query seen. First rung: every tier; the two rungs above it:
-        the long-run tiers. In dispatch order: ``warm_f1`` walks it as
-        it stands."""
+        ``_costed`` buckets with no table, filter or sort — enumerated
+        from D_cap alone, no query seen. At T = T_FLOOR, (B, Rd, Rs,
+        Lsp, κ, k2): first rung, every tier; the two rungs above it,
+        the long-run tiers. Then at T = WIDE_T, (B, Rd, Rs, Lsp, κ,
+        k2, T): the top tier on each rung. ``warm_f1`` walks each
+        family in this order."""
         kap = [min(r * KAPPA_FLOOR, self.D_cap) for r in F1_RUNGS]
         out = [(F1_B, *tier, kap[0], kap[0]) for tier in F1_TIERS]
         for kappa in kap[1:]:
             out += [(F1_B, *tier, kappa, kappa) for tier in F1_UPPER_TIERS]
+        out += [(F1_B, *F1_TIERS[-1], kappa, kappa, WIDE_T)
+                for kappa in kap]
         return list(dict.fromkeys(out))   # a small D_cap folds rungs
 
     def warm_f1(self) -> int:
@@ -1899,13 +1916,16 @@ class DeviceIndex:
         compilation cache
         (utils/compilecache.py): cold it is seconds a program, after a
         restart a load. FD and F2 programs are not part of it (a fused
-        FD variant compiles for 90 s: ROADMAP S1)."""
+        FD variant compiles for 90 s: ROADMAP S1). Every program is
+        traced in the set's own order, one after another (a program's
+        cache key can depend on which program of its family was traced
+        first: PERF.md, Findings), then the compiles or cache
+        loads run side by side, then each program is dispatched."""
         if self._f1_warmed:
             return 0
-        T = T_FLOOR
         z = np.zeros
 
-        def dummy(nd: int, ns: int, run: int) -> ResidentPlan:
+        def dummy(T: int, nd: int, ns: int, run: int) -> ResidentPlan:
             req = z(T, bool)
             req[0] = True
             one = lambda n, dt=np.int32: np.ones(n, dt)
@@ -1931,9 +1951,17 @@ class DeviceIndex:
 
         with trace.timed_span("devindex.warm_f1"):
             keys = self.f1_programs()
-            outs = [self._run_batch([dummy(rd, rs, lsp)], kappa, k2)
-                    for _, rd, rs, lsp, kappa, k2 in keys]
-            jax.device_get(outs)
+            calls = [self._f1_call([dummy(t[0] if t else T_FLOOR, rd, rs,
+                                          lsp)], kappa, k2)
+                     for _, rd, rs, lsp, kappa, k2, *t in keys]
+            lowered = [_two_phase.lower(*args, **statics)
+                       for _, _, args, statics in calls]
+            with ThreadPoolExecutor(4, "warm-f1") as ex:
+                list(ex.map(lambda lo: lo.compile(), lowered))
+            jax.device_get([self._costed("devindex._two_phase", bucket,
+                                         modeled, _two_phase, *args,
+                                         **statics)
+                            for bucket, modeled, args, statics in calls])
         g_stats.count("devindex.f1.programs_enumerated", len(keys))
         self._f1_warmed = True
         return len(keys)
@@ -2016,28 +2044,25 @@ class DeviceIndex:
             return max(4, min(16, (4 << 30) // max(per_q, 1)))
         return max(4, min(16, self._f2_bmax()))
 
-    def wave_bytes_per_query(self, plans: list[ResidentPlan],
-                             packed: bool = True) -> float:
-        """Modelled HBM bytes the F1 wave path streams per query —
-        under the live packed layout (f16 impacts, uint8 doc meta,
-        length-bucketed Lsp tiles) or the legacy unpacked one (f32
-        impacts, int32 meta, flat 2048-lane tiles). Shares _run_batch's
-        tier chain so the model moves when the layout does; the
-        per-plan Lsp tile is the fine-grained bound (real waves pay
-        their rung-group's max). BENCH_DISPATCH enforces packed/legacy
-        ≤ 0.7 on this model with a nonzero exit."""
-        imp = 2 if packed else 4
-        meta = 1 if packed else 4
+    def wave_bytes_per_query(self, plans: list[ResidentPlan]) -> float:
+        """Modelled HBM bytes the F1 wave path streams per query under
+        the packed layout (f16 impacts, uint8 doc meta, length-bucketed
+        Lsp tiles): what devwatch shows beside a round's measured
+        device time. Shares _run_batch's tier chain so the model moves
+        when the layout does; the per-plan Lsp tile is the fine-grained
+        bound (real waves pay their rung-group's max)."""
+        imp = 2
+        meta = 1
         V = self.d_dense_imp.shape[0]
         D = self.D_cap
         B = max(len(plans), 1)
         total = 0.0
         for p in plans:
             mls = int(p.s_len.max()) if len(p.s_len) else 0
-            Rd, Rs, Lsp = _f1_rows(max(len(p.d_slot), 1),
-                                   max(len(p.s_start), 1),
-                                   mls if packed else LSP_MAX)
             T = max(len(p.required), 1)
+            Rd, Rs, Lsp = _f1_rows(max(len(p.d_slot), 1),
+                                   max(len(p.s_start), 1), mls,
+                                   wide=T == WIDE_T)
             k2 = min(128, D)
             # sparse lane gathers: doc4 + imp + rs4 + cnt1 + dead1
             total += Rs * Lsp * (4 + imp + 4 + 1 + 1)
@@ -2076,6 +2101,13 @@ class DeviceIndex:
         return fn(*args, **statics)
 
     def _run_batch(self, plans: list[ResidentPlan], kappa: int, k2: int):
+        bucket, modeled, args, statics = self._f1_call(plans, kappa, k2)
+        return self._costed("devindex._two_phase", bucket, modeled,
+                            _two_phase, *args, **statics)
+
+    def _f1_call(self, plans: list[ResidentPlan], kappa: int, k2: int):
+        """An F1 wave's (program key, modelled bytes, arguments,
+        statics) for ``_two_phase``."""
         # the row shape is a TIER of a chain (F1_TIERS): the wave pays
         # for its widest rider — dense rows, sparse rows, and the lane
         # tile of its longest sparse run (runs chunk at LSP_MAX in the
@@ -2086,8 +2118,9 @@ class DeviceIndex:
         mrs = max([len(p.s_start) for p in plans] + [1])
         mls = max([int(p.s_len.max()) if len(p.s_len) else 0
                    for p in plans] + [0])
-        Rd, Rs, Lsp = _f1_rows(mrd, mrs, mls, kappa > KAPPA_FLOOR)
         T = max(len(p.required) for p in plans)
+        Rd, Rs, Lsp = _f1_rows(mrd, mrs, mls, kappa > KAPPA_FLOOR,
+                               wide=T == WIDE_T)
         # one B: every per-lane cost (phase-1 chains, phase-2 gathers)
         # scales with B INCLUDING pad lanes, and a wider bucket is a
         # program of its own to compile (``_issue_waves`` chunks at B)
@@ -2096,9 +2129,11 @@ class DeviceIndex:
             B = _bucket(len(plans), 4)
         use_table = any(p.has_table for p in plans)
         d_filter, d_sort, uf, us = self._filter_sort_cols(plans[0])
-        bucket = (B, Rd, Rs, Lsp, kappa, k2)
-        if (T != T_FLOOR or use_table or uf or us
-                or bucket not in self._f1_keys):
+        # T is in the key of every program but the T_FLOOR family's
+        # (whose keys read as they always have)
+        bucket = (B, Rd, Rs, Lsp, kappa, k2) + (
+            () if T == T_FLOOR else (T,))
+        if use_table or uf or us or bucket not in self._f1_keys:
             g_stats.count("devindex.f1.key_outside_set")
 
         def pad_plan(p: ResidentPlan | None):
@@ -2147,13 +2182,12 @@ class DeviceIndex:
         # (each separate blocking fetch is a host sync of its own)
         modeled = self.wave_bytes_per_query(plans) * B \
             if devwatch.enabled() else None
-        return self._costed(
-            "devindex._two_phase", bucket, modeled, _two_phase,
+        return bucket, modeled, (
             self.d_payload, self.d_doc, self.d_imp, self.d_rs,
             self.d_cnt, self.d_dense_imp, self.d_dense_rs,
             self.d_dense_cnt,
             self.d_siterank, self.d_doclang, self.d_dead,
-            np.int32(self.n_docs), d_filter, d_sort, sel, *args,
+            np.int32(self.n_docs), d_filter, d_sort, sel, *args), dict(
             n_positions=self.P, lsp=Lsp, kappa=kappa, k2=k2,
             use_table=use_table, use_filter=uf, use_sort=us)
 
@@ -2243,6 +2277,9 @@ class DeviceIndex:
         Lp = 0 if maxlen == 0 else (512 if maxlen <= 512 else (
             F2_LPOST_FLOOR if maxlen <= F2_LPOST_FLOOR
             else F2_SCATTER_MAX))
+        if Lp and T == WIDE_T:
+            # live lanes whose kernel reads a tail cube beside the rows
+            g_stats.count("devindex.fd.t8_tail", len(plans))
 
         def pad_plan(p: ResidentPlan | None):
             if p is None:
@@ -2417,29 +2454,17 @@ def _two_phase(d_payload, d_doc, d_imp, d_rs, d_cnt,
             m1 = present & sc[:, None]
             ubw_m = jnp.where(m1, ubw, big)
             min_single_ub = jnp.min(ubw_m, axis=0)
-            from .scorer import MAX_PAIR_SPAN
-            if T <= MAX_PAIR_SPAN + 1:
-                # every pair is within the span, so the pair-bound min has
-                # a closed form: min over pairs of √(a_i·a_j) = √(min1·min2)
-                # over the two smallest present scored bounds — O(T·D)
-                # instead of the unrolled pair loop (~79 ms/wave at B=32)
-                npres = jnp.sum(m1, axis=0)                       # [D]
-                am = jnp.argmin(ubw_m, axis=0)                    # [D]
-                min2 = jnp.min(
-                    jnp.where(t_ax[:, None] == am[None, :], big, ubw_m),
-                    axis=0)
-                min_pair_ub = jnp.sqrt(min_single_ub * min2)
-                any_pair = npres >= 2
-            else:
-                min_pair_ub = jnp.full((D,), big)
-                any_pair = jnp.zeros((D,), bool)
-                for i in range(T):
-                    for j in range(i + 1, min(i + 1 + MAX_PAIR_SPAN, T)):
-                        ok = present[i] & present[j] & sc[i] & sc[j]
-                        pu = jnp.sqrt(ubw[i] * ubw[j])
-                        min_pair_ub = jnp.where(
-                            ok, jnp.minimum(min_pair_ub, pu), min_pair_ub)
-                        any_pair = any_pair | ok
+            # every pair is in the min, so the pair-bound min has a
+            # closed form at any T: min over pairs of √(a_i·a_j) =
+            # √(min1·min2) over the two smallest present scored bounds —
+            # O(T·D) instead of a pair loop (~79 ms/wave at B=32)
+            npres = jnp.sum(m1, axis=0)                           # [D]
+            am = jnp.argmin(ubw_m, axis=0)                        # [D]
+            min2 = jnp.min(
+                jnp.where(t_ax[:, None] == am[None, :], big, ubw_m),
+                axis=0)
+            min_pair_ub = jnp.sqrt(min_single_ub * min2)
+            any_pair = npres >= 2
             ubmin = jnp.minimum(jnp.where(any_pair, min_pair_ub, big),
                                 min_single_ub)
             # per-doc filter-only fallback (mirrors scorer.min_scores)
@@ -2856,12 +2881,15 @@ def _direct_cube_fused(d_cube, d_payload, d_docc, d_siterank,
                 jnp.where(ok, pay, jnp.uint32(0)).ravel(), mode="drop"
             ).reshape(T, P, D)
 
-    from .pallas_scores import fd_scores_fused_notail
+    from .pallas_scores import (fd_scores_fused_notail,
+                                fd_scores_fused_notail_t8)
     interp = jax.default_backend() == "cpu"
     with jax.named_scope("fd.fused_score"):
         if lpost == 0:
             # pure quarter-row wave: no tail cube at all
-            ms, presbits = fd_scores_fused_notail(
+            notail = fd_scores_fused_notail_t8 if T == WIDE_T \
+                else fd_scores_fused_notail
+            ms, presbits = notail(
                 g_quarter.reshape(B, T * 4),
                 g_qsyn.reshape(B, T * 4).astype(jnp.int32), n_live,
                 d_cube, d_dead.astype(jnp.int32).reshape(1, D),

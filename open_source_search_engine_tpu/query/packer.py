@@ -108,6 +108,7 @@ def _bucket(n: int, floor: int = 8) -> int:
 #: everyday queries collapse into a handful of buckets; the wasted lanes
 #: are masked compute the VPU shrugs off.
 T_FLOOR = 4      # term groups
+WIDE_T = 2 * T_FLOOR  # the group bucket of five to eight words
 L_FLOOR = 512    # postings per group
 D_FLOOR = 256    # candidate docs
 

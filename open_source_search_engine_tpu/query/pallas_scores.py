@@ -41,7 +41,8 @@ import numpy as np
 
 from ..index.posdb import HASHGROUP_END, HASHGROUP_INLINKTEXT
 from . import weights
-from .scorer import MAX_PAIR_SPAN, QDIST
+from .packer import WIDE_T
+from .scorer import QDIST
 
 #: doc-axis tile width (lane-dim multiple of 128). Sized UP to 1024:
 #: the FD grid runs T·4 steps per (query, tile), so step-dispatch
@@ -65,10 +66,35 @@ def _sel_chain(idx, table):
     return out
 
 
-def _score_tile(cube, fw, cnt, T: int, P: int):
+def pair_planes(T: int, P: int, TD: int):
+    """VMEM scratch the pair loop reads by a dynamic group index:
+    word positions, position weights, flags (in body | valid << 1)
+    [T, P, TD], and each group's term-frequency weight where the group
+    is present and scored, else -1 [T, 1, TD]."""
+    from jax.experimental.pallas import tpu as pltpu
+    return [pltpu.VMEM((T, P, TD), jnp.int32),
+            pltpu.VMEM((T, P, TD), jnp.float32),
+            pltpu.VMEM((T, P, TD), jnp.int32),
+            pltpu.VMEM((T, 1, TD), jnp.float32)]
+
+
+def _pair_ij(k, T: int):
+    """Pair ``k`` of the table (0, 1), (0, 2) .. (T-2, T-1) as scalar
+    (i, j), by a select chain over the rows' first pair indices."""
+    first = [sum(T - 1 - r for r in range(i)) for i in range(T - 1)]
+    i, base = jnp.int32(0), jnp.int32(0)
+    for r in range(1, T - 1):
+        hit = k >= first[r]
+        i = jnp.where(hit, jnp.int32(r), i)
+        base = jnp.where(hit, jnp.int32(first[r]), base)
+    return i, k - base + i + 1
+
+
+def _score_tile(cube, fw, cnt, T: int, P: int, planes):
     """The scoring body on one [T, P, TD] VMEM tile → (min_score [TD],
     presence bitmask [TD] int32). Bit-for-bit the scorer.min_scores
-    math (modulo reduction order)."""
+    math (modulo reduction order), over every term pair; ``planes`` are
+    ``pair_planes``' refs."""
     big = jnp.float32(9.99e8)
 
     valid = cube != 0
@@ -126,40 +152,50 @@ def _score_tile(cube, fw, cnt, T: int, P: int):
     s_mask = present & (cnt[:, None] > 0.5)
     min_single = jnp.min(jnp.where(s_mask, single, big), axis=0)
 
-    # pairs: exact max over P×P per nearby (i, j) (pair_best)
+    # pairs: exact max over P×P for every pair (i, j) (pair_best)
     in_body = _sel_chain(hg, weights.IN_BODY) > 0.5       # [T, P, TD]
-    min_pair = jnp.full(min_single.shape, big)
-    any_pair = jnp.zeros(min_single.shape, jnp.bool_)
-    for i in range(T):
-        for j in range(i + 1, min(i + 1 + MAX_PAIR_SPAN, T)):
-            delta = (wordpos[j][None, :, :]
-                     - wordpos[i][:, None, :]).astype(jnp.float32)
-            d_plain = jnp.maximum(jnp.abs(delta), 2.0)    # [P, P, TD]
-            bi = in_body[i][:, None, :]
-            bj = in_body[j][None, :, :]
-            mixed = bi != bj
-            both_nb = (~bi) & (~bj)
-            d_base = jnp.where(
-                both_nb & (d_plain > weights.NONBODY_DIST_CAP),
-                jnp.float32(weights.FIXED_DISTANCE), d_plain)
-            d_adj = (jnp.where(d_base >= QDIST, d_base - QDIST, d_base)
-                     + (delta < 0))
-            dist = jnp.where(mixed,
-                             jnp.float32(weights.FIXED_DISTANCE),
-                             d_adj)
-            pvij = (valid[i][:, None, :] & valid[j][None, :, :])
-            ps = (jnp.float32(weights.BASE_SCORE)
-                  * posw[i][:, None, :] * posw[j][None, :, :]
-                  / (dist + 1.0)) * pvij
-            best = jnp.max(ps, axis=(0, 1))               # [TD]
-            wts = best * fw[i] * fw[j]
-            pair_ok = (present[i] & present[j]
-                       & (cnt[i] > 0.5) & (cnt[j] > 0.5))
-            min_pair = jnp.where(pair_ok,
-                                 jnp.minimum(min_pair, wts), min_pair)
-            any_pair = any_pair | pair_ok
+    # one loop over the pair table, reading each group's planes from
+    # VMEM by a dynamic index: the body is traced once, so a program's
+    # compile does not grow with its pairs (T 8: 28)
+    wp_ref, pw_ref, fl_ref, gw_ref = planes
+    wp_ref[...] = wordpos
+    pw_ref[...] = posw
+    fl_ref[...] = (in_body.astype(jnp.int32)
+                   | (valid.astype(jnp.int32) << 1))
+    for t in range(T):
+        gw_ref[t] = jnp.where(s_mask[t], fw[t], jnp.float32(-1.0))[None, :]
 
-    ms = jnp.minimum(jnp.where(any_pair, min_pair, big), min_single)
+    def pair(k, min_pair):
+        i, j = _pair_ij(k, T)
+        fl_i, fl_j = fl_ref[i], fl_ref[j]
+        gw_i, gw_j = gw_ref[i][0], gw_ref[j][0]
+        delta = (wp_ref[j][None, :, :]
+                 - wp_ref[i][:, None, :]).astype(jnp.float32)
+        d_plain = jnp.maximum(jnp.abs(delta), 2.0)        # [P, P, TD]
+        bi = ((fl_i & 1) > 0)[:, None, :]
+        bj = ((fl_j & 1) > 0)[None, :, :]
+        mixed = bi != bj
+        both_nb = (~bi) & (~bj)
+        d_base = jnp.where(
+            both_nb & (d_plain > weights.NONBODY_DIST_CAP),
+            jnp.float32(weights.FIXED_DISTANCE), d_plain)
+        d_adj = (jnp.where(d_base >= QDIST, d_base - QDIST, d_base)
+                 + (delta < 0))
+        dist = jnp.where(mixed, jnp.float32(weights.FIXED_DISTANCE),
+                         d_adj)
+        pvij = ((fl_i & 2) > 0)[:, None, :] & ((fl_j & 2) > 0)[None, :, :]
+        ps = (jnp.float32(weights.BASE_SCORE)
+              * pw_ref[i][:, None, :] * pw_ref[j][None, :, :]
+              / (dist + 1.0)) * pvij
+        wts = jnp.max(ps, axis=(0, 1)) * gw_i * gw_j      # [TD]
+        pair_ok = (gw_i >= 0.0) & (gw_j >= 0.0)
+        return jnp.where(pair_ok, jnp.minimum(min_pair, wts), min_pair)
+
+    # no pair present leaves min_pair at big
+    min_pair = jax.lax.fori_loop(0, T * (T - 1) // 2, pair,
+                                 jnp.full(min_single.shape, big))
+
+    ms = jnp.minimum(min_pair, min_single)
     ms = jnp.where(jnp.any(s_mask, axis=0), ms, jnp.float32(1.0))
     # presence bitmask (T ≤ 16 bits): callers unpack for req/neg/table
     pres = jnp.zeros(ms.shape, jnp.int32)
@@ -170,8 +206,9 @@ def _score_tile(cube, fw, cnt, T: int, P: int):
 
 # --------------------------------------------------------------- F2 path
 
-def _ms_kernel(cube_ref, fw_ref, cnt_ref, out_ref, *, T: int, P: int):
-    ms, _ = _score_tile(cube_ref[0], fw_ref[0], cnt_ref[0], T, P)
+def _ms_kernel(cube_ref, fw_ref, cnt_ref, out_ref, *planes, T: int,
+               P: int):
+    ms, _ = _score_tile(cube_ref[0], fw_ref[0], cnt_ref[0], T, P, planes)
     out_ref[0] = ms
 
 
@@ -216,6 +253,7 @@ def _min_scores_fused(cube, freqw, counts, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((1, TILE_D), lambda d: (0, d)),
         out_shape=jax.ShapeDtypeStruct((1, D), jnp.float32),
+        scratch_shapes=pair_planes(T, P, TILE_D),
         interpret=interpret,
     )(cube4, fw, cnt)
     return out[0]
@@ -243,10 +281,10 @@ def _fd_kernel(gq_ref, syn_ref, nlive_ref, rows_hbm, *rest, T: int,
 
     if has_tail:
         tail_ref, dead_ref, fw_ref, cnt_ref, ms_ref, pres_ref, \
-            acc_ref, sems = rest
+            acc_ref, sems, *planes = rest
     else:
         dead_ref, fw_ref, cnt_ref, ms_ref, pres_ref, acc_ref, \
-            sems = rest
+            sems, *planes = rest
 
     b = pl.program_id(0)
     d = pl.program_id(1)
@@ -287,7 +325,8 @@ def _fd_kernel(gq_ref, syn_ref, nlive_ref, rows_hbm, *rest, T: int,
             # postings of re-added docs live PAST the dead mask) — OR
             # after masking. Slot ranges are disjoint by the slot plan.
             cube = cube | tail_ref[0]
-        ms, pres = _score_tile(cube, fw_ref[0, 0], cnt_ref[0, 0], T, P)
+        ms, pres = _score_tile(cube, fw_ref[0, 0], cnt_ref[0, 0], T, P,
+                               planes)
         ms_ref[0, 0] = ms
         pres_ref[0, 0] = pres
 
@@ -307,14 +346,26 @@ def _fd_scores_fused(g_quarter, g_qsyn, n_live, d_cube, tail_cube,
                     interpret=interpret, has_tail=True)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("T", "P", "interpret"))
+def fd_scores_fused_t8(g_quarter, g_qsyn, n_live, d_cube, tail_cube,
+                       dead_i32, freqw, counts, T: int, P: int,
+                       interpret: bool = False):
+    """``_fd_scores_fused`` at ``WIDE_T`` (five to eight words): a
+    program of its own name, which the device trace tells apart."""
+    return _fd_call(g_quarter, g_qsyn, n_live, d_cube, tail_cube,
+                    dead_i32, freqw, counts, T=T, P=P,
+                    interpret=interpret, has_tail=True)
+
+
 def fd_scores_fused(g_quarter, g_qsyn, n_live, d_cube, tail_cube,
                     dead_i32, freqw, counts, T: int, P: int,
                     interpret: bool = False):
     """Tail-carrying variant (see _fd_kernel)."""
     d_cube = _guard_cube(d_cube, "pallas.fd")
-    return _fd_scores_fused(g_quarter, g_qsyn, n_live, d_cube,
-                            tail_cube, dead_i32, freqw, counts, T=T,
-                            P=P, interpret=interpret)
+    fn = fd_scores_fused_t8 if T == WIDE_T else _fd_scores_fused
+    return fn(g_quarter, g_qsyn, n_live, d_cube, tail_cube, dead_i32,
+              freqw, counts, T=T, P=P, interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -323,6 +374,18 @@ def fd_scores_fused_notail(g_quarter, g_qsyn, n_live, d_cube, dead_i32,
                            freqw, counts, T: int, P: int,
                            interpret: bool = False):
     """No-tail variant: pure quarter-row waves."""
+    return _fd_call(g_quarter, g_qsyn, n_live, d_cube, None, dead_i32,
+                    freqw, counts, T=T, P=P, interpret=interpret,
+                    has_tail=False)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("T", "P", "interpret"))
+def fd_scores_fused_notail_t8(g_quarter, g_qsyn, n_live, d_cube,
+                              dead_i32, freqw, counts, T: int, P: int,
+                              interpret: bool = False):
+    """``fd_scores_fused_notail`` at ``T`` 8 (see
+    ``fd_scores_fused_t8``)."""
     return _fd_call(g_quarter, g_qsyn, n_live, d_cube, None, dead_i32,
                     freqw, counts, T=T, P=P, interpret=interpret,
                     has_tail=False)
@@ -400,12 +463,13 @@ def _fd_call(g_quarter, g_qsyn, n_live, d_cube, tail_cube, dead_i32,
         scratch_shapes=[
             pltpu.VMEM((T * 4, P // 4, TILE_D), jnp.uint32),
             pltpu.SemaphoreType.DMA((T * 4,)),
-        ],
+        ] + pair_planes(T, P, TILE_D),
     )
     # no scope of its own: the kernel's op takes the name of the
-    # innermost scope, and stays ``_fd_scores_fused`` /
-    # ``fd_scores_fused_notail`` (the jit it is called under), which is
-    # what the ledger's ``breakdown.device_ops`` has held since PR 27
+    # innermost scope, the jit it is called under: ``_fd_scores_fused``
+    # / ``fd_scores_fused_notail``, the names the ledger's
+    # ``breakdown.device_ops`` has always held, and their ``_t8`` twins
+    # at ``WIDE_T``
     ms, pres = pl.pallas_call(
         functools.partial(_fd_kernel, T=T, P=P, has_tail=has_tail),
         grid_spec=grid_spec,

@@ -153,6 +153,47 @@ class TestFdPadLanes:
             assert np.array_equal(pres[b], bits)
 
 
+@pytest.mark.parametrize("tail", [False, True], ids=["notail", "tail"])
+def test_wide_fd_kernel_rolls_its_pairs_and_answers_the_same(tail):
+    """At ``T`` 8 the kernel rolls its 28 pairs (VMEM planes read by a
+    dynamic group index), as at every ``T``, and runs under names of its
+    own; each live lane
+    scores its assembled cube as ``scorer.min_scores`` does, and a pad
+    lane still writes ms 1.0 and presence 0."""
+    from open_source_search_engine_tpu.query import pallas_scores as ps
+    T, P, D, Vc, B, n_live = 8, 16, TILE_D * 2, 12, 4, 3
+    assert T == ps.WIDE_T
+    rng = np.random.default_rng(808)
+    gq, sy, cube, tails, dead, fw, counts = _fd_wave(
+        rng, B, n_live, T, P, D, Vc, tail)
+    nl = np.array([n_live], np.int32)
+    if tail:
+        ms, pres = ps.fd_scores_fused(gq, sy, nl, cube, tails, dead, fw,
+                                      counts, T=T, P=P, interpret=True)
+    else:
+        ms, pres = ps.fd_scores_fused_notail_t8(
+            gq, sy, nl, cube, dead, fw, counts, T=T, P=P, interpret=True)
+    ms, pres = np.asarray(ms), np.asarray(pres)
+    assert (ms[n_live:] == np.float32(1.0)).all()
+    assert (pres[n_live:] == 0).all()
+    for b in range(n_live):
+        rows = cube[gq[b]]
+        rows = np.where(rows != 0,
+                        rows | (sy[b].astype(np.uint32)
+                                << np.uint32(31))[:, None, None],
+                        rows).reshape(T, P, D)
+        lane = np.where(dead[0] == 0, rows, 0).astype(np.uint32)
+        lane |= tails[b]
+        ref, present = scorer.min_scores(
+            jnp.asarray(lane), jnp.asarray(lane != 0),
+            jnp.asarray(fw[b]), jnp.asarray(counts[b] > 0.5))
+        np.testing.assert_allclose(ms[b], np.asarray(ref), rtol=1e-5,
+                                   atol=1e-7)
+        bits = (np.asarray(present).astype(np.int32)
+                << np.arange(T)[:, None]).sum(0)
+        assert np.array_equal(pres[b], bits)
+
+
 def _assert_same_ranking(ref_ids, ref_scores, ids, scores, label):
     """Scores agree to the last-ulp reduction order; docids agree at
     strictly-untied ranks (tie order is not part of the contract)."""

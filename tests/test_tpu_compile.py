@@ -115,3 +115,34 @@ def test_fd_scores_fused_compiles_with_no_cube_copy(one_chip, tail):
     expected = VC * P * D * 4 + (B * T * P * D * 4 if tail else 0)
     assert mem.temp_size_in_bytes < 1 << 30
     assert abs(mem.argument_size_in_bytes - expected) < 64 << 20
+
+
+@pytest.mark.parametrize("tail", [False, True], ids=["notail", "tail"])
+def test_fd_scores_fused_t8_compiles_in_bounded_time(one_chip, tail):
+    """The FD kernel rolls its term pairs into one loop at every ``T``,
+    so its compile does not grow with the pairs: at ``T`` 8 (28 pairs)
+    each variant compiles in seconds, where one that unrolled them did
+    not finish in 23 minutes, and its scratch (the pair loop's VMEM
+    planes) fits the chip."""
+    T = 8
+    head = (_sds(one_chip, (B, T * 4), jnp.int32),
+            _sds(one_chip, (B, T * 4), jnp.int32),
+            _sds(one_chip, (1,), jnp.int32),
+            _sds(one_chip, (VC * 4, P // 4, D), jnp.uint32))
+    rest = (_sds(one_chip, (1, D), jnp.int32),
+            _sds(one_chip, (B, T), jnp.float32),
+            _sds(one_chip, (B, T), jnp.float32))
+    t0 = time.perf_counter()
+    if tail:
+        compiled = _compile(
+            pallas_scores.fd_scores_fused_t8, *head,
+            _sds(one_chip, (B, T, P, D), jnp.uint32), *rest,
+            T=T, P=P, interpret=False)
+    else:
+        compiled = _compile(pallas_scores.fd_scores_fused_notail_t8,
+                            *head, *rest, T=T, P=P, interpret=False)
+    assert time.perf_counter() - t0 < 120.0
+    mem = compiled.memory_analysis()
+    expected = VC * P * D * 4 + (B * T * P * D * 4 if tail else 0)
+    assert mem.temp_size_in_bytes < 1 << 30
+    assert abs(mem.argument_size_in_bytes - expected) < 64 << 20
