@@ -1594,8 +1594,7 @@ class DeviceIndex:
     def search_batch(self, queries, topk: int = 64, lang: int = 0,
                      df_of=None, total_docs: int | None = None,
                      sort_base_of=None):
-        """Batched execution: B queries per device round trip (vmap over
-        the query axis). Routing: drivers with a bounded doc set use the
+        """Batched execution: B queries per device round trip. Routing: drivers with a bounded doc set use the
         two-phase pruned kernel (F1); corpus-wide drivers go to the
         full-cube exact kernel (F2) when every sublist fits it.
 
@@ -1754,9 +1753,8 @@ class DeviceIndex:
                  plans[i].filters, plans[i].sortby), []).append(i)
         for (kappa, k2g, *_spec), idxs in sorted(
                 groups.items(), key=lambda kv: str(kv[0])):
-            step = self._f1_step(k2g)
-            for a in range(0, len(idxs), step):
-                chunk = idxs[a:a + step]
+            for a in range(0, len(idxs), F1_B):
+                chunk = idxs[a:a + F1_B]
                 waves.append(("f1", kappa, k2g, chunk,
                               self._run_batch(
                                   [plans[i] for i in chunk],
@@ -2015,18 +2013,6 @@ class DeviceIndex:
                 return min(r * KAPPA_FLOOR, self.D_cap)
         return min(_bucket(need, KAPPA_FLOOR), self.D_cap)
 
-    def _f1_step(self, k2: int) -> int:
-        """How many plans one ``_run_batch`` call takes: a wave's B, or
-        fewer on a terminal rung, whose [T, P, k2]·B phase-2
-        intermediates must stay bounded at k2 = D_cap."""
-        return F1_B if k2 <= F1_RUNGS[-1] * KAPPA_FLOOR \
-            else self._f1_step_terminal()
-
-    def _f1_step_terminal(self) -> int:
-        """Terminal-rung (k2 = D_cap) chunk size: the exact-scoring
-        cube chain costs ~2048·D bytes per lane."""
-        return max(1, min(4, (2 << 30) // (2048 * self.D_cap)))
-
     def _f2_bmax(self) -> int:
         """F2 batch cap: full-cube intermediates are ~48 bytes/doc/query
         ([T,P,D] cube+validity+scores) — bound them to ~1.5 GB."""
@@ -2102,6 +2088,10 @@ class DeviceIndex:
 
     def _run_batch(self, plans: list[ResidentPlan], kappa: int, k2: int):
         bucket, modeled, args, statics = self._f1_call(plans, kappa, k2)
+        # lanes from len(plans) on are padding: ``_two_phase`` is told
+        # how many are live and runs none of its per-lane work for them
+        g_stats.count("devindex.f1.lanes", bucket[0])
+        g_stats.count("devindex.f1.pad_lanes", bucket[0] - len(plans))
         return self._costed("devindex._two_phase", bucket, modeled,
                             _two_phase, *args, **statics)
 
@@ -2121,9 +2111,9 @@ class DeviceIndex:
         T = max(len(p.required) for p in plans)
         Rd, Rs, Lsp = _f1_rows(mrd, mrs, mls, kappa > KAPPA_FLOOR,
                                wide=T == WIDE_T)
-        # one B: every per-lane cost (phase-1 chains, phase-2 gathers)
-        # scales with B INCLUDING pad lanes, and a wider bucket is a
-        # program of its own to compile (``_issue_waves`` chunks at B)
+        # one B: a wider bucket is a program of its own to compile
+        # (``_issue_waves`` chunks at B); the per-lane work (phase-1
+        # chains, phase-2 gathers) runs for the live lanes only
         B = F1_B
         if len(plans) > B:  # stray caller overshoot: correctness first
             B = _bucket(len(plans), 4)
@@ -2180,14 +2170,15 @@ class DeviceIndex:
         # host args ride the (async) dispatch; returned WITHOUT fetching
         # — the caller fetches every wave's output in ONE device_get
         # (each separate blocking fetch is a host sync of its own)
-        modeled = self.wave_bytes_per_query(plans) * B \
+        modeled = self.wave_bytes_per_query(plans) * len(plans) \
             if devwatch.enabled() else None
         return bucket, modeled, (
             self.d_payload, self.d_doc, self.d_imp, self.d_rs,
             self.d_cnt, self.d_dense_imp, self.d_dense_rs,
             self.d_dense_cnt,
             self.d_siterank, self.d_doclang, self.d_dead,
-            np.int32(self.n_docs), d_filter, d_sort, sel, *args), dict(
+            np.int32(self.n_docs), d_filter, d_sort, sel,
+            np.int32(len(plans)), *args), dict(
             n_positions=self.P, lsp=Lsp, kappa=kappa, k2=k2,
             use_table=use_table, use_filter=uf, use_sort=us)
 
@@ -2343,20 +2334,23 @@ def _apply_doc_meta(sr, dl, idx, vsr, vdl):
 def _two_phase(d_payload, d_doc, d_imp, d_rs, d_cnt,
                d_dense_imp, d_dense_rs, d_dense_cnt,
                d_siterank, d_doclang, d_dead, n_docs_total,
-               d_filter, d_sort, d_sel,
+               d_filter, d_sort, d_sel, n_live,
                d_slot, d_group, d_base, d_quota, d_syn,
                s_start, s_len, s_group, s_base, s_quota, s_syn, s_isbase,
                freqw, required, negative, scored, counts, table, qlang,
                n_positions: int, lsp: int, kappa: int, k2: int,
                use_table: bool = True, use_filter: bool = False,
                use_sort: bool = False):
-    """The fused two-phase kernel, vmapped over the query axis.
+    """The fused two-phase kernel, one query lane after another.
 
     Phase 1 = dense upper bounds + intersection + approx top-κ (the
     maxPossibleScore prune, Posdb.cpp:6052); phase 2 = exact cube scoring
     of the κ candidates (docIdLoop semantics via scorer.min_scores).
     Output per query: [n_matched, bitcast(max missed bound), κ-top-k2
-    doc indices, bitcast(exact scores)]."""
+    doc indices, bitcast(exact scores)]. ``n_live`` (int32, traced)
+    counts the wave's live lanes, which come first: the lanes from it on
+    are padding, run nothing past the batched phase-1 matmul, and read
+    as rows of zeros."""
     D = d_dead.shape[0]
     V = d_dense_imp.shape[0]
     M = d_doc.shape[0]
@@ -2565,10 +2559,18 @@ def _two_phase(d_payload, d_doc, d_imp, d_rs, d_cnt,
             jax.lax.bitcast_convert_type(ts, jnp.uint32),
         ])
 
-    return jax.vmap(one)(ubb_mm, d_slot, d_group, d_base, d_quota,
-                         d_syn, s_start, s_len, s_group, s_base,
-                         s_quota, s_syn, s_isbase, freqw, required,
-                         negative, scored, counts, table, qlang)
+    # one lane after another, the live ones only: the per-lane work is
+    # scalar gathers and scatters, which a vmap does not overlap across
+    # lanes, and a pad lane's row stays zeros
+    lanes = (d_slot, d_group, d_base, d_quota, d_syn, s_start, s_len,
+             s_group, s_base, s_quota, s_syn, s_isbase, freqw, required,
+             negative, scored, counts, table, qlang)
+
+    def lane(b, out):
+        return out.at[b].set(one(ubb_mm[b], *(x[b] for x in lanes)))
+
+    return jax.lax.fori_loop(0, n_live, lane,
+                             jnp.zeros((B, 2 + 2 * k2), jnp.uint32))
 
 
 @partial(jax.jit, static_argnames=("n_positions", "lpost", "k2", "n_sel",

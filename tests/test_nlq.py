@@ -363,6 +363,39 @@ def test_warm_f1_leaves_no_f1_program_to_compile(nlq):
             jitwatch.disable()
 
 
+@pytest.mark.parametrize("T,tier", [(4, (4, 4, 512)), (8, (16, 16, 2048))],
+                         ids=["t4", "t8"])
+@pytest.mark.parametrize("n_live", [1, 2, 3, 4])
+def test_an_f1_wave_scores_its_live_lanes_only(nlq, monkeypatch, T, tier,
+                                               n_live):
+    """A ``B`` 4 ``_two_phase`` wave of ``n_live`` plans: each live row is
+    bit for bit that plan's row in a wave whose four lanes are live, and
+    each pad row is zeros (the lanes past ``n_live`` run nothing)."""
+    di = engine.get_device_index(nlq["coll"])
+    qs = [" ".join(q.split()[:3]) if T == 4 else q for q in nlq["queries"]]
+
+    def fits(p):
+        mls = int(p.s_len.max()) if len(p.s_len) else 0
+        return (p.matchable and not p.has_table and len(p.required) == T
+                and len(p.d_slot) <= tier[0] and len(p.s_start) <= tier[1]
+                and mls <= tier[2] and len(p.d_slot) + len(p.s_start) > 1)
+    plans = [p for p in (di.plan(compile_query(q, 0)) for q in qs)
+             if fits(p)][:4]
+    assert len(plans) == 4
+    # every wave on the tier under test, whatever rows its riders hold
+    monkeypatch.setattr(devindex, "_f1_rows", lambda *a, **k: tier)
+
+    def rows(ps):
+        bucket, _, args, statics = di._f1_call(ps, 256, 256)
+        assert bucket[:4] == (4, *tier) and int(args[15]) == len(ps)
+        return np.asarray(devindex._two_phase(*args, **statics))
+    full, part = rows(plans), rows(plans[:n_live])
+    assert full.shape == part.shape == (4, 2 + 2 * 256)
+    assert (full[:, 0] >= 1).all()          # each plan matches its page
+    assert np.array_equal(part[:n_live], full[:n_live])
+    assert not part[n_live:].any()
+
+
 # ---------------------------------------------------- the question rule
 
 def test_question_rule_lengths_stop_words_classes_and_uniqueness(nlq):

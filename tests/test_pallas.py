@@ -329,21 +329,21 @@ class TestQuarterRowCube:
         for q, route in (("alpha beta", "fd"), ("alpha gamma", "fd"),
                          ("boxes dogs", "f2")):
             before = di.route_counts[route]
-            lanes = _fd_lanes()
+            lanes = _lanes("fd")
             dev = engine.search_device(coll, q, topk=8,
                                        site_cluster=False,
                                        with_snippets=False)
             assert di.route_counts[route] == before + 1, (q, route)
             if route == "fd":
-                assert _fd_lanes() == (lanes[0] + 4, lanes[1] + 3), q
+                assert _lanes("fd") == (lanes[0] + 4, lanes[1] + 3), q
             same_as_host(q, dev)
         before = di.route_counts["fd"]
-        lanes = _fd_lanes()
+        lanes = _lanes("fd")
         devs = engine.search_device_batch(coll, FD_FIVE, topk=8,
                                           site_cluster=False,
                                           with_snippets=False)
         assert di.route_counts["fd"] == before + 5
-        assert _fd_lanes() == (lanes[0] + 16, lanes[1] + 11)
+        assert _lanes("fd") == (lanes[0] + 16, lanes[1] + 11)
         for q, dev in zip(FD_FIVE, devs):
             same_as_host(q, dev)
 
@@ -365,14 +365,14 @@ class TestQuarterRowCube:
             seen.append((name, tuple(int(x) for x in bucket), args,
                          statics))
         monkeypatch.setattr(dv.DeviceIndex, "_costed", note)
-        lanes = _fd_lanes()
+        lanes = _lanes("fd")
         di._run_batch_fd(plans, 128, 2048)
         # the bucket rule, as it stood before pad lanes were skipped
         B = 4 if n <= 4 else max(di._fd_bmax(), n)
         T = max(len(p.required) for p in plans)
         mrp = max([len(p.p_start) for p in plans] + [1])
         Rp = 4 if mrp <= 4 else dv._bucket(mrp, 8)
-        assert _fd_lanes() == (lanes[0] + B, lanes[1] + B - n)
+        assert _lanes("fd") == (lanes[0] + B, lanes[1] + B - n)
         assert B == (4 if n == 1 else 16)
         [(name, bucket, args, statics)] = seen
         assert name == "devindex._direct_cube"
@@ -383,6 +383,49 @@ class TestQuarterRowCube:
         # g_quarter, g_qsyn, then the live count: [n] int32, traced
         assert args[9].shape == (B, T, 4) and args[10].shape == (B, T, 4)
         assert args[11].dtype == np.int32 and args[11].tolist() == [n]
+
+    def test_an_f1_wave_counts_its_pad_lanes_and_keeps_its_key(
+            self, env, monkeypatch):
+        """``_run_batch`` with one plan counts 4 lanes and 3 pad lanes,
+        hands ``_two_phase`` the live count as data, and dispatches the
+        program key the tier rule has always given: one of the closed
+        set, which is what it was."""
+        import open_source_search_engine_tpu.query.devindex as dv
+        from open_source_search_engine_tpu.query.compiler import \
+            compile_query
+
+        _, di = env
+        plan = di.plan(compile_query("zeta", 0))
+        seen = []
+
+        def note(self, name, bucket, modeled, fn, *args, **statics):
+            seen.append((name, tuple(int(x) for x in bucket), args,
+                         statics))
+        monkeypatch.setattr(dv.DeviceIndex, "_costed", note)
+        lanes = _lanes("f1")
+        di._run_batch([plan], 256, 256)
+        assert _lanes("f1") == (lanes[0] + 4, lanes[1] + 3)
+        [(name, bucket, args, statics)] = seen
+        assert name == "devindex._two_phase"
+        mls = int(plan.s_len.max()) if len(plan.s_len) else 0
+        tier = dv._f1_rows(max(len(plan.d_slot), 1),
+                           max(len(plan.s_start), 1), mls)
+        assert bucket == (4, *tier, 256, 256)
+        assert set(statics) == {"n_positions", "lsp", "kappa", "k2",
+                                "use_table", "use_filter", "use_sort"}
+        # the selector, then the live count: an int32 scalar, traced
+        assert args[14].shape[0] == 4
+        assert args[15].dtype == np.int32 and int(args[15]) == 1
+        # the closed F1 set at this D_cap (the rungs fold at 2048)
+        assert di.D_cap == 2048
+        assert di.f1_programs() == [
+            (4, 4, 2, 128, 256, 256), (4, 4, 2, 512, 256, 256),
+            (4, 4, 4, 512, 256, 256), (4, 4, 4, 2048, 256, 256),
+            (4, 16, 16, 2048, 256, 256), (4, 4, 4, 2048, 2048, 2048),
+            (4, 16, 16, 2048, 2048, 2048),
+            (4, 16, 16, 2048, 256, 256, 8),
+            (4, 16, 16, 2048, 2048, 2048, 8)]
+        assert bucket in di.f1_programs()
 
 
 @pytest.mark.parametrize("counters,want", [
@@ -402,12 +445,32 @@ def test_fd_pad_share_reads_the_counters_or_nothing(counters, want):
     assert reader.read({"counters": counters}) == want
 
 
+@pytest.mark.parametrize("counters,want", [
+    ({"devindex.f1.lanes": 8.0, "devindex.f1.pad_lanes": 5.0}, 62.5),
+    ({"devindex.f1.lanes": 4.0, "devindex.f1.pad_lanes": 0.0}, 0.0),
+    ({"devindex.fd.lanes": 8.0, "devindex.fd.pad_lanes": 5.0}, None),
+    ({"query": 40.0}, None),            # the parent: no such counters
+    ({"devindex.f1.lanes": 0.0, "devindex.f1.pad_lanes": 0.0}, None),
+])
+def test_f1_pad_share_reads_the_counters_or_nothing(counters, want):
+    import importlib.util
+    from pathlib import Path
+    path = (Path(__file__).resolve().parents[1] / "benchmarks"
+            / "layer_metrics" / "f1_pad_share.py")
+    spec = importlib.util.spec_from_file_location("f1_pad_share", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    assert reader.read({"counters": counters}) == want
+
+
 #: FD queries of the route corpus that meet in one pure quarter-row wave
 FD_FIVE = ["alpha beta", "alpha gamma", "beta gamma", "alpha boxes",
            "beta dogs"]
 
 
-def _fd_lanes() -> tuple[float, float]:
+def _lanes(route: str) -> tuple[float, float]:
+    """(lanes, pad lanes) the ``route`` ("fd" or "f1") waves counted."""
     from open_source_search_engine_tpu.utils.stats import g_stats
     c = g_stats.snapshot()["counters"]
-    return c.get("devindex.fd.lanes", 0), c.get("devindex.fd.pad_lanes", 0)
+    return (c.get(f"devindex.{route}.lanes", 0),
+            c.get(f"devindex.{route}.pad_lanes", 0))
