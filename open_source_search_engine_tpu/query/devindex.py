@@ -1614,7 +1614,7 @@ class DeviceIndex:
         soon as the host args are enqueued — no host sync. This is the
         resident loop's steady-state dispatch cost (one enqueue), vs
         the full jit round trip a one-shot ``search_batch`` pays."""
-        t_plan = time.perf_counter()
+        t_plan, cpu0 = time.perf_counter(), time.thread_time()
         qplans = [q if isinstance(q, QueryPlan) else compile_query(q, lang)
                   for q in queries]
         # plan cache: only the pure-local form is cacheable — mesh calls
@@ -1639,7 +1639,7 @@ class DeviceIndex:
             plans = [self.plan(qp, df_of=df_of, total_docs=total_docs,
                                sort_base_of=sort_base_of)
                      for qp in qplans]
-        trace.record("devindex.plan", t_plan, queries=len(qplans))
+        trace.record("devindex.plan", t_plan, cpu0=cpu0, queries=len(qplans))
         live = [i for i, p in enumerate(plans) if p.matchable]
         results = [(np.empty(0, np.uint64), np.empty(0, np.float32), 0)
                    ] * len(plans)

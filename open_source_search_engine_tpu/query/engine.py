@@ -567,7 +567,7 @@ def search_device_batch(coll: Collection, queries, *, topk: int = 10,
     batch_span = trace.timed_span("query.results_batch",
                                   queries=len(plans))
     with batch_span, lock_ctx:
-        t_held = time.perf_counter()
+        t_held, cpu_held = time.perf_counter(), time.thread_time()
         for plan, (docids, scores, n_matched) in zip(plans, raw):
             results, clustered = build_results(
                 get_doc,
@@ -591,7 +591,7 @@ def search_device_batch(coll: Collection, queries, *, topk: int = 10,
     if results_lock is not None:
         trace.record("query.lock_wait", batch_span.t0, t_held)
     trace.record("query.results_work", t_held, batch_span.t1,
-                 queries=len(out))
+                 cpu0=cpu_held, queries=len(out))
     return out
 
 

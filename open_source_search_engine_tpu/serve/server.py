@@ -89,7 +89,8 @@ class QueryBatcher:
         # being built: 2 * DEPTH out, or the loop's queue is empty at
         # every collect and host and device take turns (PERF.md, PR 31)
         from concurrent.futures import ThreadPoolExecutor
-        self._pool = ThreadPoolExecutor(2 * resident.DEPTH)
+        self._pool = ThreadPoolExecutor(2 * resident.DEPTH,
+                                        thread_name_prefix="query-pool")
         self._thread = threads.spawn("query-batcher", self._loop)
 
     @property
@@ -120,27 +121,35 @@ class QueryBatcher:
         deadline = deadline_mod.Deadline.after(timeout)
         if dl is not None and dl.at < deadline.at:
             deadline = dl
-        with self._cv:
-            if len(self._queue) >= self.MAX_QUEUE:
-                g_stats.count("admission.queue_full")
-                raise priority_mod.QueueFull(
-                    "query batcher queue full")
-            self._queue.append((key, q, holder,
-                                trace_mod.current_span(), dl,
-                                priority_mod.current_tier(),
-                                priority_mod.current_tenant(),
-                                trace_mod.current_ledgers(),
-                                time.perf_counter()))
-            self._gauge_locked()
-            self._cv.notify_all()
-            while "res" not in holder and "err" not in holder:
-                left = deadline.remaining()
-                if left <= 0:
-                    if dl is not None and dl.expired():
-                        raise deadline_mod.DeadlineExceeded(
-                            "query deadline exceeded in batcher")
-                    raise TimeoutError("query batcher timeout")
-                self._cv.wait(timeout=left)
+        # every return from the wait below, a notify's, a timeout's or
+        # a spurious one; published once a request (the herd's count)
+        wakes = 0
+        try:
+            with self._cv:
+                if len(self._queue) >= self.MAX_QUEUE:
+                    g_stats.count("admission.queue_full")
+                    raise priority_mod.QueueFull(
+                        "query batcher queue full")
+                self._queue.append((key, q, holder,
+                                    trace_mod.current_span(), dl,
+                                    priority_mod.current_tier(),
+                                    priority_mod.current_tenant(),
+                                    trace_mod.current_ledgers(),
+                                    time.perf_counter()))
+                self._gauge_locked()
+                self._cv.notify_all()
+                while "res" not in holder and "err" not in holder:
+                    left = deadline.remaining()
+                    if left <= 0:
+                        if dl is not None and dl.expired():
+                            raise deadline_mod.DeadlineExceeded(
+                                "query deadline exceeded in batcher")
+                        raise TimeoutError("query batcher timeout")
+                    self._cv.wait(timeout=left)
+                    wakes += 1
+        finally:
+            if wakes:
+                g_stats.count("batcher.rider_wake", wakes)
         if "err" in holder:
             raise holder["err"]
         # result set on the pool thread -> this rider's thread runs
@@ -255,6 +264,18 @@ class QueryBatcher:
                 for e in batch:
                     e[2]["err"] = exc
                 self._cv.notify_all()
+
+
+class _FrontDoorHTTPServer(ThreadingHTTPServer):
+    """The search server's socket server. A handler thread lives one
+    connection; as it ends it adds its whole CPU time to the role
+    ``front`` of the process's CPU account (``host.cpu_us.front``)."""
+
+    def process_request_thread(self, request, client_address):
+        try:
+            super().process_request_thread(request, client_address)
+        finally:
+            threads.g_cpu.add_front()
 
 
 def _xml_escape(s: str) -> str:
@@ -2049,7 +2070,7 @@ class SearchHTTPServer:
                 self._serve("POST")
 
         self._warm_device()
-        self._httpd = ThreadingHTTPServer((self.host, self.port), Handler)
+        self._httpd = _FrontDoorHTTPServer((self.host, self.port), Handler)
         # TLS plane (reference links -lssl and serves https off gb.pem,
         # TcpServer.cpp / Makefile:113): wrap the listening socket when
         # a cert is configured — same handler, same port semantics

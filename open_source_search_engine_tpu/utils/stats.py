@@ -201,6 +201,18 @@ class Stats:
         self.gauges: dict[str, float] = {}
         #: per-second samples: (epoch_s, {metric: value}) ring
         self.timeseries: deque = deque(maxlen=timeseries_window)
+        #: callables read when a snapshot is taken, each returning
+        #: counter increments (the CPU account of ``utils.threads``)
+        self._polls: list = []
+
+    def add_poll(self, fn) -> None:
+        """Fold ``fn()``'s counter increments into every snapshot."""
+        self._polls.append(fn)
+
+    def _fold_polls(self) -> None:
+        for fn in self._polls:
+            for name, n in fn().items():
+                self.count(name, n)
 
     def count(self, name: str, n: int = 1) -> None:
         with self._lock:
@@ -211,10 +223,15 @@ class Stats:
             self.gauges[name] = float(value)
 
     def record_ms(self, name: str, ms: float,
-                  exemplar: str | None = None) -> None:
+                  exemplar: str | None = None, counter: str | None = None,
+                  n: int = 0) -> None:
+        """Record a latency; ``counter``, where given, is raised by
+        ``n`` under the same lock (a span's CPU µs beside its ms)."""
         with self._lock:
             self.latencies.setdefault(name, LatencyStat()).add(
                 ms, exemplar=exemplar)
+            if counter is not None:
+                self.counters[counter] = self.counters.get(counter, 0) + n
 
     def timed(self, name: str):
         """Context manager: ``with g_stats.timed("query"): ...``."""
@@ -240,6 +257,7 @@ class Stats:
             self.gauges.clear()
 
     def snapshot(self) -> dict:
+        self._fold_polls()
         with self._lock:
             return {
                 "counters": dict(self.counters),
@@ -252,6 +270,7 @@ class Stats:
         """Mergeable snapshot: raw histogram buckets instead of derived
         percentiles — the ``/rpc/stats`` payload a coordinator scrape
         merges into fleet distributions."""
+        self._fold_polls()
         with self._lock:
             return {
                 "counters": dict(self.counters),

@@ -16,7 +16,8 @@ This module is the Dapper-style fix (Sigelman et al., 2010):
   trace start (``trace_sample`` parm, default 1 in 64).  Unsampled
   traces still time their root (so the slow-query net below works) but
   every ``span()`` inside them is a no-op: the unsampled path must be
-  cheap enough to leave on in production (see ``BENCH_TRACE=1``).
+  cheap enough to leave on in production (what tracing costs when on
+  is the benchmark's ``traced_qps`` beside its ``qps``).
 * **Slow-query log** — any trace slower than ``slow_query_ms`` is kept
   regardless of the sampling coin flip and appended as one JSON line to
   ``slowlog.jsonl`` (next to ``statsdb.jsonl``).  An unsampled slow
@@ -36,6 +37,11 @@ This module is the Dapper-style fix (Sigelman et al., 2010):
   they feed ``g_stats`` (always), the ledger (stages), the span tree
   (sampled) and, while a ``jax.profiler`` session runs, a
   ``TraceAnnotation`` of the same name on the device trace's clock.
+* **Time off the CPU** — a span of :data:`CPU_SPANS` also reads its
+  thread's CPU clock at both ends and counts the difference as
+  ``<name>.cpu_us``: its wall time less that is time its thread was
+  ready or blocked, not running (waiting for the interpreter lock
+  looks like running to a wall clock).
 * **Cross-host propagation** — the transport stamps outgoing RPCs with
   an ``X-OSSE-Trace: <trace_id>:<parent_span_id>`` header; node
   handlers ``adopt()`` it, run their handler under a local root span,
@@ -103,6 +109,16 @@ REQUEST_STAGES = (
     "serve.unaccounted",        # serve.request less all of the above
 )
 _STAGE_SET = frozenset(REQUEST_STAGES)
+
+#: the spans that also count their thread's CPU time, in µs, as the
+#: counter ``<name>.cpu_us`` (two ``thread_time`` reads a span)
+CPU_SPANS = (
+    "resident.issue_wave",      # the loop's thread: plan, route, issue
+    "devindex.plan",            # inside it
+    "query.results_work",       # a pool thread, under the core lock
+)
+_CPU_COUNTER = {name: f"{name}.cpu_us" for name in CPU_SPANS}
+_thread_time = time.thread_time
 
 _ids = itertools.count(1)
 
@@ -367,9 +383,11 @@ class timed_span:
     and while a ``jax.profiler`` session runs the interval is written
     onto its clock as a ``TraceAnnotation`` of the same name. ``t0`` /
     ``t1`` are the clock readings it made, for a neighbour that starts
-    or ends on the same boundary (one clock a boundary)."""
+    or ends on the same boundary (one clock a boundary). A span of
+    :data:`CPU_SPANS` reads its thread's CPU clock inside ``t0`` ..
+    ``t1`` and counts it as ``<name>.cpu_us``."""
 
-    __slots__ = ("name", "_cm", "t0", "t1", "_ann")
+    __slots__ = ("name", "_cm", "t0", "t1", "_ann", "_cpu0")
 
     def __init__(self, name: str, **tags):
         self.name = name
@@ -382,9 +400,13 @@ class timed_span:
         if self._ann is not None:
             self._ann.__enter__()
         self.t0 = time.perf_counter()
+        self._cpu0 = _thread_time() if self.name in _CPU_COUNTER else None
         return self._cm.__enter__()
 
     def __exit__(self, *exc) -> None:
+        cpu0 = self._cpu0
+        if cpu0 is not None:
+            cpu_us = int((_thread_time() - cpu0) * 1e6)
         self.t1 = time.perf_counter()
         self._cm.__exit__(*exc)
         if self._ann is not None:
@@ -396,13 +418,17 @@ class timed_span:
         sp = self._cm.sp
         ms = (self.t1 - self.t0) * 1000.0
         _to_ledgers(self.name, ms)
-        g_stats.record_ms(
-            self.name, ms,
-            exemplar=sp.trace_id if sp is not None else None)
+        exemplar = sp.trace_id if sp is not None else None
+        if cpu0 is None:
+            g_stats.record_ms(self.name, ms, exemplar=exemplar)
+        else:
+            g_stats.record_ms(self.name, ms, exemplar,
+                              _CPU_COUNTER[self.name], cpu_us)
 
 
 def record(name: str, t0: float, t1: float | None = None, *,
-           parent: Span | None = None, ledgers=None, **tags) -> None:
+           parent: Span | None = None, ledgers=None,
+           cpu0: float | None = None, **tags) -> None:
     """Attach an already-measured ``perf_counter`` interval to the
     current span AND to ``g_stats`` — like ``timed_span`` but for
     intervals the caller timed itself (device-time attribution after a
@@ -410,7 +436,11 @@ def record(name: str, t0: float, t1: float | None = None, *,
     ``perf_counter`` deltas off the query path (the ``adhoc-timing``
     lint rule). ``parent`` / ``ledgers`` name a request other than the
     thread's own: a wait measured by the thread that ended it (the
-    batcher for a rider, the resident loop for a ticket)."""
+    batcher for a rider, the resident loop for a ticket). ``cpu0``,
+    for a span of :data:`CPU_SPANS`, is this thread's ``thread_time``
+    read at ``t0``: the CPU it has used since is counted."""
+    ctr = _CPU_COUNTER.get(name) if cpu0 is not None else None
+    cpu_us = int((_thread_time() - cpu0) * 1e6) if ctr is not None else 0
     end = time.perf_counter() if t1 is None else t1
     p = parent if parent is not None else _ctx.get()
     if p is not None:
@@ -418,7 +448,8 @@ def record(name: str, t0: float, t1: float | None = None, *,
     ms = (end - t0) * 1000.0
     _to_ledgers(name, ms, ledgers)
     g_stats.record_ms(name, ms,
-                      exemplar=p.trace_id if p is not None else None)
+                      exemplar=p.trace_id if p is not None else None,
+                      counter=ctr, n=cpu_us)
 
 
 def finish_request(ledger: StageLedger, t0: float,
